@@ -14,8 +14,7 @@ mesh pipeline' (`_place_batch_keyed_mesh`) runs an explicit `shard_map`
 cold stage over these same shardings with ZERO collectives in any
 compiled program, exchanges only O(devices x T x k) winner-candidate
 rows point-to-point, and keeps warm storm windows resident on the lead
-device (`mesh_collective_audit` gates the claim in tier-1 and the
-multi-chip dry run).
+device (`mesh_collective_audit` gates the claim in tier-1).
 """
 
 from __future__ import annotations
@@ -40,8 +39,8 @@ def scheduling_mesh(devices: Optional[Sequence[jax.Device]] = None) -> Mesh:
 def pow2_prefix(devices: Sequence[jax.Device]) -> Sequence[jax.Device]:
     """Largest power-of-two prefix of a device list — the mesh-sizing rule
     (node rows pad to powers of two, so the sharded axis must divide
-    evenly). THE single definition; server boot and the multi-chip dry run
-    both use it."""
+    evenly). THE single definition; server boot, bench.py and
+    chip_smoke.py all use it."""
     n = 1
     while n * 2 <= len(devices):
         n *= 2

@@ -1,11 +1,10 @@
 """Pipelined placement: device-resident usage chaining across evaluations.
 
-The TPU-native throughput path. A synchronous per-eval loop pays one
-device->host RTT per evaluation (expensive on remote-attached TPUs); instead
-the placer chains evaluations ON DEVICE — eval i+1's usage input is eval i's
-usage_after array, never copied back — dispatches asynchronously, and streams
-packed results home with copy-ahead, so the RTT amortizes across the whole
-in-flight window.
+The TPU-native throughput path. A synchronous per-eval loop waits on one
+device->host readback per evaluation; instead the placer chains evaluations
+ON DEVICE — eval i+1's usage input is eval i's usage_after array, never
+copied back — dispatches asynchronously, and streams packed results home
+with copy-ahead, so one host sync covers the whole in-flight window.
 
 This is the tensor re-expression of the reference's optimistic concurrency:
 N workers scheduling against snapshots with a serializing applier
@@ -170,8 +169,8 @@ class PipelinedPlacer:
             self._drain_window()
 
     def _drain_window(self) -> None:
-        """ONE readback for the whole in-flight window: per-transfer RTT on a
-        remote-attached TPU amortizes across all of the window's evals."""
+        """ONE readback for the whole in-flight window: the host sync is
+        shared by all of the window's evals."""
         jnp = self._jnp
         window = self._inflight
         self._inflight = []

@@ -53,9 +53,9 @@ _NOISE_SCALE = 1e-3
 class _DeviceInputCache:
     """Content-addressed host->device transfer cache.
 
-    On a remote-attached TPU every `jnp.asarray(numpy)` pays a fixed RTT; a
-    scheduling storm re-uploads the SAME eligibility masks, demand vectors,
-    and zero count/host arrays for every eval. Keying on the exact bytes
+    A scheduling storm would otherwise re-upload the SAME eligibility
+    masks, demand vectors, and zero count/host arrays for every eval (a
+    [T, N] mask is N bytes per key per put). Keying on the exact bytes
     (not an identity or semantic key) makes the cache safe under any caller:
     equal content -> same immutable device buffer. Bounded LRU."""
 
@@ -137,12 +137,13 @@ class WindowAccumulator:
         return self._usage
 
 # Row-steps (node rows x padded placements) under which an eval places via
-# the numpy mirror (kernels.place_batch_host) instead of a device dispatch.
-# A device readback costs a fixed ~100ms sync on remote-attached TPUs; the
-# host kernel's incremental same-demand caching covers this budget in
-# ~10-30ms (one full table pass per unique (tg, demand) + O(1) patches per
-# placement), and a lone 50-placement eval on a 1k-node table in ~2ms.
-# Deep storm windows on big tables stay on the device chain.
+# the numpy mirror (kernels.place_batch_host) instead of a device dispatch:
+# a shallow window then needs no dispatch, readback or cold compile. The
+# host kernel's incremental same-demand caching does one full table pass
+# per unique (tg, demand) + O(1) patches per placement. Deep storm windows
+# on big tables stay on the device chain. The value predates the directly
+# attached chip; PERF.md has the host-sync round trip chip_smoke.py
+# measured there, which is what a re-tuning would start from.
 HOST_ROW_STEP_BUDGET = 1 << 23
 
 # Candidate-table budget for the keyed kernel (keys x candidates x devices).
@@ -255,7 +256,7 @@ def _mesh_shardings(nt):
 
 def _chain_to_device(usage, node_sh):
     """Rejoin the device chain after a host-placed window: one async
-    host->device upload (uploads don't pay the sync RTT readbacks do)."""
+    host->device upload (nothing waits on it, unlike a readback)."""
     if not isinstance(usage, np.ndarray):
         return usage
     import jax
@@ -367,13 +368,12 @@ class GenericStack:
         # The port-collision retry loop runs at most a handful of times: a
         # winner failing host-side network assignment is masked and the
         # remaining placements re-run.
-        # Small evals place host-side: a device readback pays a fixed
-        # ~100ms RTT on remote-attached TPUs, far more than numpy takes
-        # over a modest rows x placements product. Storms and huge evals
-        # keep the device path (the budget keeps host work bounded).
-        # allow_host_select mirrors ServerConfig.host_placement so that
-        # host_placement=False forces the device kernel on the slow path
-        # too (the multichip dry run proves the SPMD path end to end).
+        # Small evals place host-side (no dispatch, readback or cold
+        # compile for a modest rows x placements product). Storms and
+        # huge evals keep the device path (the budget keeps host work
+        # bounded). allow_host_select mirrors ServerConfig.host_placement
+        # so that host_placement=False forces the device kernel on the
+        # slow path too (the mesh serving tests rely on it).
         use_host = (self.tindex.allow_host_select
                     and nt.n_rows * prep.p_pad <= HOST_ROW_STEP_BUDGET)
         for _attempt in range(8):
@@ -391,9 +391,8 @@ class GenericStack:
                                     placed_counts=placed_counts,
                                     placed_hosts=placed_hosts,
                                     keep=remaining)
-            # ONE device->host transfer: on remote-attached TPUs a readback
-            # pays a fixed RTT, so results come back packed (free for the
-            # host path — already numpy).
+            # ONE device->host transfer: results come back packed (free
+            # for the host path — already numpy).
             packed = np.asarray(res.packed)
             failed_rows, remaining = self.collect(
                 prep, packed, results, remaining,
@@ -513,8 +512,7 @@ class GenericStack:
         reset vector; scan kernels take per-placement demands (reset only
         for the multi-eval scan). Every host array goes through the
         content-addressed transfer cache, so a storm's byte-identical
-        masks/demands/zero arrays pay ZERO host->device puts per eval
-        (each put is a full RTT on remote-attached TPUs)."""
+        masks/demands/zero arrays pay ZERO host->device puts per eval."""
         node_sh, mask_sh, rep_sh = _mesh_shardings(self.tindex.nt)
         mid = prep.tg_demands if kind == "keyed" else demands
         dev = (_dev_cache.get(masks, mask_sh),
@@ -653,12 +651,11 @@ class GenericStack:
                       placed_counts: Optional[np.ndarray] = None,
                       placed_hosts: Optional[np.ndarray] = None,
                       keep: Optional[Sequence[int]] = None):
-        """Host-side mirror of dispatch() for shallow windows: every host
-        sync on a remote-attached TPU costs a fixed ~100ms round trip, so
-        a near-idle broker's evals place faster as numpy vector ops than
-        as a device dispatch + readback (kernels.place_batch_host). The
-        result's packed array is already host-side; the pipelined drain
-        recognizes that and skips the device RTT entirely."""
+        """Host-side mirror of dispatch() for shallow windows: a near-idle
+        broker's evals place as numpy vector ops, with no device dispatch
+        or readback (kernels.place_batch_host). The result's packed array
+        is already host-side; the pipelined drain recognizes that and
+        skips the device fetch entirely."""
         nt = self.tindex.nt
         if usage_override is not None:
             usage = np.asarray(usage_override, np.float32)

@@ -117,8 +117,9 @@ class ServerConfig:
     distributed_workers: bool = True
     # Host fast-path placement for shallow pipelined windows (numpy mirror
     # of the device kernel — see scheduler/kernels.place_batch_host).
-    # False forces every fast-path window onto the device chain; the
-    # multichip dryrun uses that to prove the SPMD path compiles and runs.
+    # False forces every fast-path window onto the device chain; the mesh
+    # serving tests and chip_smoke.py --chips 4 use that to prove the
+    # device path compiles and runs.
     host_placement: bool = True
     # Columnar service commits: all-placed pipelined windows ride the
     # sweep-batch machinery end to end — one ApplySweepBatch raft entry +
@@ -226,10 +227,15 @@ class Server:
         else:
             self.raft = DevRaft(self.fsm)
         self.state: StateStore = self.fsm.state
+        # Before anything compiles: a backend that cannot initialize
+        # raises here, and the persistent compile cache gets its place.
+        from nomad_tpu.tensor.backend import init_backend
+
+        devices = init_backend()
         self.tindex = TensorIndex.attach(self.state)
         # host_placement=False must force the DEVICE kernel everywhere —
-        # including the per-eval slow path's select_batch — so the
-        # multichip dry run proves the SPMD path end to end.
+        # including the per-eval slow path's select_batch — so a mesh
+        # test or smoke run proves the device path end to end.
         self.tindex.allow_host_select = self.config.host_placement
         if self.config.scheduler_mesh:
             if self.config.scheduler_mesh != "all":
@@ -238,10 +244,7 @@ class Server:
                     f"{self.config.scheduler_mesh!r}")
             from nomad_tpu.parallel import pow2_prefix, scheduling_mesh
 
-            import jax
-
-            self.tindex.nt.set_mesh(
-                scheduling_mesh(pow2_prefix(jax.devices())))
+            self.tindex.nt.set_mesh(scheduling_mesh(pow2_prefix(devices)))
 
         # QoS: tiered broker lanes + admission at ingress + preemption in
         # the scheduler, all sharing one config and one counter block.

@@ -35,7 +35,7 @@ class TensorIndex:
         self.attached = False
         # Mirrors ServerConfig.host_placement: False forces every stack
         # sharing this index onto the device kernels, including the
-        # per-eval slow path (the multichip dry run relies on it).
+        # per-eval slow path (the mesh serving tests rely on it).
         self.allow_host_select = True
         # System-sweep eligibility: ONE ClassEligibility over the whole
         # node table, shared by every system evaluation until the node

@@ -18,6 +18,7 @@ resident, delta-scatter updates, never re-ship the table).
 
 from __future__ import annotations
 
+import functools
 import threading
 import time
 from typing import Dict, List, Optional, Sequence, Set, Tuple
@@ -112,7 +113,7 @@ class NodeTensor:
         # changed (node upserts — must always refresh) vs rows where only
         # USAGE moved (alloc commits). A caller that overrides usage with a
         # device-side chain can skip the usage tier entirely, turning the
-        # steady-state storm refresh (one blocking host->device RTT per
+        # steady-state storm refresh (one host->device transfer per
         # window) into zero transfers.
         self._dirty_rows: Set[int] = set()
         self._usage_dirty: Set[int] = set()
@@ -343,8 +344,6 @@ class NodeTensor:
         override the usage input with their own device-side chain (the
         pipelined worker mid-storm). The queued rows are flushed by the next
         full call."""
-        ensure_backend()
-
         with self._lock:
             pending = (set(self._dirty_rows) if skip_usage
                        else self._dirty_rows | self._usage_dirty)
@@ -386,8 +385,7 @@ class NodeTensor:
                                             chunk[0], dtype=np.int32)])
                     # ONE host->device transfer per chunk: rows + all three
                     # column groups ride a single packed array and split
-                    # device-side (transfers are blocking RTTs on
-                    # remote-attached TPUs; dispatches are async).
+                    # device-side.
                     packed = np.concatenate(
                         [chunk[:, None].astype(np.float32),
                          self.capacity[chunk], self.score_cap[chunk],
@@ -711,7 +709,7 @@ class ChainArbiter:
         burn and the storm splinters into one-eval windows. The lease is
         only held during dispatch, so a worker parked here still wakes in
         time to dispatch while the previous window's drain/build (the
-        device RTT and plan-applier wait) run lease-free."""
+        device readback and plan-applier wait) run lease-free."""
         with self._cond:
             deadline = time.monotonic() + timeout
             while self._holder is not None:
@@ -747,57 +745,32 @@ class ChainArbiter:
             self._cond.wait(min(remaining, 0.1))
 
 
-_BACKEND_CHECKED = False
-_SCATTER_REFRESH = None
+@functools.lru_cache(maxsize=None)
+def _refresh_program():
+    """The jitted split + 3-way row scatter (built on first use so that
+    importing this module does not import jax)."""
+    import jax
+    import jax.numpy as jnp
+
+    @jax.jit
+    def refresh(cap, sc, us, pk):
+        rows = pk[:, 0].astype(jnp.int32)
+        cap_v = pk[:, 1:1 + RES_DIMS]
+        sc_v = pk[:, 1 + RES_DIMS:3 + RES_DIMS]
+        us_v = pk[:, 3 + RES_DIMS:]
+        return (cap.at[rows].set(cap_v), sc.at[rows].set(sc_v),
+                us.at[rows].set(us_v))
+
+    return refresh
 
 
 def _scatter_refresh(capacity, score_cap, usage, packed):
-    """Jitted split + 3-way row scatter of one packed refresh transfer.
+    """Row-scatter one packed refresh transfer into the device tables.
     packed: [k, 1 + R + 2 + R] f32 = (row, capacity, score_cap, usage)."""
-    global _SCATTER_REFRESH
-    if _SCATTER_REFRESH is None:
-        import jax
-        import jax.numpy as jnp
-
-        @jax.jit
-        def refresh(cap, sc, us, pk):
-            rows = pk[:, 0].astype(jnp.int32)
-            cap_v = pk[:, 1:1 + RES_DIMS]
-            sc_v = pk[:, 1 + RES_DIMS:3 + RES_DIMS]
-            us_v = pk[:, 3 + RES_DIMS:]
-            return (cap.at[rows].set(cap_v), sc.at[rows].set(sc_v),
-                    us.at[rows].set(us_v))
-
-        _SCATTER_REFRESH = refresh
-
     # packed stays a host array (uncommitted): jit places it with the other
     # operands, which may be sharded over a mesh — an eager jnp.asarray here
     # would commit it to the default device and conflict.
-    return _SCATTER_REFRESH(capacity, score_cap, usage, packed)
-
-
-def ensure_backend() -> None:
-    """Fail over to any available JAX backend if the configured one is gone.
-
-    A scheduler must keep placing when an accelerator platform fails to
-    initialize (e.g. a remote-TPU plugin configured in the environment but
-    not registered); XLA:CPU runs the same programs.
-    """
-    global _BACKEND_CHECKED
-    if _BACKEND_CHECKED:
-        return
-    import jax
-
-    try:
-        jax.devices()
-    except RuntimeError:
-        import logging
-
-        logging.getLogger("nomad.tensor").warning(
-            "configured JAX backend unavailable; falling back to auto-detect")
-        jax.config.update("jax_platforms", "")
-        jax.devices()
-    _BACKEND_CHECKED = True
+    return _refresh_program()(capacity, score_cap, usage, packed)
 
 
 def _next_pow2(n: int) -> int:

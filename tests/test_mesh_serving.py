@@ -1,12 +1,16 @@
 """Multi-chip SERVING: the pipelined worker's windows run on a sharded mesh.
 
 The node tensor (and every placement-kernel input) shards its node axis over
-a jax.sharding.Mesh; XLA's SPMD partitioner turns the same place_batch
-program into the multi-chip version. These tests run on the 8-virtual-CPU
+a jax.sharding.Mesh, and keyed windows run the shard-local mesh pipeline
+(kernels._place_batch_keyed_mesh). These tests run on the 8-virtual-CPU
 mesh from conftest and assert the mesh-served path is indistinguishable from
 single-device serving (reference frame: SURVEY §7.1 — the node axis IS the
 sharded tensor axis; the serving semantics come from nomad/worker.go +
 plan_apply.go, which don't care where the argmax ran).
+
+The servers run with host_placement=False: at these sizes every window is
+under HOST_ROW_STEP_BUDGET, and the numpy mirror would otherwise place them
+all without the mesh kernel ever being built.
 """
 
 import random
@@ -34,6 +38,7 @@ def _make_server(mesh: bool, window: int = 16) -> Server:
     cfg = ServerConfig(num_schedulers=1, pipelined_scheduling=True,
                        scheduler_window=window,
                        scheduler_mesh="all" if mesh else "",
+                       host_placement=False,
                        min_heartbeat_ttl=3600.0, heartbeat_grace=3600.0)
     srv = Server(cfg)
     srv.establish_leadership()
@@ -81,6 +86,15 @@ def _run_stream(srv, jobs):
     return placements
 
 
+def _assert_placed_on_mesh(srv):
+    """Every fast eval went through the mesh pipeline, none through numpy."""
+    stats = srv.workers[0].stats
+    srv.workers[0].quiesce(30.0)
+    assert stats["fast"] > 0 and stats["host"] == 0, stats
+    assert stats["mesh_windows"] > 0, stats
+    assert stats["mesh_shards"] == len(jax.devices()), stats
+
+
 class TestMeshServing:
     def test_mesh_is_wired_into_the_served_tensor(self):
         srv = _make_server(mesh=True)
@@ -94,6 +108,10 @@ class TestMeshServing:
             sh = arrays["usage"].sharding
             assert getattr(sh, "mesh", None) is not None
             assert sh.spec[0] is not None, "node axis not sharded"
+            # ... and a job placed against them runs the mesh kernel.
+            placed = _run_stream(srv, [_job()])
+            assert [len(v) for v in placed.values()] == [6]
+            _assert_placed_on_mesh(srv)
         finally:
             srv.shutdown()
 
@@ -117,6 +135,8 @@ class TestMeshServing:
                 placements = _run_stream(
                     srv, pickle.loads(pickle.dumps(jobs)))
                 results.append(placements)
+                if mesh:
+                    _assert_placed_on_mesh(srv)
             finally:
                 srv.shutdown()
         single, sharded = results
@@ -141,6 +161,7 @@ class TestMeshServing:
                 allocs = list(srv.state.allocs_by_eval(eid))
                 total += len(allocs)
             assert total == 12 * 4
+            _assert_placed_on_mesh(srv)
             for node in nodes:
                 used = sum(alloc_vec(a)[0]
                            for a in srv.state.allocs_by_node(node.ID)

@@ -494,11 +494,11 @@ class TestPlacementQualityParity:
 
 
 class TestHostKernelParity:
-    """place_batch_host is the numpy mirror used for shallow windows (a
-    device readback costs a fixed ~100ms sync on remote-attached TPUs);
-    its placements must match the device kernel exactly on the same
-    inputs (same f32 BestFit-v3 + Inf/NaN edges, same anti-affinity and
-    noise tie-break, same in-loop usage chaining)."""
+    """place_batch_host is the numpy mirror used for shallow windows; on
+    XLA's CPU backend its placements must match the device kernel
+    exactly on the same inputs (same f32 BestFit-v3 + Inf/NaN edges,
+    same anti-affinity and noise tie-break, same in-loop usage
+    chaining). chip_smoke.py makes the comparison on the chip."""
 
     def _inputs(self, seed, n=256, p=48, t=8):
         import numpy.random as nr

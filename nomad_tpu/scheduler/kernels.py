@@ -37,6 +37,16 @@ import numpy as np
 
 _LOG2_10 = float(np.log2(10.0))
 
+# The names the served path's device programs carry in a profiler trace
+# (each as "jit_<name>"): chosen, not inherited from whatever a factory's
+# inner function is called, because trace reductions find programs by
+# name (benchmark/layer_metrics/kernel_ms.storm.json matches
+# "place_batch" and "compact_window"). tests/test_stage_spans.py pins
+# every name to its lowered module. node_table_refresh is built in
+# tensor/node_table.py, which imports no kernel.
+PROGRAM_NAMES = ("place_batch", "place_batch_multi", "place_batch_keyed",
+                 "compact_window", "node_table_refresh")
+
 
 class PlacementResult(NamedTuple):
     packed: jax.Array       # [P, 3] f32: (chosen row or -1, score, n_feasible)
@@ -552,6 +562,7 @@ def _keyed_program(mesh, k_cand: int):
             c_use_f, mode="drop")
         return packed, usage
 
+    local_fn.__name__ = local_fn.__qualname__ = "place_batch_keyed"
     return jax.jit(local_fn)
 
 

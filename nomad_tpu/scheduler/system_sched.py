@@ -14,7 +14,6 @@ from __future__ import annotations
 
 import logging
 import random
-import time
 from typing import Dict, List, Optional
 
 from nomad_tpu.telemetry import metrics
@@ -291,9 +290,8 @@ class SystemScheduler:
         kept for network-ask groups, deregisters, and as the equivalence
         oracle."""
         if use_sweep:
-            t0 = time.monotonic()
-            system_sweep.compute_job_allocs(self)
-            metrics.measure_since(("nomad", "sched", "system", "sweep"), t0)
+            with metrics.measure(("nomad", "sched", "system", "sweep")):
+                system_sweep.compute_job_allocs(self)
             metrics.incr_counter(("nomad", "sched", "system", "fast"))
             return
         metrics.incr_counter(("nomad", "sched", "system", "exact"))

@@ -169,7 +169,7 @@ class EvalBroker:
         "_lock", "_enabled", "_evals", "_job_evals", "_blocked", "_ready",
         "_unack", "_requeue", "_time_wait", "stats", "_ages",
         "_age_slack", "_slo", "_floors", "_foreign", "_region",
-        "_index_source")
+        "_index_source", "_ready_at")
 
     def __init__(self, nack_timeout: float = 60.0, delivery_limit: int = 3,
                  qos: Optional[QoSConfig] = None):
@@ -195,6 +195,10 @@ class EvalBroker:
         # never reset behind fresh arrivals, and ack-time wait vs the tier
         # deadline feeds the SLO-burn rings below.
         self._ages: Dict[str, float] = {}
+        # eval id -> when it entered a ready queue THIS time (monotonic):
+        # the one source of the broker's queue wait, sampled at dequeue as
+        # nomad.broker.wait and synthesized into the trace's broker.wait.
+        self._ready_at: Dict[str, float] = {}
         # Warm-failover witness slack per eval: the first-enqueue seed a
         # new leader derives from the replicated timetable errs OLDER by
         # up to one witness interval (good for ordering — the eval keeps
@@ -264,6 +268,7 @@ class EvalBroker:
             self._requeue.clear()
             self._time_wait.clear()
             self._ages.clear()
+            self._ready_at.clear()
             self._age_slack.clear()
             self._floors.clear()
             self._foreign.clear()
@@ -360,6 +365,7 @@ class EvalBroker:
             # re-entry (nack redelivery, blocked promotion) — the newest
             # release point is the sound snapshot bound.
             self._floors[ev.ID] = self._index_source()
+        self._ready_at[ev.ID] = time.monotonic()
         self._ready.setdefault(queue, self._queue()).push(ev, enq_time)
         self.stats.TotalReady += 1
         sched = self.stats.ByScheduler.setdefault(
@@ -488,11 +494,12 @@ class EvalBroker:
                            now: Optional[float] = None
                            ) -> Tuple[Evaluation, str]:
         ev = self._ready[sched].pop(now)
-        entry = trace.linked_entry("eval", ev.ID)
-        if entry is not None:
-            # Synthesized queue-wait span: enqueue-link time -> now.
-            trace.record_span(entry[0], "broker.wait", entry[1],
-                              eval=ev.ID, scheduler=sched)
+        ready_at = self._ready_at.pop(ev.ID, None)
+        if ready_at is not None:
+            metrics.measure_since(("nomad", "broker", "wait"), ready_at)
+            # Synthesized queue-wait span, from the same stamp.
+            trace.record_span(trace.linked("eval", ev.ID), "broker.wait",
+                              ready_at, eval=ev.ID, scheduler=sched)
         token = generate_uuid()
         timer = wheel.after(self.nack_timeout, self.nack, ev.ID, token)
         self._unack[ev.ID] = _Unack(ev, token, timer)

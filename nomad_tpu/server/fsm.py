@@ -136,8 +136,6 @@ class FSM:
         under nomad.fsm.<op> as in fsm.go:147 MeasureSince, and — inside
         an active trace — spanned as fsm.<op>, child-only so background
         applies never mint traces)"""
-        # lint: allow(apply_pure, local metrics timer; never enters state)
-        start = time.monotonic()
         # The witness is REPLICA-LOCAL wall time by design (reference:
         # fsm.go:147): each replica records when IT applied the index, for
         # operator time->index queries. It never feeds replicated tables
@@ -148,35 +146,35 @@ class FSM:
         leaf = _MSG_METRIC.get(msg_type, msg_type.name.lower())
         broker = self.events
         events = None
-        try:
-            with trace.span("fsm." + leaf, index=index):
-                result = handler(self, index, payload)
+        with metrics.measure(("nomad", "fsm", leaf)):
+            try:
+                with trace.span("fsm." + leaf, index=index):
+                    result = handler(self, index, payload)
+                    if broker is not None:
+                        # Build INSIDE the span so publish stamps this
+                        # entry's fsm trace/span ids onto its events.
+                        try:
+                            events = build_events(self, msg_type, payload)
+                        except Exception:
+                            # A builder bug must not fail a consensus-
+                            # committed entry (the handler already applied);
+                            # the entry publishes empty and the loss shows
+                            # up in the equivalence fold.
+                            logger.exception(
+                                "event builder failed at index %d", index)
+                # Fold only SUCCESSFUL applies into the digest chain (a
+                # handler exception skips this via the raise): every replica
+                # applies the same entries, so every replica folds the same
+                # sequence.
+                if self.digest is not None:
+                    self._digest_fold(index, msg_type, payload)
+                return result
+            finally:
+                # Publish in the finally — even a failed handler releases the
+                # broker's index reservation (empty batch), so one poisoned
+                # entry can never wedge every later subscriber.
                 if broker is not None:
-                    # Build INSIDE the span so publish stamps this
-                    # entry's fsm trace/span ids onto its events.
-                    try:
-                        events = build_events(self, msg_type, payload)
-                    except Exception:
-                        # A builder bug must not fail a consensus-
-                        # committed entry (the handler already applied);
-                        # the entry publishes empty and the loss shows
-                        # up in the equivalence fold.
-                        logger.exception(
-                            "event builder failed at index %d", index)
-            # Fold only SUCCESSFUL applies into the digest chain (a
-            # handler exception skips this via the raise): every replica
-            # applies the same entries, so every replica folds the same
-            # sequence.
-            if self.digest is not None:
-                self._digest_fold(index, msg_type, payload)
-            return result
-        finally:
-            # Publish in the finally — even a failed handler releases the
-            # broker's index reservation (empty batch), so one poisoned
-            # entry can never wedge every later subscriber.
-            if broker is not None:
-                broker.publish(index, events or ())
-            metrics.measure_since(("nomad", "fsm", leaf), start)
+                    broker.publish(index, events or ())
 
     def _digest_fold(self, index: int, msg_type: MessageType,
                      payload: Dict[str, Any]) -> None:

@@ -55,6 +55,7 @@ import queue
 import random
 import threading
 import time
+from contextlib import contextmanager
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Tuple
 
@@ -129,6 +130,7 @@ STATS_COUNTERS = (
 )
 STATS_TIMERS_MS = (
     "t_lease_ms",        # waiting for the shared chain-lease (ChainArbiter)
+    "t_fill_ms",         # window fill, raft-sync barrier, snapshot
     "t_refresh_ms",      # node-table device refresh at dispatch
     "t_diff_ms",         # job diff/alloc filtering per eval
     "t_prep_ms",         # PreparedBatch assembly (device inputs)
@@ -143,6 +145,7 @@ STATS_TIMERS_MS = (
     "t_planwait_ms",     # waiting on the plan applier
     "t_evalupd_ms",      # consensus EvalUpdate batch
     "t_slow_ms",         # slow-path evals of the window
+    "t_stagewait_ms",    # windows waiting at a stage seam, blocked put incl.
     "t_mesh_exchange_ms",  # mesh pipeline: cold rebuild + winner exchange
 )
 
@@ -212,6 +215,8 @@ class _WindowWork:
 
     fast: List[_FastEval]
     slow: List[Tuple[Evaluation, str]]
+    number: int = 0             # the worker's own count: `window` on spans
+    staged: float = 0.0         # when it was offered to the next stage
     drain: Optional[_DrainPlan] = None         # set by the dispatch stage
     packed: Optional[list] = None              # CompactResults, set by drain
     failed: bool = False                       # drain blew up: nack window
@@ -291,6 +296,31 @@ class PipelinedWorker(Worker):
             maxsize=1)
         self._build_q: "queue.Queue[Optional[_WindowWork]]" = queue.Queue(
             maxsize=1)
+        self._window_no = 0  # run-loop thread only
+
+    # ------------------------------------------------------------ stage spans
+    @contextmanager
+    def _stage(self, stage: str, window: int):
+        """One stage of one window, timed once for all three readers: the
+        registry's nomad.worker.<stage> sample and the profiler's span
+        (metrics.measure), and stats["t_<stage>_ms"]. One span a stage a
+        window: per-eval work inside a stage adds to `stats` alone."""
+        timed = metrics.measure(("nomad", "worker", stage),
+                                worker=self.name, window=window)
+        try:
+            with timed:
+                yield
+        finally:
+            self.stats[f"t_{stage}_ms"] += timed.ms
+
+    def _hand_off(self, q: "queue.Queue", work: _WindowWork) -> None:
+        work.staged = time.monotonic()
+        q.put(work)  # the taker counts the wait, a blocked put included
+
+    def _enter_stage(self, work: _WindowWork) -> None:
+        self.stats["t_stagewait_ms"] += \
+            (time.monotonic() - work.staged) * 1e3
+        self._reset_window_deadlines(work)
 
     # -------------------------------------------------------------- run loop
     def run(self) -> None:
@@ -317,13 +347,12 @@ class PipelinedWorker(Worker):
                 # evals; filling under the lease captures everything that
                 # accumulated while another worker's dispatch held it —
                 # so windows stay full.
-                tw0 = time.perf_counter()
-                idle = self._arbiter.wait_dispatch_idle(DEQUEUE_TIMEOUT)
-                # The park above IS the convoy time (it only blocks while
+                # The park IS the convoy time (it only blocks while
                 # another worker's dispatch holds the lease), so it counts
                 # toward t_lease_ms — the later acquire is near-instant by
                 # construction and would report ~0 under real convoying.
-                self.stats["t_lease_ms"] += (time.perf_counter() - tw0) * 1e3
+                with self._stage("lease", self._window_no + 1):
+                    idle = self._arbiter.wait_dispatch_idle(DEQUEUE_TIMEOUT)
                 if not idle:
                     continue
                 got = self._dequeue_first()
@@ -331,17 +360,16 @@ class PipelinedWorker(Worker):
                     continue
                 work = None
                 batch: List[Tuple[Evaluation, str]] = [got]
-                tl0 = time.perf_counter()
                 try:
-                    lease = self._arbiter.acquire(self._stop, holder=self.name)
+                    with self._stage("lease", self._window_no + 1):
+                        lease = self._arbiter.acquire(self._stop,
+                                                      holder=self.name)
                 except RuntimeError:
                     continue  # stopping; the eval redelivers via its timer
-                self.stats["t_lease_ms"] += (time.perf_counter() - tl0) * 1e3
                 if lease.rebased:
                     self.stats["rebases"] += 1
                 try:
-                    batch.extend(self._fill_window(got[0]))
-                    work = self._dispatch_window(batch, lease)
+                    work = self._dispatch_window(batch, lease, fill=True)
                 except Exception:
                     # Broker/plan-queue teardown on leadership loss: drop
                     # quietly, redelivery handles the rest (worker.go:88-99).
@@ -356,7 +384,7 @@ class PipelinedWorker(Worker):
                     # failure path.
                     self._arbiter.abort(lease)
                 if work is not None:
-                    self._drain_q.put(work)
+                    self._hand_off(self._drain_q, work)
         finally:
             self._drain_q.put(None)
             drainer.join(timeout=60.0)
@@ -394,13 +422,11 @@ class PipelinedWorker(Worker):
             if work is None:
                 self._build_q.put(None)
                 return
-            self._reset_window_deadlines(work)
+            self._enter_stage(work)
             try:
                 if work.fast and not work.failed:
-                    t0 = time.perf_counter()
-                    work.packed = self._drain_window(work)
-                    self.stats["t_drain_ms"] += \
-                        (time.perf_counter() - t0) * 1e3
+                    with self._stage("drain", work.number):
+                        work.packed = self._drain_window(work)
                     for rec in work.fast:
                         if rec.span is not None:
                             rec.span.event("drained")
@@ -409,7 +435,7 @@ class PipelinedWorker(Worker):
                 if not (self._stop.is_set()
                         or not self.eval_broker.enabled()):
                     logger.exception("pipelined worker: window drain failed")
-            self._build_q.put(work)
+            self._hand_off(self._build_q, work)
 
     def _build_loop(self) -> None:
         """Stage 3: plan build/submit -> status batch -> acks, plus the
@@ -418,16 +444,16 @@ class PipelinedWorker(Worker):
             work = self._build_q.get()
             if work is None:
                 return
-            self._reset_window_deadlines(work)
+            self._enter_stage(work)
             try:
                 if work.failed:
                     raise RuntimeError("window drain failed")
                 if work.fast:
                     self._finish_fast(work)
-                t0 = time.perf_counter()
-                for ev, token in work.slow:
-                    self._process_slow(ev, token)
-                self.stats["t_slow_ms"] += (time.perf_counter() - t0) * 1e3
+                if work.slow:
+                    with self._stage("slow", work.number):
+                        for ev, token in work.slow:
+                            self._process_slow(ev, token)
             except Exception:
                 if work.published:
                     # None of this window's kernel placements will commit,
@@ -529,20 +555,39 @@ class PipelinedWorker(Worker):
 
     # ------------------------------------------------------------ the window
     def _dispatch_window(self, batch: List[Tuple[Evaluation, str]],
-                         lease=None) -> Optional[_WindowWork]:
+                         lease=None, fill: bool = False
+                         ) -> Optional[_WindowWork]:
         """Dispatch one window's kernels chained on the leased usage tail;
         publishes the new tail (ending the lease) once the window's
         launches are all in flight. run() passes the lease it acquired
         BEFORE dequeuing and aborts it if we return unpublished; tests
-        calling without one get the same acquire/abort wrapper here."""
+        calling without one get the same acquire/abort wrapper here.
+        With `fill`, `batch` holds the window's first eval and is filled
+        IN PLACE (run() nacks what it holds when this raises)."""
         if lease is None:
             lease = self._arbiter.acquire(self._stop, holder=self.name)
             if lease.rebased:
                 self.stats["rebases"] += 1
             try:
-                return self._dispatch_window(batch, lease)
+                return self._dispatch_window(batch, lease, fill)
             finally:
                 self._arbiter.abort(lease)  # no-op after a publish
+        self._window_no += 1
+        # The fill stage: everything between the first eval in hand (lease
+        # wait aside) and the dispatch stage. A lone eval pays all of it.
+        with self._stage("fill", self._window_no):
+            if fill:
+                batch.extend(self._fill_window(batch[0][0]))
+            pinned = self._pin_window(batch)
+        if pinned is None:
+            return None
+        with self._stage("dispatch", self._window_no):
+            return self._launch_window(lease, *pinned)
+
+    def _pin_window(self, batch: List[Tuple[Evaluation, str]]
+                    ) -> Optional[tuple]:
+        """(live batch, snapshot, federation birth time) the window places
+        against, or None when every eval of it was redelivered."""
         # The window is in hand: push every eval's nack deadline out NOW
         # (one broker lock round for the whole window). Filling +
         # dispatching + draining a cold window (first compiles) can exceed
@@ -573,8 +618,12 @@ class PipelinedWorker(Worker):
         else:
             snap = self.raft.fsm.state.snapshot()
             fed_born = None
-        t0 = time.perf_counter()
+        return batch, snap, fed_born
 
+    def _launch_window(self, lease, batch: List[Tuple[Evaluation, str]],
+                       snap, fed_born) -> _WindowWork:
+        """The dispatch stage proper (see _dispatch_window)."""
+        number = self._window_no
         nt = self.tindex.nt
         # The lease captured the taint sequence BEFORE handing out the
         # chain: a taint raised in between must surface as external at
@@ -609,9 +658,9 @@ class PipelinedWorker(Worker):
         # the device refresh entirely — it never reads the device tables;
         # an eval that upgrades to device mid-window fetches them lazily
         # inside stack.dispatch.
-        tables = None if host_mode else nt.device_arrays(
-            skip_usage=usage_chain is not None)
-        self.stats["t_refresh_ms"] += (time.perf_counter() - t0) * 1e3
+        with self._stage("refresh", number):
+            tables = None if host_mode else nt.device_arrays(
+                skip_usage=usage_chain is not None)
 
         fast: List[_FastEval] = []
         slow: List[Tuple[Evaluation, str]] = []
@@ -660,8 +709,6 @@ class PipelinedWorker(Worker):
         # order of optimistic placements is valid (each eval sees every
         # placement dispatched before its own, and the plan applier
         # re-verifies all of them against committed state).
-        tl0 = time.perf_counter()
-        i = 0
         # Warm mesh windows carry an exactness-certificate flag (device
         # scalar) per dispatch; the drain stage fetches and enforces them
         # (a failed certificate nacks the window like a failed drain).
@@ -670,41 +717,44 @@ class PipelinedWorker(Worker):
         group_ids: Dict[int, int] = {}
         pend.sort(key=lambda r: group_ids.setdefault(
             id(r.prep) if r.shareable else id(r), len(group_ids)))
-        while i < len(pend):
-            rec = pend[i]
-            j = i + 1
-            if rec.shareable:
-                while (j < len(pend) and pend[j].shareable
-                       and pend[j].prep is rec.prep):
-                    j += 1
-            run = pend[i:j]
-            try:
-                if len(run) >= 2:
-                    if tables is None:
-                        tables = nt.device_arrays(
-                            skip_usage=usage_chain is not None)
-                    res, _ = rec.stack.dispatch_multi(
-                        rec.prep, len(run), usage_override=usage_chain,
-                        tables=tables)
-                    for k, r in enumerate(run):
-                        r.res = _MultiSlice(res, k, rec.prep.p_pad)
-                    usage_chain = res.usage_after
-                    self.stats["multi"] += 1
-                else:
-                    rec.res = rec.stack.dispatch(
-                        rec.prep, usage_override=usage_chain, tables=tables)
-                    usage_chain = rec.res.usage_after
-                fl = getattr(usage_chain, "flag", None)
-                if fl is not None:
-                    mesh_flags.append(fl)
-            except Exception:
-                logger.exception("window launch failed; routing %d evals "
-                                 "to the exact path", len(run))
-                for r in run:
-                    r.fallback = True
-                    fast.remove(r)
-                    slow.append((r.ev, r.token))
-            i = j
+        with self._stage("launch", number):
+            i = 0
+            while i < len(pend):
+                rec = pend[i]
+                j = i + 1
+                if rec.shareable:
+                    while (j < len(pend) and pend[j].shareable
+                           and pend[j].prep is rec.prep):
+                        j += 1
+                run = pend[i:j]
+                try:
+                    if len(run) >= 2:
+                        if tables is None:
+                            tables = nt.device_arrays(
+                                skip_usage=usage_chain is not None)
+                        res, _ = rec.stack.dispatch_multi(
+                            rec.prep, len(run), usage_override=usage_chain,
+                            tables=tables)
+                        for k, r in enumerate(run):
+                            r.res = _MultiSlice(res, k, rec.prep.p_pad)
+                        usage_chain = res.usage_after
+                        self.stats["multi"] += 1
+                    else:
+                        rec.res = rec.stack.dispatch(
+                            rec.prep, usage_override=usage_chain,
+                            tables=tables)
+                        usage_chain = rec.res.usage_after
+                    fl = getattr(usage_chain, "flag", None)
+                    if fl is not None:
+                        mesh_flags.append(fl)
+                except Exception:
+                    logger.exception("window launch failed; routing %d evals "
+                                     "to the exact path", len(run))
+                    for r in run:
+                        r.fallback = True
+                        fast.remove(r)
+                        slow.append((r.ev, r.token))
+                i = j
         # Reorder `fast` to CHAIN order (host-placed recs, then deferred
         # device recs in their sorted launch order): the phantom-usage
         # quarantine in _finish_fast reasons about "evals placed behind a
@@ -714,7 +764,6 @@ class PipelinedWorker(Worker):
         pend_ids = {id(r) for r in pend}
         launched = [r for r in fast if id(r) not in pend_ids]
         fast = launched + [r for r in pend if not r.fallback]
-        self.stats["t_launch_ms"] += (time.perf_counter() - tl0) * 1e3
 
         if fast:
             # Publish the window's device-side usage tail as the shared
@@ -727,8 +776,8 @@ class PipelinedWorker(Worker):
             self._arbiter.publish(lease, usage_chain)
         self.stats["windows"] += 1
         self.stats["slow"] += len(slow)
-        work = _WindowWork(fast=fast, slow=slow, published=bool(fast),
-                           chain_seq=lease.seq,
+        work = _WindowWork(fast=fast, slow=slow, number=number,
+                           published=bool(fast), chain_seq=lease.seq,
                            mesh_flags=mesh_flags or None,
                            fed_born=fed_born)
         # Build the drain plan NOW: the compaction kernels dispatch async
@@ -743,12 +792,12 @@ class PipelinedWorker(Worker):
         # the dispatch handler (which would nack WITHOUT tainting and
         # leave later windows chained on usage that never commits).
         try:
-            work.drain = self._plan_drain(fast)
+            with self._stage("drain_stack", number):
+                work.drain = self._plan_drain(fast)
         except Exception:
             work.failed = True
             if not (self._stop.is_set() or not self.eval_broker.enabled()):
                 logger.exception("pipelined worker: drain plan failed")
-        self.stats["t_dispatch_ms"] += (time.perf_counter() - t0) * 1e3
         # Mesh pipeline roll-up: module counters drain into the declared
         # schema here (workers sharing a mesh may attribute a window to
         # whichever worker drains first; totals are preserved).
@@ -890,11 +939,49 @@ class PipelinedWorker(Worker):
     def _finish_fast(self, work: _WindowWork) -> None:
         """Build + submit plans, wait, batch status updates (packed results
         already drained by stage 2)."""
-        fast, packed = work.fast, work.packed
-        t1 = time.perf_counter()
+        fast = work.fast
+        with self._stage("build", work.number):
+            self._submit_window(work)
+        with self._stage("planwait", work.number):
+            done, eval_updates = self._await_window(work)
+        with self._stage("evalupd", work.number):
+            if eval_updates:
+                self.raft.apply(MessageType.EvalUpdate,
+                                {"Evals": eval_updates})
+        self.stats["fast"] += len(done)
+        if done:
+            # ONE broker lock round acks the whole window; per-eval races
+            # (redelivered / token rotated) come back as failures instead
+            # of aborting the rest of the window's acks.
+            try:
+                for eval_id, e in self.eval_broker.ack_batch(
+                        [(rec.ev.ID, rec.token) for rec in done]):
+                    logger.debug("worker: ack skipped for %s: %s", eval_id, e)
+            except Exception:
+                logger.exception("worker: window ack failed")
+        for rec in done:
+            if rec.span is not None:
+                rec.span.set_attr("path", "fast")
+                rec.span.finish()
+        for rec in fast:
+            if rec.fallback:
+                self.stats["fallback"] += 1
+                if rec.span is not None:
+                    # Tail-retention rule: a fallback marks the trace.
+                    rec.span.event("fallback", eval=rec.ev.ID)
+                    rec.span.finish()
+                self._process_slow(rec.ev, rec.token)
+            elif rec.stale:
+                self.stats["stale"] += 1
+                if rec.span is not None:
+                    rec.span.event("stale", eval=rec.ev.ID)
+                    rec.span.finish()
 
-        # Build and enqueue plans back-to-back: the applier verifies plan i
-        # while we materialize plan i+1's ports host-side.
+    def _submit_window(self, work: _WindowWork) -> None:
+        """The build stage: packed results -> plans, enqueued back-to-back
+        (the applier verifies plan i while we materialize plan i+1's
+        ports host-side)."""
+        fast, packed = work.fast, work.packed
         nt = self.tindex.nt
         # The kernels ran chained: eval k saw evals 1..k-1's placements.
         # The shared accumulator can reproduce that chain host-side so
@@ -904,31 +991,30 @@ class PipelinedWorker(Worker):
         # never does.
         acc = WindowAccumulator(nt.n_rows)
         submit: List[_FastEval] = []
-        for rec, cr in zip(fast, packed):
-            if rec.stale:
-                continue  # redelivered between stages: abandoned
-            tc0 = time.perf_counter()
-            try:
-                ok = rec.stack.collect_build(
-                    rec.prep, cr, rec.ev.ID, rec.plan.Job, rec.place,
-                    rec.plan, rec.failed_tg_allocs, acc)
-            except Exception:
-                logger.exception("collect failed for eval %s", rec.ev.ID)
-                rec.fallback = True
-                continue
-            if not ok:
-                # Port collision against the cached index (or a node that
-                # vanished mid-window): rare; the sync path's banned-row
-                # retry loop owns it.
-                rec.fallback = True
-                continue
-            self.stats["t_collect_ms"] += (time.perf_counter() - tc0) * 1e3
-            if rec.plan.is_no_op() and not rec.failed_tg_allocs:
-                rec.fallback = True  # nothing placeable; let sync path decide
-                continue
-            rec.plan.EvalToken = rec.token
-            stamp_fed_born(rec.plan, work.fed_born)
-            submit.append(rec)
+        with self._stage("collect", work.number):
+            for rec, cr in zip(fast, packed):
+                if rec.stale:
+                    continue  # redelivered between stages: abandoned
+                try:
+                    ok = rec.stack.collect_build(
+                        rec.prep, cr, rec.ev.ID, rec.plan.Job, rec.place,
+                        rec.plan, rec.failed_tg_allocs, acc)
+                except Exception:
+                    logger.exception("collect failed for eval %s", rec.ev.ID)
+                    rec.fallback = True
+                    continue
+                if not ok:
+                    # Port collision against the cached index (or a node that
+                    # vanished mid-window): rare; the sync path's banned-row
+                    # retry loop owns it.
+                    rec.fallback = True
+                    continue
+                if rec.plan.is_no_op() and not rec.failed_tg_allocs:
+                    rec.fallback = True  # nothing placeable: sync path decides
+                    continue
+                rec.plan.EvalToken = rec.token
+                stamp_fed_born(rec.plan, work.fed_born)
+                submit.append(rec)
         # ONE broker lock round re-arms every submitting eval's deadline
         # and surfaces redeliveries; ONE queue lock round enqueues the
         # window's plans contiguously in chain order (a second worker's
@@ -957,10 +1043,12 @@ class PipelinedWorker(Worker):
                     if not rec.stale and rec.pending is None:
                         rec.fallback = True
 
-        t2 = time.perf_counter()
-        self.stats["t_build_ms"] += (t2 - t1) * 1e3
-
-        # Wait for the applier; anything not fully committed re-runs sync.
+    def _await_window(self, work: _WindowWork
+                      ) -> Tuple[List[_FastEval], List[Evaluation]]:
+        """The plan-wait stage: wait for the applier (anything not fully
+        committed re-runs sync), settle the window's taint decision, and
+        return (committed recs, their status updates)."""
+        fast = work.fast
         for rec in fast:
             if rec.fallback or rec.stale or rec.pending is None:
                 continue
@@ -1048,40 +1136,7 @@ class PipelinedWorker(Worker):
                 continue
             eval_updates.extend(self._status_evals(rec))
             done.append(rec)
-
-        t3 = time.perf_counter()
-        self.stats["t_planwait_ms"] += (t3 - t2) * 1e3
-        if eval_updates:
-            self.raft.apply(MessageType.EvalUpdate, {"Evals": eval_updates})
-        self.stats["t_evalupd_ms"] += (time.perf_counter() - t3) * 1e3
-        self.stats["fast"] += len(done)
-        if done:
-            # ONE broker lock round acks the whole window; per-eval races
-            # (redelivered / token rotated) come back as failures instead
-            # of aborting the rest of the window's acks.
-            try:
-                for eval_id, e in self.eval_broker.ack_batch(
-                        [(rec.ev.ID, rec.token) for rec in done]):
-                    logger.debug("worker: ack skipped for %s: %s", eval_id, e)
-            except Exception:
-                logger.exception("worker: window ack failed")
-        for rec in done:
-            if rec.span is not None:
-                rec.span.set_attr("path", "fast")
-                rec.span.finish()
-        for rec in fast:
-            if rec.fallback:
-                self.stats["fallback"] += 1
-                if rec.span is not None:
-                    # Tail-retention rule: a fallback marks the trace.
-                    rec.span.event("fallback", eval=rec.ev.ID)
-                    rec.span.finish()
-                self._process_slow(rec.ev, rec.token)
-            elif rec.stale:
-                self.stats["stale"] += 1
-                if rec.span is not None:
-                    rec.span.event("stale", eval=rec.ev.ID)
-                    rec.span.finish()
+        return done, eval_updates
 
     def _status_evals(self, rec: _FastEval) -> List[Evaluation]:
         """Terminal status (+ blocked follow-up) for one fast eval, matching
@@ -1125,7 +1180,6 @@ class PipelinedWorker(Worker):
         stack on device first — arity padded to the configured window size
         so XLA compiles ONE program per packed shape, never one per
         distinct window fill level."""
-        t0 = time.perf_counter()
         layout: list = [None] * len(fast)
         fetches: dict = {}
         # parent id -> (parent, [(pos-in-fast, slice-index)], prep)
@@ -1201,7 +1255,6 @@ class PipelinedWorker(Worker):
                 for i, rec in group:
                     layout[i] = ("host", kernels.compact_host(
                         np.asarray(rec.res.packed), rec.prep.n_valid))
-        self.stats["t_drain_stack_ms"] += (time.perf_counter() - t0) * 1e3
         return _DrainPlan(fetches=fetches, layout=layout)
 
     def _drain_window(self, work: _WindowWork) -> list:
@@ -1225,13 +1278,11 @@ class PipelinedWorker(Worker):
         if plan.fetches or flags:
             import jax
 
-            t0 = time.perf_counter()
             # The warm-mesh exactness certificates (tiny device scalars)
             # ride the SAME blocking call as the compaction outputs, so
             # the one-host-sync invariant above survives the mesh path.
-            flags_h, fetched = jax.device_get((flags, plan.fetches))
-            self.stats["t_drain_fetch_ms"] += \
-                (time.perf_counter() - t0) * 1e3
+            with self._stage("drain_fetch", work.number):
+                flags_h, fetched = jax.device_get((flags, plan.fetches))
             if any(float(f) > 0 for f in flags_h):
                 # Warm mesh windows are exact only when the certificate
                 # held (kernels.py 'shard-local mesh pipeline'): a failed

@@ -553,6 +553,8 @@ class PlanApplier:
                     return  # queue disabled
                 live = []
                 for p in batch:
+                    metrics.measure_since(("nomad", "plan", "queue_wait"),
+                                          p.enqueued)
                     if p.cancelled:
                         # Abandoned chunk (its submitter's earlier chunk
                         # failed): answer the future, commit nothing.

@@ -23,6 +23,9 @@ class PendingPlan:
 
     def __init__(self, plan: Plan):
         self.plan = plan
+        # Made inside enqueue/enqueue_all: the applier samples
+        # nomad.plan.queue_wait from this when it takes the plan up.
+        self.enqueued = time.monotonic()
         self._event = threading.Event()
         self._result: Optional[PlanResult] = None
         self._error: Optional[Exception] = None

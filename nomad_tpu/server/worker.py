@@ -431,8 +431,6 @@ class Worker:
         paces the poll (1-10ms jittered) under the RAFT_SYNC_LIMIT
         deadline; the shutdown-aware sleep aborts the wait the moment
         stop() is called instead of burning out the deadline."""
-        start = time.monotonic()
-
         def check() -> None:
             if self.raft.fsm.state.latest_index() < index:
                 raise TimeoutError(f"timed out waiting for index {index}")
@@ -442,11 +440,8 @@ class Worker:
                              retry_on=(TimeoutError,),
                              sleep=self._stop.wait,
                              trace_events=False)  # ms-cadence poll
-        try:
+        with metrics.measure(("nomad", "worker", "wait_for_index")):
             policy.call(check)
-        finally:
-            metrics.measure_since(("nomad", "worker", "wait_for_index"),
-                                  start)
 
     def _invoke_scheduler(self, ev: Evaluation, token: str,
                           min_index: Optional[int] = None) -> None:
@@ -461,8 +456,8 @@ class Worker:
         re-runs (pipelined slow path — whose plan just failed against
         possibly-stale state) pass None and always get a direct fresh
         snapshot, preserving the exact-path oracle semantics."""
-        start = time.monotonic()
-        try:
+        with metrics.measure(
+                ("nomad", "worker", "invoke_scheduler", ev.Type)):
             with trace.resume(trace.linked("eval", ev.ID),
                               "worker.invoke_scheduler",
                               eval=ev.ID, type=ev.Type):
@@ -492,9 +487,6 @@ class Worker:
                                       self.tindex, logger,
                                       impl=self.scheduler_impl)
                 sched.process(ev)
-        finally:
-            metrics.measure_since(
-                ("nomad", "worker", "invoke_scheduler", ev.Type), start)
 
     # ------------------------------------------------------------ ack / nack
     def _send_ack(self, eval_id: str, token: str) -> None:
@@ -524,14 +516,11 @@ class Worker:
 
     def submit_plan(self, plan: Plan) -> Tuple[Optional[PlanResult], Optional[object]]:
         """(reference: worker.go:285-342)"""
-        start = time.monotonic()
         plan.EvalToken = self._token
         self._stamp_fed_born(plan)
-        try:
+        with metrics.measure(("nomad", "worker", "submit_plan")):
             with trace.span("worker.submit_plan", eval=plan.EvalID):
                 result = self.backend.submit_plan(plan)
-        finally:
-            metrics.measure_since(("nomad", "worker", "submit_plan"), start)
 
         # If the state is behind the plan result, refresh before retrying.
         # The wait runs against the LOCAL replica: followers see the applied
@@ -569,12 +558,11 @@ class Worker:
         account, and retrying against the same stale snapshot would
         burn the eval's retry budget to a terminal Failed where a nack
         redelivers it to a healthier worker or the new leader."""
-        start = time.monotonic()
         for plan in plans:
             plan.EvalToken = self._token
             self._stamp_fed_born(plan)
         partial = False
-        try:
+        with metrics.measure(("nomad", "worker", "submit_plan")):
             with trace.span("worker.submit_plans", chunks=len(plans)):
                 submit = getattr(self.backend, "submit_plans", None)
                 if submit is not None:
@@ -603,8 +591,6 @@ class Worker:
                 if partial:
                     trace.add_event("fallback", kind="partial_plan_sweep",
                                     committed=len(results))
-        finally:
-            metrics.measure_since(("nomad", "worker", "submit_plan"), start)
         refresh = max((r.RefreshIndex for r in results if r is not None),
                       default=0)
         if partial:

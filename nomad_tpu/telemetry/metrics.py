@@ -15,9 +15,9 @@ from __future__ import annotations
 
 import logging
 import socket
+import sys
 import threading
 import time
-from contextlib import contextmanager
 from typing import Any, Dict, Iterable, List, Optional, Tuple
 
 from nomad_tpu.analysis import guarded_by, requires_lock
@@ -29,6 +29,56 @@ Key = Tuple[str, ...]
 
 def _name(key: Iterable[str]) -> str:
     return ".".join(str(p) for p in key)
+
+
+_annotation: Any = None  # jax.profiler.TraceAnnotation, once JAX is loaded
+
+
+def _trace_annotation() -> Any:
+    """jax.profiler.TraceAnnotation if THIS process has imported JAX, else
+    None. Never imports it: a client agent or CLI process that runs no
+    kernel stays JAX-free. Looked up in sys.modules until found, then
+    kept (a server imports JAX at start-up, before its first measure)."""
+    global _annotation
+    if _annotation is None:
+        profiler = getattr(sys.modules.get("jax"), "profiler", None)
+        _annotation = getattr(profiler, "TraceAnnotation", None)
+    return _annotation
+
+
+class Measure:
+    """One timed stage: a registry sample in ms AND a span on the
+    profiler's timeline, from one call. The span is a
+    jax.profiler.TraceAnnotation named by the dotted key and carrying
+    `attrs`: inert while no profiler session runs, and on the same
+    nanosecond clock as the device's program events while one does, so a
+    trace shows host stages and device programs together. After the block
+    `ms` holds the sample."""
+
+    __slots__ = ("_registry", "_key", "_attrs", "_span", "_start", "ms")
+
+    def __init__(self, registry: "MetricsRegistry", key: Key,
+                 attrs: Dict[str, Any]) -> None:
+        self._registry = registry
+        self._key = tuple(key)
+        self._attrs = attrs
+        self._span = None
+        self.ms = 0.0
+
+    def __enter__(self) -> "Measure":
+        annotation = _trace_annotation()
+        if annotation is not None:
+            self._span = annotation(_name(self._key), **self._attrs)
+            self._span.__enter__()
+        self._start = time.monotonic()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        self.ms = (time.monotonic() - self._start) * 1000.0
+        if self._span is not None:
+            self._span.__exit__(*exc)
+        self._registry.add_sample(self._key, self.ms)
+        return False
 
 
 class _Aggregate:
@@ -249,13 +299,9 @@ class MetricsRegistry:
         """`start` is a time.monotonic() stamp; records milliseconds."""
         self.add_sample(tuple(key), (time.monotonic() - start) * 1000.0)
 
-    @contextmanager
-    def measure(self, key: Key):
-        start = time.monotonic()
-        try:
-            yield
-        finally:
-            self.measure_since(key, start)
+    def measure(self, key: Key, **attrs) -> Measure:
+        """Context manager: sample + profiler span (see Measure)."""
+        return Measure(self, key, attrs)
 
     def snapshot(self) -> Dict[str, Any]:
         return self.inmem.snapshot()

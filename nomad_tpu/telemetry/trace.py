@@ -47,7 +47,7 @@ from . import metrics
 __all__ = [
     "Span", "configure", "is_enabled", "root_span", "span", "resume",
     "start_from", "attach", "current", "add_event", "inject", "link",
-    "linked", "linked_entry", "record_span", "traces", "get_trace",
+    "linked", "record_span", "traces", "get_trace",
     "export_chrome", "clear", "status",
 ]
 
@@ -220,7 +220,7 @@ _enabled = False
 _sample_ratio = 1.0
 _ring_max = _DEFAULT_RING
 _traces: "OrderedDict[str, _Trace]" = OrderedDict()
-_links: "OrderedDict[tuple, tuple]" = OrderedDict()  # (kind,key)->(carrier,t)
+_links: "OrderedDict[tuple, dict]" = OrderedDict()  # (kind, key) -> carrier
 _tls = threading.local()
 
 
@@ -446,21 +446,12 @@ def link(kind: str, key: str) -> None:
     if carrier is None:
         return
     with _lock:
-        _links[(kind, key)] = (carrier, time.monotonic())
+        _links[(kind, key)] = carrier
         while len(_links) > _LINK_CAP:
             _links.popitem(last=False)
 
 
 def linked(kind: str, key: str) -> Optional[Dict[str, Any]]:
-    if not _enabled:
-        return None
-    with _lock:
-        entry = _links.get((kind, key))
-    return entry[0] if entry is not None else None
-
-
-def linked_entry(kind: str, key: str) -> Optional[tuple]:
-    """(carrier, monotonic-link-time) — queue-wait reconstruction."""
     if not _enabled:
         return None
     with _lock:

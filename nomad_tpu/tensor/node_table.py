@@ -753,7 +753,7 @@ def _refresh_program():
     import jax.numpy as jnp
 
     @jax.jit
-    def refresh(cap, sc, us, pk):
+    def node_table_refresh(cap, sc, us, pk):  # kernels.PROGRAM_NAMES
         rows = pk[:, 0].astype(jnp.int32)
         cap_v = pk[:, 1:1 + RES_DIMS]
         sc_v = pk[:, 1 + RES_DIMS:3 + RES_DIMS]
@@ -761,7 +761,7 @@ def _refresh_program():
         return (cap.at[rows].set(cap_v), sc.at[rows].set(sc_v),
                 us.at[rows].set(us_v))
 
-    return refresh
+    return node_table_refresh
 
 
 def _scatter_refresh(capacity, score_cap, usage, packed):

@@ -1,0 +1,376 @@
+"""One stage-span primitive (ISSUE 26): metrics.measure is a registry
+sample AND a span on the profiler's timeline; PipelinedWorker._stage adds
+the declared stats key from the same call; the broker's and the plan
+queue's waits are sampled where the waiting happens, tracing on or off;
+the device programs carry the names kernels.PROGRAM_NAMES declares."""
+
+import glob
+import json
+import os
+import re
+import subprocess
+import sys
+import time
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from nomad_tpu import mock
+from nomad_tpu.scheduler import kernels
+from nomad_tpu.server import Server, ServerConfig, pipelined_worker
+from nomad_tpu.server.pipelined_worker import (STATS_TIMERS_MS,
+                                               PipelinedWorker)
+from nomad_tpu.structs.structs import EvalStatusComplete
+from nomad_tpu.telemetry import metrics, trace
+from nomad_tpu.tensor import node_table
+
+from helpers import wait_for  # noqa: E402
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+class Spans:
+    """Stands where jax.profiler.TraceAnnotation does and keeps what was
+    opened and closed, in order."""
+
+    def __init__(self):
+        self.opened, self.closed, self.closed_by = [], [], []
+
+    def __call__(self, name, **attrs):
+        outer = self
+
+        class _Span:
+            def __enter__(self):
+                outer.opened.append((name, attrs))
+
+            def __exit__(self, *exc):
+                outer.closed.append(name)
+                outer.closed_by.append((name, attrs.get("worker")))
+
+        return _Span()
+
+
+class Samples:
+    """A registry sink that keeps every timer sample."""
+
+    def __init__(self):
+        self.rows = []
+
+    def add_sample(self, key, value):
+        self.rows.append((".".join(key), value))
+
+    def set_gauge(self, key, value):
+        pass
+
+    def incr_counter(self, key, value):
+        pass
+
+    def of(self, name):
+        return [v for n, v in self.rows if n == name]
+
+
+@pytest.fixture()
+def spans(monkeypatch):
+    fake = Spans()
+    monkeypatch.setattr(metrics, "_annotation", fake)
+    return fake
+
+
+@pytest.fixture()
+def samples():
+    sink = Samples()
+    metrics.registry.add_sink(sink)
+    yield sink
+    with metrics.registry._lock:
+        metrics.registry._sinks = [s for s in metrics.registry._sinks
+                                   if s is not sink]
+
+
+# ------------------------------------------------------------------ measure
+def test_measure_is_a_sample_and_a_span_from_one_call(spans, samples):
+    with metrics.measure(("nomad", "plan", "apply"), batch=3) as timed:
+        time.sleep(0.002)
+        assert spans.closed == []
+    assert spans.opened == [("nomad.plan.apply", {"batch": 3})]
+    assert spans.closed == ["nomad.plan.apply"]
+    assert samples.of("nomad.plan.apply") == [timed.ms]
+    assert 2.0 <= timed.ms < 500.0
+
+
+def test_measure_samples_and_closes_the_span_when_the_block_raises(
+        spans, samples):
+    with pytest.raises(KeyError):
+        with metrics.measure(("nomad", "fsm", "boom")):
+            raise KeyError("x")
+    assert spans.closed == ["nomad.fsm.boom"]
+    assert len(samples.of("nomad.fsm.boom")) == 1
+
+
+def test_measure_lands_on_the_profilers_timeline_with_its_attrs(tmp_path):
+    """The real thing: a profiler session sees the span on a host plane,
+    named by the dotted key, with the attrs as the event's stats."""
+    assert metrics._trace_annotation() is jax.profiler.TraceAnnotation
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    options.host_tracer_level = 1
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        with metrics.measure(("nomad", "worker", "dispatch"), worker="w-7",
+                             window=41):
+            time.sleep(0.003)
+    finally:
+        jax.profiler.stop_trace()
+    [path] = glob.glob(str(tmp_path / "plugins" / "profile" / "*"
+                           / "*.xplane.pb"))
+    found = [(e.duration_ns, dict(e.stats))
+             for plane in jax.profiler.ProfileData.from_file(path).planes
+             if plane.name.startswith("/host:")
+             for line in plane.lines for e in line.events
+             if e.name == "nomad.worker.dispatch"]
+    assert len(found) == 1
+    duration_ns, stats = found[0]
+    assert duration_ns >= 3e6
+    assert str(stats["worker"]) == "w-7" and int(stats["window"]) == 41
+
+
+def test_without_jax_measure_is_a_plain_timer_and_imports_nothing():
+    code = (
+        "import sys\n"
+        "from nomad_tpu.telemetry import metrics\n"
+        "with metrics.measure(('nomad', 'x', 'y'), a=1) as timed:\n"
+        "    pass\n"
+        "assert 'jax' not in sys.modules, 'measure imported jax'\n"
+        "assert metrics._trace_annotation() is None\n"
+        "[s] = metrics.snapshot()['Samples']\n"
+        "assert s['Name'] == 'nomad.x.y' and s['Count'] == 1\n"
+        "assert timed.ms >= 0.0\n"
+        "print('plain')\n")
+    proc = subprocess.run([sys.executable, "-c", code], cwd=ROOT,
+                          capture_output=True, text=True, timeout=120)
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    assert proc.stdout.strip() == "plain"
+
+
+# ------------------------------------------------------------ worker stages
+with open(pipelined_worker.__file__) as _f:
+    WORKER_SOURCE = _f.read()
+STAGES = sorted(set(re.findall(r'_stage\(\s*"([a-z_]+)"', WORKER_SOURCE)))
+
+
+@pytest.fixture(scope="module")
+def served():
+    srv = Server(ServerConfig(num_schedulers=0, pipelined_scheduling=True,
+                              scheduler_window=8))
+    srv.establish_leadership()
+    for _ in range(4):
+        srv.node_register(mock.node())
+    worker = PipelinedWorker(srv.raft, srv.eval_broker, srv.plan_queue,
+                             srv.blocked_evals, srv.tindex,
+                             ["service", "batch", "system"], window=8)
+    worker.name = "w-test"
+    yield srv, worker
+    srv.shutdown()
+
+
+def test_the_worker_times_every_stage_the_issue_lists():
+    assert set(STAGES) == {"lease", "fill", "dispatch", "refresh", "launch",
+                           "drain_stack", "drain", "drain_fetch", "build",
+                           "collect", "planwait", "evalupd", "slow"}
+    # The per-eval timers of _try_dispatch_fast are all that is left of
+    # the hand-written pairs: they add to `stats` alone, by design.
+    assert WORKER_SOURCE.count("perf_counter()") == 5
+
+
+@pytest.mark.parametrize("stage", STAGES)
+def test_a_stage_is_a_stats_key_a_sample_and_a_span(served, spans, samples,
+                                                    stage):
+    _, worker = served
+    key = f"t_{stage}_ms"
+    assert key in STATS_TIMERS_MS
+    before = worker.stats[key]
+    with worker._stage(stage, 12):
+        time.sleep(0.001)
+    [sample] = samples.of(f"nomad.worker.{stage}")
+    assert sample >= 1.0
+    assert worker.stats[key] - before == pytest.approx(sample)
+    assert spans.opened == [(f"nomad.worker.{stage}",
+                             {"worker": "w-test", "window": 12})]
+    assert spans.closed == [f"nomad.worker.{stage}"]
+
+
+def test_the_spans_of_one_window_share_its_number(served, spans, samples):
+    srv, worker = served
+    for _ in range(3):
+        srv.job_register(mock.job())
+    fill0, wait0 = worker.stats["t_fill_ms"], worker.stats["t_stagewait_ms"]
+    first = worker._dequeue_first()
+    batch = [first]
+    work = worker._dispatch_window(batch, fill=True)
+    assert len(batch) == 3 and len(work.fast) == 3  # filled in place
+    worker._hand_off(worker._drain_q, work)
+    assert worker._drain_q.get() is work
+    time.sleep(0.002)
+    worker._enter_stage(work)
+    with worker._stage("drain", work.number):
+        work.packed = worker._drain_window(work)
+    worker._finish_fast(work)
+    assert worker.stats["fast"] >= 3
+    assert worker.stats["t_stagewait_ms"] - wait0 >= 2.0
+    assert worker.stats["t_fill_ms"] > fill0
+    by_name = {}
+    for name, attrs in spans.opened:  # another test's worker may still run
+        if name.startswith("nomad.worker.") \
+                and attrs.get("worker") == "w-test":
+            by_name.setdefault(name[len("nomad.worker."):], []).append(attrs)
+    # One span a stage a window, none per eval; all carry this window.
+    for stage in ("fill", "dispatch", "refresh", "launch", "drain_stack",
+                  "drain", "build", "collect", "planwait", "evalupd"):
+        assert by_name[stage] == [{"worker": "w-test",
+                                   "window": work.number}], stage
+    # Nesting as the timeline shows it: refresh, launch and drain_stack
+    # open and close inside dispatch; collect inside build.
+    order = [n for n, a in spans.opened if a.get("worker") == "w-test"]
+    closed = [n for n, who in spans.closed_by if who == "w-test"]
+    assert order.index("nomad.worker.fill") \
+        < order.index("nomad.worker.dispatch") \
+        < order.index("nomad.worker.refresh")
+    assert closed.index("nomad.worker.drain_stack") \
+        < closed.index("nomad.worker.dispatch")
+    assert closed.index("nomad.worker.collect") \
+        < closed.index("nomad.worker.build")
+    # The registry saw the same stages, and the plan applier's own.
+    assert samples.of("nomad.worker.planwait")
+    assert samples.of("nomad.plan.apply")
+    assert any(n.startswith("nomad.fsm.") for n, _ in samples.rows)
+
+
+# ---------------------------------------------------- broker and plan queue
+@pytest.fixture()
+def dev_server():
+    srv = Server(ServerConfig(num_schedulers=1, dev_mode=True))
+    srv.establish_leadership()
+    for _ in range(2):
+        srv.node_register(mock.node())
+    yield srv
+    srv.shutdown()
+    trace.configure(enabled=False, sample_ratio=1.0, ring=128)
+    trace.clear()
+
+
+def _system_job():
+    job = mock.system_job()
+    task = job.TaskGroups[0].Tasks[0]
+    task.Resources.DiskMB = 150
+    task.Resources.Networks = []
+    task.Services = []
+    return job
+
+
+def _run_job(srv, job):
+    eval_id, _, _ = srv.job_register(job)
+    assert wait_for(lambda: (
+        (e := srv.state.eval_by_id(eval_id)) is not None
+        and e.Status == EvalStatusComplete))
+    return eval_id
+
+
+@pytest.mark.parametrize("job", [mock.job, _system_job],
+                         ids=["service", "system"])
+def test_the_waits_are_sampled_with_tracing_off(dev_server, samples, job):
+    assert not trace.is_enabled()
+    _run_job(dev_server, job())
+    [wait] = samples.of("nomad.broker.wait")
+    assert 0.0 <= wait < 5000.0
+    queued = samples.of("nomad.plan.queue_wait")
+    assert queued and all(0.0 <= q < 5000.0 for q in queued)
+
+
+def test_the_dapper_broker_wait_span_is_the_registrys_sample(dev_server,
+                                                             samples):
+    trace.configure(enabled=True, sample_ratio=1.0, ring=128)
+    with trace.root_span("test.register"):
+        eval_id = _run_job(dev_server, mock.job())
+    [wait] = samples.of("nomad.broker.wait")
+    found = [s for t in trace.traces()
+             for s in trace.get_trace(t["TraceID"])["Spans"]
+             if s["Name"] == "broker.wait"
+             and s["Attrs"].get("eval") == eval_id]
+    assert len(found) == 1
+    # One stamp, read twice a few microseconds apart.
+    assert found[0]["DurationMs"] == pytest.approx(wait, abs=1.0)
+
+
+def test_a_redelivered_eval_waits_from_its_re_entry(samples):
+    from nomad_tpu.server.eval_broker import EvalBroker
+
+    broker = EvalBroker(nack_timeout=5.0)
+    broker.set_enabled(True)
+    ev = mock.eval()
+    broker.enqueue(ev)
+    time.sleep(0.02)
+    got, token = broker.dequeue([ev.Type], timeout=1.0)
+    broker.nack(got.ID, token)
+    got, token = broker.dequeue([ev.Type], timeout=1.0)
+    first, second = samples.of("nomad.broker.wait")
+    assert first >= 20.0 and second < first
+    broker.ack(got.ID, token)
+    assert broker._ready_at == {}
+    broker.set_enabled(False)
+
+
+# ------------------------------------------------------------ program names
+def _f32(*shape):
+    return jax.ShapeDtypeStruct(shape, jnp.float32)
+
+
+def _placement_args(n, p, reset):
+    args = [_f32(n, 5), _f32(n, 2), _f32(n, 5),
+            jax.ShapeDtypeStruct((1, n), jnp.bool_),
+            jax.ShapeDtypeStruct((n,), jnp.int32), None,
+            jax.ShapeDtypeStruct((p,), jnp.int32),
+            jax.ShapeDtypeStruct((p,), jnp.bool_), _f32(n), _f32(),
+            jax.ShapeDtypeStruct((), jnp.bool_),
+            jax.ShapeDtypeStruct((n,), jnp.bool_)]
+    if reset:
+        args.append(jax.ShapeDtypeStruct((p,), jnp.bool_))
+    return args
+
+
+def _lowered(name):
+    n, p = 16, 8
+    if name in ("place_batch", "place_batch_multi"):
+        args = _placement_args(n, p, reset=name.endswith("multi"))
+        args[5] = _f32(p, 5)
+        return getattr(kernels, name).lower(*args)
+    if name == "place_batch_keyed":
+        args = _placement_args(n, p, reset=True)
+        args[5] = _f32(1, 5)
+        return kernels._keyed_program(None, 8).lower(*args)
+    if name == "compact_window":
+        return kernels.compact_window.lower(
+            _f32(2, p, 3), jax.ShapeDtypeStruct((2, p), jnp.bool_),
+            jax.ShapeDtypeStruct((2,), jnp.int32))
+    if name == "node_table_refresh":
+        return node_table._refresh_program().lower(
+            _f32(n, 5), _f32(n, 2), _f32(n, 5),
+            _f32(4, 3 + 2 * node_table.RES_DIMS))
+    raise AssertionError(f"no program known for {name!r}: add it here")
+
+
+@pytest.mark.parametrize("name", kernels.PROGRAM_NAMES)
+def test_a_program_carries_its_declared_name(name):
+    text = _lowered(name).as_text()
+    assert re.search(r"module @jit_%s\b" % re.escape(name), text), \
+        text[:120]
+
+
+def test_the_benchmarks_kernel_metric_still_finds_the_placement_programs():
+    with open(os.path.join(ROOT, "benchmark", "layer_metrics",
+                           "kernel_ms.storm.json")) as f:
+        match = json.load(f)["args"]["match"]
+    hit = {name for name in kernels.PROGRAM_NAMES
+           if any(m in "jit_" + name for m in match)}
+    assert {"place_batch_keyed", "place_batch_multi", "place_batch",
+            "compact_window"} <= hit
+    assert "node_table_refresh" not in hit  # a refresh is not a placement

@@ -324,6 +324,30 @@ class GenericStack:
         self._cand_mask = cand_mask
         self.elig = elig
 
+    def tg_eligibility(self, tgs: Sequence[TaskGroup]) -> tuple:
+        """The job's and its unique task groups' eligibility, read through
+        (and so filling) this job's per-job views of the eligibility cache:
+        (unique TGs, TG name -> index, job mask, per unique TG its merged
+        constraints and mask). prepare_batch assembles its masks from it;
+        a job that adopts another's PreparedBatch (value-identical by
+        _prep_sig) calls it alone, so that a blocked eval still reports ITS
+        class eligibility and escaped flag, which are read by job id."""
+        job = self.job
+        unique_tgs: List[TaskGroup] = []
+        tg_index: Dict[str, int] = {}
+        for tg in tgs:
+            if tg.Name not in tg_index:
+                tg_index[tg.Name] = len(unique_tgs)
+                unique_tgs.append(tg)
+        job_mask, _, _ = self.elig.job_mask(job.ID, job.Constraints)
+        per_tg = []
+        for tg in unique_tgs:
+            cons = task_group_constraints(tg)
+            m, _, _ = self.elig.tg_mask(job.ID, tg.Name, cons.constraints,
+                                        cons.drivers)
+            per_tg.append((cons, m))
+        return unique_tgs, tg_index, job_mask, per_tg
+
     def adopt_shared(self, job: Job, elig: ClassEligibility) -> None:
         """Wire the stack for a tensor-sweep evaluation: the job plus the
         table-wide shared eligibility (TensorIndex.shared_elig), WITHOUT
@@ -418,20 +442,10 @@ class GenericStack:
         job = self.job
 
         # Per-unique-TG eligibility masks and demand vectors.
-        unique_tgs: List[TaskGroup] = []
-        tg_index: Dict[str, int] = {}
-        for tg in tgs:
-            if tg.Name not in tg_index:
-                tg_index[tg.Name] = len(unique_tgs)
-                unique_tgs.append(tg)
-
-        job_mask, _, _ = self.elig.job_mask(job.ID, job.Constraints)
+        unique_tgs, tg_index, job_mask, per_tg = self.tg_eligibility(tgs)
         tg_masks = np.zeros((len(unique_tgs), nt.n_rows), dtype=bool)
         tg_demands = np.zeros((len(unique_tgs), RES_DIMS), dtype=np.float32)
-        for i, tg in enumerate(unique_tgs):
-            cons = task_group_constraints(tg)
-            m, _, _ = self.elig.tg_mask(job.ID, tg.Name, cons.constraints,
-                                        cons.drivers)
+        for i, (cons, m) in enumerate(per_tg):
             tg_masks[i] = self._cand_mask & job_mask & m
             tg_demands[i] = resources_vec(cons.size)
 

@@ -81,7 +81,6 @@ from nomad_tpu.scheduler.util import (
     BLOCKED_EVAL_FAILED_PLACEMENTS,
     diff_allocs,
     materialize_task_groups,
-    ready_nodes_in_dcs,
     tainted_nodes,
 )
 from nomad_tpu.structs import AllocMetric, Evaluation, Plan
@@ -127,17 +126,20 @@ STATS_COUNTERS = (
     "fed_stale",       # windows nacked for a stale federation snapshot
     #                    (applier StaleSnapshotError -> exactly-once
     #                    redelivery onto a fresh snapshot)
+    "node_ctx_hit",    # node-context lookups (one a window a datacenter
+    "node_ctx_miss",   # set) served from TensorIndex's memo / built anew
 )
 STATS_TIMERS_MS = (
     "t_lease_ms",        # waiting for the shared chain-lease (ChainArbiter)
     "t_fill_ms",         # window fill, raft-sync barrier, snapshot
     "t_refresh_ms",      # node-table device refresh at dispatch
+    "t_nodectx_ms",      # node-context lookup, or its build on a miss
     "t_diff_ms",         # job diff/alloc filtering per eval
     "t_prep_ms",         # PreparedBatch assembly (device inputs)
     "t_launch_ms",       # kernel launches (host or device, async)
     "t_drain_stack_ms",  # drain-plan build: stack + compaction dispatch
     #                      (runs in the DISPATCH stage since round 6)
-    "t_dispatch_ms",     # whole dispatch stage (includes the five above)
+    "t_dispatch_ms",     # whole dispatch stage (includes the six above)
     "t_drain_ms",        # whole drain stage
     "t_drain_fetch_ms",  # blocking device->host readback
     "t_collect_ms",      # packed output -> plan allocations
@@ -664,17 +666,22 @@ class PipelinedWorker(Worker):
 
         fast: List[_FastEval] = []
         slow: List[Tuple[Evaluation, str]] = []
-        # Shared per-window: every eval sees the same snapshot, so the ready
-        # node list, candidate mask, class-eligibility cache, AND the node
+        # Shared per-window: every eval sees the same snapshot, so the
+        # node context (ready nodes, candidate mask, class eligibility:
+        # TensorIndex.node_context keeps it ACROSS windows, until the
+        # nodes table changes) is looked up once per window, and the node
         # table's device arrays (whose dirty-row refresh is a blocking
-        # host->device transfer) are built once per window, not once per
+        # host->device transfer) are fetched once per window, not once per
         # eval. The tie-break noise is refreshed every 64 windows — enough
-        # to spread load across ties without paying an upload per window.
+        # to spread load across ties without paying an upload per window;
+        # the batches prepared with the old vector go with it.
         node_cache: Dict[tuple, tuple] = {}
         if self._noise is None or self._noise.shape[0] != nt.n_rows \
                 or self.stats["windows"] % 64 == 0:
             from nomad_tpu.scheduler.stack import make_noise_vec
 
+            if self._noise is not None:
+                self.tindex.drop_noise(self._noise)
             self._noise = make_noise_vec(nt.n_rows, random.Random())
         noise_vec = self._noise
         for ev, token in batch:
@@ -842,7 +849,7 @@ class PipelinedWorker(Worker):
     def _try_dispatch_fast(self, ev: Evaluation, token: str, snap,
                            usage_chain,
                            node_cache: Dict[tuple, tuple],
-                           noise_vec: Optional[np.ndarray] = None,
+                           noise_vec: np.ndarray,
                            tables: Optional[dict] = None,
                            host: bool = False
                            ) -> Optional[_FastEval]:
@@ -878,37 +885,37 @@ class PipelinedWorker(Worker):
         dc_key = tuple(sorted(job.Datacenters))
         cached = node_cache.get(dc_key)
         if cached is None:
-            from nomad_tpu.tensor.constraints import ClassEligibility
-
-            nodes, by_dc = ready_nodes_in_dcs(snap, job.Datacenters)
-            nt = self.tindex.nt
-            nodes_by_id = {n.ID: n for n in nodes}
-            cand_mask = np.zeros(nt.n_rows, dtype=bool)
-            for n in nodes:
-                row = nt.row_of.get(n.ID)
-                if row is not None:
-                    cand_mask[row] = True
-            elig = ClassEligibility(nt, nodes)
-            cached = (nodes_by_id, cand_mask, elig, by_dc, {})
-            node_cache[dc_key] = cached
-        nodes_by_id, cand_mask, elig, by_dc, prep_cache = cached
-        if not nodes_by_id:
+            with self._stage("nodectx", self._window_no):
+                nctx, hit = self.tindex.node_context(snap, dc_key)
+            self.stats["node_ctx_hit" if hit else "node_ctx_miss"] += 1
+            # The window's own view: the context, and per-job eligibility
+            # views that die with the window (job ids are re-registered).
+            cached = node_cache[dc_key] = (nctx, nctx.window_elig())
+        nctx, elig = cached
+        if not nctx.nodes_by_id:
             return None
         stack.job = job
-        stack.adopt_nodes(nodes_by_id, cand_mask, elig)
-        ctx.metrics.NodesAvailable = by_dc
+        stack.adopt_nodes(nctx.nodes_by_id, nctx.cand_mask, elig)
+        # One dict for every eval placed under the context: read-only.
+        ctx.metrics.NodesAvailable = nctx.by_dc
 
         td2 = time.perf_counter()
         # A storm re-submits value-identical jobs: share the whole prepared
-        # batch (and its resolved device inputs) across them. Only sound
-        # when the job has no prior allocs (zero anti-affinity/banned base).
+        # batch (and its resolved device inputs) across them, for as long
+        # as the node context and this worker's noise vector live. Only
+        # sound when the job has no prior allocs (zero anti-affinity/banned
+        # base).
+        tgs = [t.TaskGroup for t in diff.place]
         sig = None if allocs else _prep_sig(job, diff.place, batch)
-        prep = prep_cache.get(sig) if sig is not None else None
+        prep = nctx.prep(sig, noise_vec) if sig is not None else None
         if prep is None:
-            prep = stack.prepare_batch([t.TaskGroup for t in diff.place],
-                                       noise_vec=noise_vec)
+            prep = stack.prepare_batch(tgs, noise_vec=noise_vec)
             if sig is not None:
-                prep_cache[sig] = prep
+                nctx.keep_prep(sig, prep)
+        else:
+            # Adopted: the blocked eval's class eligibility is read by job
+            # id from the window's views, which only this fills.
+            stack.tg_eligibility(tgs)
         td3 = time.perf_counter()
         self.stats["t_prep_ms"] += (td3 - td2) * 1e3
         # A huge eval blows the host budget even alone; it goes to the
@@ -929,8 +936,8 @@ class PipelinedWorker(Worker):
             # dispatch (a storm window = one kernel, not one per eval).
             res = None
         self.stats["t_launch_ms"] += (time.perf_counter() - td3) * 1e3
-        # shareable: prep came from (or went into) the window prep cache,
-        # which only holds value-identical jobs with NO prior allocs —
+        # shareable: prep came from (or went into) the context's batches,
+        # which only hold value-identical jobs with NO prior allocs —
         # exactly the precondition for the multi kernel's per-eval resets.
         return _FastEval(ev=ev, token=token, plan=plan, ctx=ctx, stack=stack,
                          prep=prep, place=diff.place, res=res,

@@ -1150,6 +1150,12 @@ class StateSnapshot(_ReadAPI):
                         if seg.index <= self.watermark and seg.n_live]
             return chain_allocs, segments
 
+    @property
+    def store(self) -> StateStore:
+        """The store this snapshot reads: part of the identity of anything
+        derived from the snapshot and kept (TensorIndex.node_context)."""
+        return self._store
+
     def get_index(self, table: str) -> int:
         # Table indexes are monotone; clamp to the watermark.
         return min(self._store.get_index(table), self.watermark)

@@ -152,6 +152,22 @@ class ClassEligibility:
         # they become views onto these shared entries.
         self._sig_cache: Dict[tuple, Tuple[np.ndarray, np.ndarray, bool]] = {}
 
+    def view(self) -> "ClassEligibility":
+        """A fresh set of per-job views over the SAME representatives and
+        signature cache: what a holder that outlives one scheduling pass
+        (TensorIndex.node_context) hands each pass. The per-job caches are
+        keyed by job id alone, so they must not outlive the pass: a job
+        re-registered under its id with other constraints would read its
+        old mask. The signature cache is keyed by the constraints' value."""
+        v = ClassEligibility.__new__(ClassEligibility)
+        v.nt = self.nt
+        v.representatives = self.representatives
+        v.nodes_by_row = self.nodes_by_row
+        v._job_cache = {}
+        v._tg_cache = {}
+        v._sig_cache = self._sig_cache
+        return v
+
     # ---- reporting for blocked evals (reference: Evaluation.ClassEligibility)
     def class_eligibility_report(self, mask_by_class: np.ndarray) -> Dict[str, bool]:
         out = {}

@@ -12,8 +12,10 @@ An alloc contributes usage while non-terminal; transitions are derived from
 from __future__ import annotations
 
 import threading
-from typing import Optional
+from collections import OrderedDict
+from typing import Dict, Optional, Tuple
 
+from nomad_tpu.analysis import guarded_by
 from nomad_tpu.state.state_store import StateStore
 from nomad_tpu.structs import Allocation, Node
 
@@ -26,8 +28,74 @@ from .node_table import NodeTensor, alloc_vec, resources_vec
 # views are dropped and rebuilt lazily from the signature cache.
 _ELIG_JOB_CACHE_CAP = 8192
 
+# Node contexts kept per index: one per datacenter set, the oldest dropped.
+_NODE_CTX_CAP = 8
+# Prepared batches kept per node context (a window holds at most its own
+# size in distinct signatures), and the signature masks under them: past
+# either the oldest batch goes, or the masks are dropped and regenerate.
+_NODE_CTX_PREP_CAP = 256
+_NODE_CTX_SIG_CAP = 1024
+
+
+class NodeContext:
+    """What a window of service/batch evals places against, as a function
+    of the nodes table alone: the ready nodes of one datacenter set, their
+    candidate row mask, the class eligibility over them and the count per
+    datacenter, plus the prepared batches assembled under them. Lives as
+    long as the nodes it was built from (TensorIndex.node_context), so it
+    is shared by windows and workers and VALUE-FROZEN by the contract of
+    stack._tg_template and alloc._resvec_cache: every consumer reads, a
+    change builds a new context."""
+
+    _concurrency = guarded_by("_lock", "_preps")
+
+    def __init__(self, key: tuple, nodes_by_id: dict, cand_mask: np.ndarray,
+                 elig, by_dc: Dict[str, int]):
+        self.key = key
+        self.nodes_by_id = nodes_by_id
+        self.cand_mask = cand_mask
+        self.elig = elig
+        self.by_dc = by_dc
+        self._lock = threading.Lock()
+        # (prep signature, id of the noise vector embedded) -> PreparedBatch
+        self._preps: "OrderedDict[Tuple[tuple, int], object]" = OrderedDict()
+
+    def window_elig(self):
+        """The eligibility one window reads: the shared class
+        representatives and signature masks under per-job views of its own
+        (ClassEligibility.view says why those do not outlive it)."""
+        if len(self.elig._sig_cache) > _NODE_CTX_SIG_CAP:
+            self.elig._sig_cache.clear()
+        return self.elig.view()
+
+    def prep(self, sig: tuple, noise_vec: np.ndarray):
+        """The batch prepared under this context for `sig` WITH this very
+        noise vector, or None: each worker renews its noise now and then,
+        and a batch embeds the vector it was prepared with."""
+        key = (sig, id(noise_vec))
+        with self._lock:
+            prep = self._preps.get(key)
+            if prep is None or prep.noise_vec is not noise_vec:
+                return None
+            self._preps.move_to_end(key)
+            return prep
+
+    def keep_prep(self, sig: tuple, prep) -> None:
+        with self._lock:
+            self._preps[(sig, id(prep.noise_vec))] = prep
+            while len(self._preps) > _NODE_CTX_PREP_CAP:
+                self._preps.popitem(last=False)
+
+    def drop_noise(self, noise_vec: np.ndarray) -> None:
+        with self._lock:
+            for key in [k for k, p in self._preps.items()
+                        if p.noise_vec is noise_vec]:
+                del self._preps[key]
+
 
 class TensorIndex:
+    _concurrency = guarded_by("_ctx_lock", "_node_ctx")
+
     def __init__(self, nt: Optional[NodeTensor] = None):
         self.nt = nt or NodeTensor()
         # True when subscribed to a store's change feed (stays in sync and
@@ -44,6 +112,10 @@ class TensorIndex:
         # O(cluster) walk 50 times.
         self._elig_lock = threading.Lock()
         self._elig_cache: Optional[tuple] = None  # (node_version, elig)
+        # Window node contexts (node_context): datacenter tuple -> the
+        # newest NodeContext built for it, oldest set first.
+        self._ctx_lock = threading.Lock()
+        self._node_ctx: "OrderedDict[tuple, NodeContext]" = OrderedDict()
 
     def shared_elig(self, state):
         """Shared, node-version-keyed ClassEligibility over ALL table rows.
@@ -74,6 +146,68 @@ class TensorIndex:
             if self.nt.node_version == ver:
                 self._elig_cache = (ver, elig)
         return elig
+
+    def _node_ctx_key(self, snap, dc_key: tuple) -> tuple:
+        """Everything a NodeContext is a function of, read from the input:
+        the store behind the snapshot (a follower's snapshot never meets a
+        context built from another store), the snapshot's nodes index
+        (clamped to its watermark: equal values mean no nodes write lies
+        between two snapshots), the datacenter set, and the table the rows
+        and class ids come from: its row identities and shape (row_epoch,
+        n_rows: a restore or a grown table remaps or reshapes cand_mask),
+        its node population (node_version: class_ids and row_of are read
+        live) and its mesh (a prepared batch keeps its device inputs)."""
+        nt = self.nt
+        with nt._lock:
+            table = (nt.row_epoch, nt.n_rows, nt.node_version, nt.mesh)
+        return (snap.store, snap.get_index("nodes"), dc_key) + table
+
+    def node_context(self, snap, datacenters) -> Tuple[NodeContext, bool]:
+        """(context, hit): the NodeContext for `datacenters` under `snap`,
+        kept until the nodes table changes (_node_ctx_key). A miss builds
+        what every window used to build for itself; concurrent builders may
+        race, the loser's copy is dropped (values are identical), and a
+        context whose table moved while it was built is handed to its
+        caller but not kept."""
+        from nomad_tpu.scheduler.util import ready_nodes_in_dcs
+
+        from .constraints import ClassEligibility
+
+        dc_key = tuple(sorted(datacenters))
+        key = self._node_ctx_key(snap, dc_key)
+        with self._ctx_lock:
+            ctx = self._node_ctx.get(dc_key)
+            if ctx is not None and ctx.key == key:
+                self._node_ctx.move_to_end(dc_key)
+                return ctx, True
+        nt = self.nt
+        nodes, by_dc = ready_nodes_in_dcs(snap, list(dc_key))
+        cand_mask = np.zeros(nt.n_rows, dtype=bool)
+        for n in nodes:
+            row = nt.row_of.get(n.ID)
+            if row is not None:
+                cand_mask[row] = True
+        ctx = NodeContext(key, {n.ID: n for n in nodes}, cand_mask,
+                          ClassEligibility(nt, nodes), by_dc)
+        if self._node_ctx_key(snap, dc_key) != key:
+            return ctx, False
+        with self._ctx_lock:
+            kept = self._node_ctx.get(dc_key)
+            if kept is not None and kept.key == key:
+                return kept, False
+            self._node_ctx[dc_key] = ctx
+            self._node_ctx.move_to_end(dc_key)
+            while len(self._node_ctx) > _NODE_CTX_CAP:
+                self._node_ctx.popitem(last=False)
+        return ctx, False
+
+    def drop_noise(self, noise_vec: np.ndarray) -> None:
+        """A worker renewed its tie-break noise: the batches prepared with
+        the old vector go, so none pins it."""
+        with self._ctx_lock:
+            contexts = list(self._node_ctx.values())
+        for ctx in contexts:
+            ctx.drop_noise(noise_vec)
 
     def _seed_from(self, state) -> None:
         """Seed the tensor from any read API: every node a row, usage =

@@ -174,9 +174,9 @@ def served():
 
 
 def test_the_worker_times_every_stage_the_issue_lists():
-    assert set(STAGES) == {"lease", "fill", "dispatch", "refresh", "launch",
-                           "drain_stack", "drain", "drain_fetch", "build",
-                           "collect", "planwait", "evalupd", "slow"}
+    assert set(STAGES) == {"lease", "fill", "dispatch", "refresh", "nodectx",
+                           "launch", "drain_stack", "drain", "drain_fetch",
+                           "build", "collect", "planwait", "evalupd", "slow"}
     # The per-eval timers of _try_dispatch_fast are all that is left of
     # the hand-written pairs: they add to `stats` alone, by design.
     assert WORKER_SOURCE.count("perf_counter()") == 5
@@ -224,17 +224,21 @@ def test_the_spans_of_one_window_share_its_number(served, spans, samples):
                 and attrs.get("worker") == "w-test":
             by_name.setdefault(name[len("nomad.worker."):], []).append(attrs)
     # One span a stage a window, none per eval; all carry this window.
-    for stage in ("fill", "dispatch", "refresh", "launch", "drain_stack",
-                  "drain", "build", "collect", "planwait", "evalupd"):
+    for stage in ("fill", "dispatch", "refresh", "nodectx", "launch",
+                  "drain_stack", "drain", "build", "collect", "planwait",
+                  "evalupd"):
         assert by_name[stage] == [{"worker": "w-test",
                                    "window": work.number}], stage
-    # Nesting as the timeline shows it: refresh, launch and drain_stack
-    # open and close inside dispatch; collect inside build.
+    # Nesting as the timeline shows it: refresh, nodectx, launch and
+    # drain_stack open and close inside dispatch; collect inside build.
     order = [n for n, a in spans.opened if a.get("worker") == "w-test"]
     closed = [n for n, who in spans.closed_by if who == "w-test"]
     assert order.index("nomad.worker.fill") \
         < order.index("nomad.worker.dispatch") \
-        < order.index("nomad.worker.refresh")
+        < order.index("nomad.worker.refresh") \
+        < order.index("nomad.worker.nodectx")
+    assert closed.index("nomad.worker.nodectx") \
+        < closed.index("nomad.worker.launch")
     assert closed.index("nomad.worker.drain_stack") \
         < closed.index("nomad.worker.dispatch")
     assert closed.index("nomad.worker.collect") \

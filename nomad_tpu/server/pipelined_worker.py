@@ -128,6 +128,9 @@ STATS_COUNTERS = (
     #                    redelivery onto a fresh snapshot)
     "node_ctx_hit",    # node-context lookups (one a window a datacenter
     "node_ctx_miss",   # set) served from TensorIndex's memo / built anew
+    "launches",        # device placement dispatches (fused or single)
+    "launch_keys",     # unique task groups (keys) summed over them
+    "launch_evals",    # evals placed by them
 )
 STATS_TIMERS_MS = (
     "t_lease_ms",        # waiting for the shared chain-lease (ChainArbiter)
@@ -302,13 +305,14 @@ class PipelinedWorker(Worker):
 
     # ------------------------------------------------------------ stage spans
     @contextmanager
-    def _stage(self, stage: str, window: int):
+    def _stage(self, stage: str, window: int, **attrs):
         """One stage of one window, timed once for all three readers: the
         registry's nomad.worker.<stage> sample and the profiler's span
-        (metrics.measure), and stats["t_<stage>_ms"]. One span a stage a
-        window: per-eval work inside a stage adds to `stats` alone."""
+        (metrics.measure, which carries `attrs`), and
+        stats["t_<stage>_ms"]. One span a stage a window: per-eval work
+        inside a stage adds to `stats` alone."""
         timed = metrics.measure(("nomad", "worker", stage),
-                                worker=self.name, window=window)
+                                worker=self.name, window=window, **attrs)
         try:
             with timed:
                 yield
@@ -706,13 +710,13 @@ class PipelinedWorker(Worker):
                 fast.append(rec)
 
         # Launch the deferred device recs in window order, fusing each
-        # consecutive run of SHARED-prep evals into one place_batch_multi
-        # call: a storm window then costs ONE kernel dispatch and (at
-        # drain) ONE readback, instead of per-eval launches plus an eager
+        # run of SHARED-prep evals into one place_batch_multi call: a
+        # storm window then costs ONE kernel dispatch and (at drain) ONE
+        # readback, instead of per-eval launches plus an eager
         # window-wide stack — both of which scale with window size.
-        # Deferred recs are stably grouped by
-        # prep identity first — an interleaved A,B,A,B window fuses into
-        # two runs. Reordering within a window is safe: any sequential
+        # Deferred recs are grouped by prep identity, in order of first
+        # appearance — an interleaved A,B,A,B window fuses into two
+        # runs. Reordering within a window is safe: any sequential
         # order of optimistic placements is valid (each eval sees every
         # placement dispatched before its own, and the plan applier
         # re-verifies all of them against committed state).
@@ -720,20 +724,17 @@ class PipelinedWorker(Worker):
         # scalar) per dispatch; the drain stage fetches and enforces them
         # (a failed certificate nacks the window like a failed drain).
         mesh_flags: list = []
-        pend = [r for r in fast if r.res is None]
-        group_ids: Dict[int, int] = {}
-        pend.sort(key=lambda r: group_ids.setdefault(
-            id(r.prep) if r.shareable else id(r), len(group_ids)))
-        with self._stage("launch", number):
-            i = 0
-            while i < len(pend):
-                rec = pend[i]
-                j = i + 1
-                if rec.shareable:
-                    while (j < len(pend) and pend[j].shareable
-                           and pend[j].prep is rec.prep):
-                        j += 1
-                run = pend[i:j]
+        by_prep: Dict[int, List[_FastEval]] = {}
+        for rec in fast:
+            if rec.res is None:
+                by_prep.setdefault(id(rec.prep) if rec.shareable
+                                   else id(rec), []).append(rec)
+        runs = list(by_prep.values())
+        pend = [r for run in runs for r in run]
+        with self._stage("launch", number, runs=len(runs),
+                         dc_sets=len(node_cache)):
+            for run in runs:
+                rec = run[0]
                 try:
                     if len(run) >= 2:
                         if tables is None:
@@ -751,6 +752,9 @@ class PipelinedWorker(Worker):
                             rec.prep, usage_override=usage_chain,
                             tables=tables)
                         usage_chain = rec.res.usage_after
+                    self.stats["launches"] += 1
+                    self.stats["launch_keys"] += rec.prep.tg_masks.shape[0]
+                    self.stats["launch_evals"] += len(run)
                     fl = getattr(usage_chain, "flag", None)
                     if fl is not None:
                         mesh_flags.append(fl)
@@ -761,7 +765,6 @@ class PipelinedWorker(Worker):
                         r.fallback = True
                         fast.remove(r)
                         slow.append((r.ev, r.token))
-                i = j
         # Reorder `fast` to CHAIN order (host-placed recs, then deferred
         # device recs in their sorted launch order): the phantom-usage
         # quarantine in _finish_fast reasons about "evals placed behind a

@@ -227,8 +227,12 @@ def test_the_spans_of_one_window_share_its_number(served, spans, samples):
     for stage in ("fill", "dispatch", "refresh", "nodectx", "launch",
                   "drain_stack", "drain", "build", "collect", "planwait",
                   "evalupd"):
+        # The launch span also says what the window is about to cost: the
+        # device launches it makes (none here: three small evals place on
+        # the host) and the node contexts it looked up.
+        extra = {"runs": 0, "dc_sets": 1} if stage == "launch" else {}
         assert by_name[stage] == [{"worker": "w-test",
-                                   "window": work.number}], stage
+                                   "window": work.number, **extra}], stage
     # Nesting as the timeline shows it: refresh, nodectx, launch and
     # drain_stack open and close inside dispatch; collect inside build.
     order = [n for n, a in spans.opened if a.get("worker") == "w-test"]

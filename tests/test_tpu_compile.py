@@ -24,6 +24,7 @@ from nomad_tpu.scheduler import kernels
 from nomad_tpu.tensor import node_table
 
 ROWS = 16_384            # 10,000 nodes padded to a power of two
+DC_ROWS = 65_536         # dc-50k: 50,000 nodes in four datacenters
 WINDOW_P = 32 * 64       # 32 evals x 50 placements, each padded to 64
 MESH_ROWS = 1 << 20
 
@@ -59,10 +60,10 @@ def _shape(shape, dtype, sharding):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-def _node_inputs(n, sh):
-    """capacity, score_cap, usage, tg_masks (one key), job_counts."""
+def _node_inputs(n, sh, keys=1):
+    """capacity, score_cap, usage, tg_masks (a row a key), job_counts."""
     return [_shape((n, 5), F32, sh), _shape((n, 2), F32, sh),
-            _shape((n, 5), F32, sh), _shape((1, n), BOOL, sh),
+            _shape((n, 5), F32, sh), _shape((keys, n), BOOL, sh),
             _shape((n,), I32, sh)]
 
 
@@ -98,6 +99,20 @@ def test_keyed_window_program_compiles(one_chip):
     _compiles(kernels._keyed_program(None, k),
               *_node_inputs(ROWS, one_chip), _shape((1, 5), F32, one_chip),
               *_tail_inputs(ROWS, WINDOW_P, one_chip, reset=True))
+
+
+@pytest.mark.parametrize("keys", [1, 2], ids=["one-key", "two-keys"])
+@pytest.mark.parametrize("evals", [1, 32], ids=["one-eval", "window"])
+def test_keyed_program_compiles_at_dc50k_widths(one_chip, keys, evals):
+    """benchmark/configs/dc-50k.json: a 65,536-row table, jobs of one or
+    two task groups (keys), launched as a run of one eval (stack.dispatch)
+    up to a whole window of one shape (stack.dispatch_multi)."""
+    k = kernels.keyed_cand_count(evals * 50)
+    assert keys * k <= 1 << 17  # stack.KEYED_CAND_BUDGET: the keyed path
+    _compiles(kernels._keyed_program(None, k),
+              *_node_inputs(DC_ROWS, one_chip, keys),
+              _shape((keys, 5), F32, one_chip),
+              *_tail_inputs(DC_ROWS, evals * 64, one_chip, reset=True))
 
 
 def test_compact_window_compiles(one_chip):

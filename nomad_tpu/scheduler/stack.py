@@ -23,6 +23,7 @@ import numpy as np
 
 from nomad_tpu.structs import (
     Allocation,
+    ColumnarPlacements,
     Job,
     NetworkIndex,
     Node,
@@ -35,6 +36,8 @@ from nomad_tpu.structs.structs import (
     ConstraintDistinctHosts,
     JobTypeBatch,
     generate_uuid,
+    generate_uuids,
+    stamp_alloc,
 )
 from nomad_tpu.tensor import ClassEligibility, TensorIndex, alloc_vec, resources_vec
 from nomad_tpu.tensor.node_table import DIM_NAMES, RES_DIMS
@@ -820,18 +823,23 @@ class GenericStack:
         row and no group asks for networks. One fancy-index gather maps
         chosen rows to node IDs, scores land in the metrics dict via one
         zip pass, the window-usage contribution queues as one batch, and
-        allocs stamp from per-TG frozen template Allocations (the sweep
-        path's __dict__-clone trick) instead of running the 20-field
-        dataclass constructor per winner.
+        the placements leave as COLUMNS: ids from one batched draw, names
+        from `place`, the node id and the task group's template index per
+        placement. The only Allocations constructed are the templates, one
+        a task group (the sweep path's frozen template).
 
-        The winner rows stay COLUMNAR past the build: a SweepBatch
-        descriptor (kind="service") rides the plan so the applier bulk-
-        verifies it as one vector op, replicates it as one ApplySweepBatch
-        raft entry, and the store scatter-applies it as a SweepSegment —
-        the service window never explodes into per-object upserts. Rows
-        that take the exact path today (failed placements, network asks,
-        vanished nodes) never reach this build, so the descriptor always
-        covers the whole plan."""
+        The columns ride the plan as a SweepBatch descriptor
+        (kind="service"): the applier bulk-verifies it as one vector op,
+        replicates it as one ApplySweepBatch raft entry, and the store
+        scatter-applies it as a SweepSegment. plan.NodeAllocation is a
+        ColumnarPlacements view over the same descriptor, so the all-fit
+        path never holds an object per placement; a reader that wants
+        them (partial verdict, refused descriptor, exact verify,
+        serialisation) has them stamped from the templates on first ask.
+        Rows that take the exact path today (failed placements, network
+        asks, vanished nodes) never reach this build, so the descriptor
+        always covers the whole plan. Without `columnar` (or on a plan
+        that already holds placements) the objects are stamped here."""
         from .system_sweep import SweepBatch
 
         nt = self.tindex.nt
@@ -863,25 +871,17 @@ class GenericStack:
         # per-CALL (eval_id/metrics are per-eval) but their task-resource
         # dict + vector come from the shared prep memo.
         shared_metric = metrics_.copy()
-        append_alloc = plan.append_alloc
         templates: List[Allocation] = []
-        tpl_dicts: List[dict] = []
         tpl_of: Dict[int, int] = {}
-        alloc_ids_l: List[str] = []
-        names_l: List[str] = []
-        alloc_tg = np.empty(n, dtype=np.int64)
-        new = object.__new__
-        cls = Allocation
-        for p, tup in enumerate(place):
-            tg = tgs[p]
-            ti = tg_index[tg.Name]
+        alloc_tg_l: List[int] = []
+        for p, ti in enumerate(prep.tg_ids[:n].tolist()):
             k = tpl_of.get(ti)
             if k is None:
                 tr, vec = self._tg_template(prep, ti)
                 template = Allocation(
                     EvalID=eval_id,
                     JobID=job.ID,
-                    TaskGroup=tg.Name,
+                    TaskGroup=tgs[p].Name,
                     TaskResources=tr,
                     Metrics=shared_metric,
                     DesiredStatus=AllocDesiredStatusRun,
@@ -890,27 +890,26 @@ class GenericStack:
                 template._resvec_cache = vec
                 k = tpl_of[ti] = len(templates)
                 templates.append(template)
-                tpl_dicts.append(template.__dict__)
-            alloc = new(cls)
-            alloc.__dict__ = dict(tpl_dicts[k])
-            alloc.ID = generate_uuid()
-            alloc.Name = tup.Name
-            alloc.NodeID = ids_list[p]
-            alloc.Services = {}
-            alloc.TaskStates = {}
-            alloc_ids_l.append(alloc.ID)
-            names_l.append(tup.Name)
-            alloc_tg[p] = k
-            append_alloc(alloc)
+            alloc_tg_l.append(k)
+        alloc_ids_l = generate_uuids(n)
+        names_l = [tup.Name for tup in place]
 
-        if not self.columnar:
-            return True
+        as_columns = self.columnar and not plan.NodeAllocation
+        if not as_columns:
+            tpl_dicts = [t.__dict__ for t in templates]
+            for p in range(n):
+                plan.append_alloc(stamp_alloc(
+                    tpl_dicts[alloc_tg_l[p]], alloc_ids_l[p], names_l[p],
+                    ids_list[p]))
+            if not self.columnar:
+                return True
         # Columnar descriptor: unique placed rows with summed demand, plus
         # the per-alloc columns sorted into row order so chunk slices stay
         # contiguous (same layout the system sweep emits). The delta uses
         # the template resource vectors — exactly what alloc_vec() yields
         # for every stamped clone, so the applier's bulk verify and the
         # optimistic overlay account the same bytes the object path would.
+        alloc_tg = np.asarray(alloc_tg_l, dtype=np.int64)
         ur, inv = np.unique(rows64, return_inverse=True)
         tpl_vecs = np.stack([t._resvec_cache for t in templates])
         delta = np.zeros((len(ur), RES_DIMS), dtype=np.float32)
@@ -927,6 +926,8 @@ class GenericStack:
             alloc_names=np.asarray(names_l, dtype=object)[order].tolist(),
             alloc_tg=alloc_tg[order].tolist(),
             templates=templates, kind="service")
+        if as_columns:
+            plan.NodeAllocation = ColumnarPlacements.over(plan._sweep)
         return True
 
     def collect_build(self, prep: PreparedBatch, cr,

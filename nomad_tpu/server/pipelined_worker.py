@@ -83,7 +83,7 @@ from nomad_tpu.scheduler.util import (
     materialize_task_groups,
     tainted_nodes,
 )
-from nomad_tpu.structs import AllocMetric, Evaluation, Plan
+from nomad_tpu.structs import AllocMetric, Evaluation, Plan, columns_only
 from nomad_tpu.telemetry import metrics, trace
 from nomad_tpu.tensor.node_table import ChainArbiter
 from nomad_tpu.structs.structs import (
@@ -131,6 +131,11 @@ STATS_COUNTERS = (
     "launches",        # device placement dispatches (fused or single)
     "launch_keys",     # unique task groups (keys) summed over them
     "launch_evals",    # evals placed by them
+    "plans_columnar",  # submitted fast plans whose placements stayed columns
+    #                    until their window settled: no object was built
+    "plans_objects",   # every other one: objects built at collect, or
+    #                    asked for later by any reader (partial verdict,
+    #                    refused descriptor, exact verify, serialisation)
 )
 STATS_TIMERS_MS = (
     "t_lease_ms",        # waiting for the shared chain-lease (ChainArbiter)
@@ -1142,6 +1147,12 @@ class PipelinedWorker(Worker):
         eval_updates: List[Evaluation] = []
         done: List[_FastEval] = []
         for rec in fast:
+            if rec.pending is not None:
+                # The window is settled: did anyone need this plan's
+                # placements as objects on the way?
+                self.stats["plans_columnar"
+                           if columns_only(rec.plan.NodeAllocation)
+                           else "plans_objects"] += 1
             if rec.fallback or rec.stale:
                 continue
             eval_updates.extend(self._status_evals(rec))

@@ -34,9 +34,11 @@ from nomad_tpu.resilience import failpoints
 from nomad_tpu.tensor.node_table import RES_DIMS, alloc_vec
 from nomad_tpu.structs import (
     Allocation,
+    ColumnarPlacements,
     Plan,
     PlanResult,
     allocs_fit,
+    columns_only,
     remove_allocs,
 )
 from nomad_tpu.structs.structs import NodeStatusReady
@@ -127,6 +129,9 @@ class OptimisticSnapshot:
         self.snap = snap
         self.nt = nt
         self._added: Dict[str, List[Allocation]] = {}
+        # In-flight results whose placements exist as columns only: read
+        # (and only then built into objects) by allocs_by_node_terminal.
+        self._added_columns: List[ColumnarPlacements] = []
         self._removed: Set[str] = set()
         self.row_delta: Dict[int, np.ndarray] = {}
         # Dense in-flight usage overlay, allocated lazily by the first
@@ -146,8 +151,9 @@ class OptimisticSnapshot:
             # Columnar sweep result: ONE scatter-add replaces the
             # per-alloc row overlay. The descriptor covers every
             # NodeAllocation key (evaluate_plan only attaches it then),
-            # so nothing is missed; _added is still filled per node — the
-            # exact verify path of a LATER plan in the group reads it.
+            # so nothing is missed. The exact verify path of a LATER plan
+            # in the group reads _added: objects join it per node, a
+            # columns-only result is kept whole and asked when read.
             if self.row_dense is None:
                 self.row_dense = np.zeros((self.nt.n_rows, RES_DIMS),
                                           dtype=np.float32)
@@ -158,7 +164,11 @@ class OptimisticSnapshot:
                 grown[:self.row_dense.shape[0]] = self.row_dense
                 self.row_dense = grown
             np.add.at(self.row_dense, sweep.rows, sweep.delta)
-            for node_id, placed in result.NodeAllocation.items():
+            placements = result.NodeAllocation
+            if columns_only(placements):
+                self._added_columns.append(placements)
+                return
+            for node_id, placed in placements.items():
                 self._added.setdefault(node_id, []).extend(placed)
             return
         for node_id, placed in result.NodeAllocation.items():
@@ -194,6 +204,9 @@ class OptimisticSnapshot:
                if a.ID not in self._removed]
         if not terminal:
             out.extend(self._added.get(node_id, ()))
+            for placements in self._added_columns:
+                if node_id in placements:
+                    out.extend(placements[node_id])
         return out
 
     def get_index(self, table: str) -> int:
@@ -366,9 +379,10 @@ def evaluate_plan(snap, plan: Plan,
         # Everything fits (the healthy-sweep common case): admit the plan
         # wholesale instead of re-walking 10k node ids to copy dict
         # entries one at a time. A full-coverage sweep descriptor rides
-        # the result so the optimistic overlay applies it as one scatter.
+        # the result so the optimistic overlay applies it as one scatter;
+        # placements held as columns only are admitted as columns.
         result.NodeUpdate = dict(plan.NodeUpdate)
-        result.NodeAllocation = dict(plan.NodeAllocation)
+        result.NodeAllocation = plan.NodeAllocation.copy()
         sweep = getattr(plan, "_sweep", None)
         if sweep is not None \
                 and len(sweep.node_ids) == len(plan.NodeAllocation):

@@ -1,14 +1,15 @@
 """Data model + wire structs (reference: nomad/structs/)."""
 
 from .structs import (  # explicit re-exports for the commonly used names
-    Allocation, AllocListStub, AllocMetric, CheckState, Constraint,
-    DesiredUpdates,
+    Allocation, AllocListStub, AllocMetric, CheckState, ColumnarPlacements,
+    Constraint, DesiredUpdates, columns_only,
     Evaluation, Job, JobListStub, JobPlanResponse, LogConfig, NetworkResource, Node,
     NodeListStub, PeriodicConfig, PeriodicLaunch, Plan, PlanAnnotations,
     PlanResult, Port, Resources, RestartPolicy, Service, ServiceCheck,
     ServiceRegistration, Task,
     TaskArtifact, TaskEvent, TaskGroup, TaskState, UpdateStrategy,
-    ValidationError, generate_uuid, job_stub,
+    ValidationError, generate_uuid, generate_uuids, job_stub, placed_count,
+    stamp_alloc,
 )
 from .bitmap import Bitmap  # noqa: F401
 from .funcs import allocs_fit, filter_terminal_allocs, remove_allocs, score_fit  # noqa: F401

@@ -10,9 +10,11 @@ from __future__ import annotations
 
 import copy
 import re
+import threading
 import time as _time
 import os
 from dataclasses import dataclass, field, replace
+from collections.abc import KeysView
 from typing import Any, Dict, List, Optional
 
 # --- Duration helpers (Go time.Duration is int64 nanoseconds on the wire) ---
@@ -137,6 +139,23 @@ def generate_uuid() -> str:
     # RFC 4122 v4 shape (version/variant nibbles fixed).
     return (f"{h[:8]}-{h[8:12]}-4{h[13:16]}-"
             f"{'89ab'[int(h[16], 16) & 3]}{h[17:20]}-{h[20:]}")
+
+
+# The version and variant bits of RFC 4122 v4, set on a whole draw at once.
+_UUID_VERSION = bytes((b & 0x0F) | 0x40 for b in range(256))
+_UUID_VARIANT = bytes((b & 0x3F) | 0x80 for b in range(256))
+
+
+def generate_uuids(n: int) -> List[str]:
+    """`n` IDs of generate_uuid's shape from ONE os.urandom draw: what a
+    window's build mints for an eval's placements, as a column."""
+    raw = bytearray(os.urandom(16 * n))
+    raw[6::16] = raw[6::16].translate(_UUID_VERSION)
+    raw[8::16] = raw[8::16].translate(_UUID_VARIANT)
+    hx = raw.hex()
+    return ["-".join((hx[i:i + 8], hx[i + 8:i + 12], hx[i + 12:i + 16],
+                      hx[i + 16:i + 20], hx[i + 20:i + 32]))
+            for i in range(0, 32 * n, 32)]
 
 
 class ValidationError(Exception):
@@ -1180,6 +1199,188 @@ class Evaluation:
         )
 
 
+def stamp_alloc(template: Dict[str, Any], alloc_id: str, name: str,
+                node_id: str) -> Allocation:
+    """One placement as an object: a clone of its task group's template
+    Allocation (`template` is that object's __dict__: EvalID, JobID,
+    TaskGroup, the shared TaskResources, Metrics and resource vector) with
+    its own identity and fresh client-mutable containers. The 20-field
+    dataclass constructor never runs."""
+    alloc = object.__new__(Allocation)
+    alloc.__dict__ = dict(template)
+    alloc.ID = alloc_id
+    alloc.Name = name
+    alloc.NodeID = node_id
+    alloc.Services = {}
+    alloc.TaskStates = {}
+    return alloc
+
+
+class _PlacedColumns:
+    """What a plan's ColumnarPlacements and every copy made of it share:
+    the columns, and the per-node lists once any reader had them built."""
+
+    __slots__ = ("cols", "by_node", "position")
+
+    def __init__(self, cols):
+        self.cols = cols
+        self.by_node: Optional[Dict[str, List[Allocation]]] = None
+        self.position: Optional[Dict[str, int]] = None  # node id -> index
+
+    def index(self, node_id) -> Optional[int]:
+        position = self.position
+        if position is None:
+            position = self.position = {
+                nid: i for i, nid in enumerate(self.cols.node_ids)}
+        return position.get(node_id)
+
+
+_STAMP_LOCK = threading.Lock()  # the applier and a worker may ask at once
+
+
+class ColumnarPlacements(dict):
+    """`Plan.NodeAllocation` of a plan whose placements exist as columns
+    only: a view over the plan's full-coverage batch descriptor (`cols`:
+    `node_ids` one a placed node in row order, `starts` the range of each
+    node's placements in the per-placement columns `alloc_ids`,
+    `alloc_names`, `alloc_tg`, and `templates`, one Allocation a task
+    group: scheduler.system_sweep.SweepBatch).
+
+    Keys, length, membership, truth and the counts are answered from the
+    columns. Every other read or write first builds the per-node lists of
+    Allocation, once for the plan and all copies of this view (a
+    PlanResult's wholesale admit holds one), and from then on this is the
+    plain dict it subclasses. A reader that knows nothing of columns
+    therefore sees what it always saw, and pays for the objects it asks
+    for."""
+
+    __slots__ = ("_shared", "_live")
+
+    def __init__(self, *args, **kwargs):
+        super().__init__(*args, **kwargs)
+        self._shared: Optional[_PlacedColumns] = None
+        self._live = False  # still answering from the columns
+
+    @classmethod
+    def over(cls, cols) -> "ColumnarPlacements":
+        self = cls()
+        self._shared = _PlacedColumns(cols)
+        self._live = True
+        return self
+
+    @property
+    def objects_built(self) -> bool:
+        """Whether any reader made this plan's placements into objects."""
+        return self._shared is None or self._shared.by_node is not None
+
+    def _build(self) -> None:
+        if not self._live:
+            return
+        shared = self._shared
+        with _STAMP_LOCK:
+            if shared.by_node is None:
+                cols = shared.cols
+                ids, names, tg = (cols.alloc_ids, cols.alloc_names,
+                                  cols.alloc_tg)
+                templates = [t.__dict__ for t in cols.templates]
+                bounds = [int(b) for b in cols.starts]
+                shared.by_node = {
+                    nid: [stamp_alloc(templates[tg[p]], ids[p], names[p],
+                                      nid)
+                          for p in range(bounds[i], bounds[i + 1])]
+                    for i, nid in enumerate(cols.node_ids)}
+            if self._live:
+                dict.update(self, shared.by_node)
+                self._live = False
+
+    # ---- answered from the columns
+    def __len__(self) -> int:
+        if self._live:
+            return len(self._shared.cols.node_ids)
+        return dict.__len__(self)
+
+    def __iter__(self):
+        if self._live:
+            return iter(self._shared.cols.node_ids)
+        return dict.__iter__(self)
+
+    def __contains__(self, node_id) -> bool:
+        if self._live:
+            return self._shared.index(node_id) is not None
+        return dict.__contains__(self, node_id)
+
+    def keys(self):
+        return KeysView(self) if self._live else dict.keys(self)
+
+    def count(self, node_id: str) -> int:
+        """Placements on one node."""
+        if not self._live:
+            return len(dict.get(self, node_id, ()))
+        i = self._shared.index(node_id)
+        if i is None:
+            return 0
+        starts = self._shared.cols.starts
+        return int(starts[i + 1] - starts[i])
+
+    def total(self) -> int:
+        """Placements on all nodes."""
+        if self._live:
+            return len(self._shared.cols.alloc_ids)
+        return sum(map(len, dict.values(self)))
+
+    def copy(self) -> Dict[str, List[Allocation]]:
+        """A verdict's own mapping of the same placements: columns while
+        nobody asked for objects, the same lists once somebody did."""
+        if not self._live:
+            return dict.copy(self)
+        other = ColumnarPlacements()
+        other._shared = self._shared
+        other._live = True
+        return other
+
+    def __repr__(self) -> str:
+        if self._live:
+            return (f"ColumnarPlacements(nodes={len(self)}, "
+                    f"placements={self.total()})")
+        return dict.__repr__(self)
+
+    def __reduce__(self):
+        self._build()
+        return (dict, (dict(self),))  # copies and pickles are plain dicts
+
+
+def _building(name: str):
+    plain = getattr(dict, name)
+
+    def method(self, *args, **kwargs):
+        self._build()
+        return plain(self, *args, **kwargs)
+
+    method.__name__ = name
+    return method
+
+
+for _name in ("__getitem__", "__setitem__", "__delitem__", "__eq__", "__ne__",
+              "__or__", "__ror__", "__ior__", "__reversed__", "get", "items",
+              "values", "setdefault", "pop", "popitem", "update", "clear"):
+    setattr(ColumnarPlacements, _name, _building(_name))
+
+
+def columns_only(node_allocation: Dict[str, List[Allocation]]) -> bool:
+    """Whether these placements exist as columns alone: no reader has had
+    them built into objects (yet)."""
+    return (isinstance(node_allocation, ColumnarPlacements)
+            and not node_allocation.objects_built)
+
+
+def placed_count(node_allocation: Dict[str, List[Allocation]]) -> int:
+    """Placements a plan or a verdict carries, without building objects
+    that exist as columns only."""
+    if isinstance(node_allocation, ColumnarPlacements):
+        return node_allocation.total()
+    return sum(map(len, node_allocation.values()))
+
+
 @dataclass
 class Plan:
     """Scheduler output submitted to the plan applier (reference: structs.go:2845-2928)."""
@@ -1229,12 +1430,8 @@ class PlanResult:
     AllocIndex: int = 0
 
     def full_commit(self, plan: Plan) -> tuple[bool, int, int]:
-        expected = 0
-        actual = 0
-        for _, allocs in plan.NodeAllocation.items():
-            expected += len(allocs)
-        for _, allocs in self.NodeAllocation.items():
-            actual += len(allocs)
+        expected = placed_count(plan.NodeAllocation)
+        actual = placed_count(self.NodeAllocation)
         return expected == actual, expected, actual
 
 

@@ -19,8 +19,9 @@ import pytest
 from nomad_tpu import mock
 from nomad_tpu.scheduler import kernels
 from nomad_tpu.server import Server, ServerConfig, pipelined_worker
-from nomad_tpu.server.pipelined_worker import (STATS_TIMERS_MS,
-                                               PipelinedWorker)
+from nomad_tpu.server.pipelined_worker import (STATS_COUNTERS,
+                                               STATS_TIMERS_MS,
+                                               PipelinedWorker, new_stats)
 from nomad_tpu.structs.structs import EvalStatusComplete
 from nomad_tpu.telemetry import metrics, trace
 from nomad_tpu.tensor import node_table
@@ -201,9 +202,16 @@ def test_a_stage_is_a_stats_key_a_sample_and_a_span(served, spans, samples,
 
 def test_the_spans_of_one_window_share_its_number(served, spans, samples):
     srv, worker = served
+    ready0 = srv.eval_broker.stats.TotalReady
     for _ in range(3):
         srv.job_register(mock.job())
+    # The fill below lingers FILL_TIMEOUT (2 ms) for stragglers: under a
+    # loaded machine that is no time at all, so the three evals have to
+    # stand in the ready queue before the first is taken.
+    assert wait_for(lambda: srv.eval_broker.stats.TotalReady - ready0 >= 3,
+                    interval=0.002)
     fill0, wait0 = worker.stats["t_fill_ms"], worker.stats["t_stagewait_ms"]
+    objects0 = worker.stats["plans_objects"]
     first = worker._dequeue_first()
     batch = [first]
     work = worker._dispatch_window(batch, fill=True)
@@ -216,6 +224,10 @@ def test_the_spans_of_one_window_share_its_number(served, spans, samples):
         work.packed = worker._drain_window(work)
     worker._finish_fast(work)
     assert worker.stats["fast"] >= 3
+    # Three submitted plans, each counted once: mock.job asks for a port,
+    # so their placements were objects from collect on.
+    assert worker.stats["plans_objects"] - objects0 == 3
+    assert worker.stats["plans_columnar"] == 0
     assert worker.stats["t_stagewait_ms"] - wait0 >= 2.0
     assert worker.stats["t_fill_ms"] > fill0
     by_name = {}
@@ -251,6 +263,66 @@ def test_the_spans_of_one_window_share_its_number(served, spans, samples):
     assert samples.of("nomad.worker.planwait")
     assert samples.of("nomad.plan.apply")
     assert any(n.startswith("nomad.fsm.") for n, _ in samples.rows)
+
+
+def _plain_job():
+    job = mock.job()  # the storm's shape: no port asked, nothing to register
+    task = job.TaskGroups[0].Tasks[0]
+    task.Resources.Networks = []
+    task.Resources.CPU, task.Resources.MemoryMB = 20, 32
+    task.Services = []
+    job.TaskGroups[0].Count = 3
+    return job
+
+
+def test_a_columns_only_window_still_opens_one_collect_span(served, spans):
+    srv, worker = served
+    ready0 = srv.eval_broker.stats.TotalReady
+    for _ in range(2):
+        srv.job_register(_plain_job())
+    assert wait_for(lambda: srv.eval_broker.stats.TotalReady - ready0 >= 2,
+                    interval=0.002)
+    before = dict(worker.stats)
+    batch = worker._dequeue_window()
+    work = worker._dispatch_window(batch)
+    assert len(work.fast) == 2
+    work.packed = worker._drain_window(work)
+    worker._finish_fast(work)
+    worker._arbiter.mark_settled(work.chain_seq)
+    worker._arbiter.finish_window()
+    assert worker.stats["plans_columnar"] - before["plans_columnar"] == 2
+    assert worker.stats["plans_objects"] == before["plans_objects"]
+    assert worker.stats["t_collect_ms"] > before["t_collect_ms"]
+    mine = [(n, a) for n, a in spans.opened if a.get("worker") == "w-test"]
+    assert [a for n, a in mine if n == "nomad.worker.collect"] \
+        == [{"worker": "w-test", "window": work.number}]
+    assert [n for n, _ in mine].count("nomad.worker.build") == 1
+
+
+with open(os.path.join(ROOT, "README.md")) as _f:
+    README = _f.read()
+README_STATS = [ln for ln in README.splitlines() if ln.startswith("| `")]
+
+
+@pytest.mark.parametrize("key", STATS_COUNTERS + STATS_TIMERS_MS)
+def test_a_stats_key_is_seeded_and_the_readme_says_what_it_counts(key):
+    """One declared schema: the key is there before anything ran, zero of
+    its kind, and README documents it (the mesh's keys under "Mesh
+    serving", every other in the stats-key table)."""
+    seeded = new_stats()[key]
+    assert seeded == 0
+    assert isinstance(seeded, float) is key.startswith("t_")
+    assert f"`{key}`" in README, key
+    if "mesh" not in key:
+        assert any(f"`{key}`" in ln.split("|")[1] for ln in README_STATS)
+
+
+def test_the_schema_counts_how_a_fast_plan_carried_its_placements():
+    assert {"plans_columnar", "plans_objects"} <= set(STATS_COUNTERS)
+    assert len(set(STATS_COUNTERS + STATS_TIMERS_MS)) \
+        == len(STATS_COUNTERS) + len(STATS_TIMERS_MS)
+    [row] = [ln for ln in README_STATS if "`plans_columnar`" in ln]
+    assert "`plans_objects`" in row.split("|")[1]
 
 
 # ---------------------------------------------------- broker and plan queue

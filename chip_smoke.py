@@ -2,16 +2,17 @@
 """chip_smoke.py: the quickest proof that the served scheduling path still
 starts, places and commits on the attached TPU.
 
-Default phase (one chip). BASELINE config 3 as bench.py builds it: 10,000
-nodes in 64 computed classes, jobs of Count=50 with the driver checker and
-the ${attr.arch} constraint, served by a dev-mode nomad_tpu.agent.Agent with
-the configuration users get by default and the HTTP API up. Two seeded
-departures make the constraint checks falsifiable without changing the class
-count: 6 of the 64 racks are ineligible (4 are arm64, 2 have no exec driver)
-and one node in a thousand registers but never turns ready. Nodes go in
-through node_register and stay alive through node_heartbeat, the endpoints
-client agents call. Then: a few jobs over HTTP, warm-up, a storm of --evals
-jobs through job_register, one lone job on the idle broker.
+Default phase (one chip). BASELINE config 3 (the fleet and job shape of
+benchmark/configs/svc-10k.json): 10,000 nodes in 64 computed classes, jobs
+of Count=50 with the driver checker and the ${attr.arch} constraint, served
+by a dev-mode nomad_tpu.agent.Agent with the configuration users get by
+default and the HTTP API up. Two seeded departures make the constraint
+checks falsifiable without changing the class count: 6 of the 64 racks are
+ineligible (4 are arm64, 2 have no exec driver) and one node in a thousand
+registers but never turns ready. Nodes go in through node_register and stay
+alive through node_heartbeat, the endpoints client agents call. Then: a few
+jobs over HTTP, warm-up, a storm of --evals jobs through job_register, one
+lone job on the idle broker.
 
 It fails unless JAX runs on a TPU, every eval completes with exactly Count
 allocations, the workers report device-placed evals (fast - host > 0) with no
@@ -176,9 +177,10 @@ def seeded_uuid(rng):
 
 
 def build_fleet(n, rng):
-    """bench.build_nodes's config-3 fleet with seeded IDs, six ineligible
-    racks and a few nodes that never turn ready."""
-    import bench
+    """BASELINE config 3's fleet (mock.Node in N_RACKS computed classes)
+    with seeded IDs, six ineligible racks and a few nodes that never turn
+    ready."""
+    from nomad_tpu import mock
     from nomad_tpu.structs import compute_node_class
     from nomad_tpu.structs.structs import NodeStatusInit
 
@@ -186,10 +188,11 @@ def build_fleet(n, rng):
     rng.shuffle(racks)
     arm, no_exec = set(racks[:4]), set(racks[4:6])
     never_ready = set(rng.sample(range(n), max(1, n // 1000)))
-    nodes = bench.build_nodes(n)
+    nodes = [mock.node() for _ in range(n)]
     for i, node in enumerate(nodes):
         node.ID = seeded_uuid(rng)
         node.Name = f"node-{i}"
+        node.Meta["rack"] = f"r{i % N_RACKS}"
         if i % N_RACKS in arm:
             node.Attributes["arch"] = "arm64"
         if i % N_RACKS in no_exec:
@@ -201,9 +204,27 @@ def build_fleet(n, rng):
 
 
 def build_job(rng, k):
-    import bench
+    """BASELINE config 3's job: Count=PER_EVAL, the exec driver checker of
+    the mock task plus an attribute constraint, asks small enough that the
+    fleet absorbs the storm, no network."""
+    from nomad_tpu import mock
+    from nomad_tpu.structs import Constraint
 
-    job = bench.build_job(PER_EVAL)
+    job = mock.job()
+    tg = job.TaskGroups[0]
+    tg.Count = PER_EVAL
+    job.Constraints.append(
+        Constraint(LTarget="${attr.arch}", RTarget="x86", Operand="="))
+    task = tg.Tasks[0]
+    task.Resources.CPU = 20
+    task.Resources.MemoryMB = 32
+    task.Resources.DiskMB = 10
+    task.Resources.Networks = []
+    task.Services = []
+    if task.LogConfig is not None:
+        # Validation: the task's log storage must fit its disk ask.
+        task.LogConfig.MaxFiles = 1
+        task.LogConfig.MaxFileSizeMB = 1
     job.ID = seeded_uuid(rng)
     job.Name = f"smoke-{k}"
     return job
@@ -394,7 +415,7 @@ def check_guarantees(checks, label, state, row_of, device_usage, eval_ids):
 # --------------------------------------------- one fixed window, by kernel
 def window_inputs(rng_seed, n_rows, n_live, n_evals):
     """A fleet of mock-node shape, part filled, and one storm window of
-    n_evals x 50 placements of bench.build_job's ask (p_pad 64)."""
+    n_evals x 50 placements of build_job's ask (p_pad 64)."""
     import numpy as np
 
     rng = np.random.default_rng(rng_seed)
@@ -704,8 +725,8 @@ def serve_side(args, checks, mesh_side, nodes_blob, jobs_blob):
     from nomad_tpu.server import Server, ServerConfig
 
     label = "mesh" if mesh_side else "one_device"
-    # Nobody heartbeats these nodes: park the TTLs past the run, as bench.py
-    # does. host_placement=False so that no window is placed by numpy.
+    # Nobody heartbeats these nodes: park the TTLs past the run.
+    # host_placement=False so that no window is placed by numpy.
     server = Server(ServerConfig(num_schedulers=1,
                                  scheduler_mesh="all" if mesh_side else "",
                                  host_placement=False,

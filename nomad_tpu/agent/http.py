@@ -913,10 +913,11 @@ def route(agent, method: str, path: str, query, get_body):
         return _trace.status(), None
 
     if path == "/v1/agent/debug/sched-stats":
-        # Scheduling-pipeline observability: the same per-worker stage
-        # timers and flow counters bench.py prints (PipelinedWorker.stats,
-        # one declared schema — see README "Serving pipeline
-        # observability"). Debug-gated like stacks/profile: stage timings
+        # Scheduling-pipeline observability: the per-worker stage timers
+        # and flow counters the benchmark's per-layer metrics are deltas
+        # of (PipelinedWorker.stats, one declared schema — see README
+        # "Serving pipeline observability"). Debug-gated like
+        # stacks/profile: stage timings
         # leak workload shape, so the agent must opt in.
         if not getattr(agent.config, "enable_debug", False):
             raise CodedError(404, "debug endpoints disabled "

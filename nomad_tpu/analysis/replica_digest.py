@@ -25,8 +25,8 @@ The leader piggybacks its latest checkpoint on AppendEntries; a
 follower that folded the same index compares and, on mismatch, raises
 the typed :class:`ReplicaDivergenceError`, bumps
 ``nomad.fsm.digest.diverged``, and is quarantined by the raft layer to
-snapshot-reinstall recovery. Dev mode folds (the bench measures the
-cost and sched-stats shows the chain) but never exchanges — concurrent
+snapshot-reinstall recovery. Dev mode folds (sched-stats shows the
+chain) but never exchanges — concurrent
 dev applies can fold out of index order, which is harmless because
 nothing compares the value.
 

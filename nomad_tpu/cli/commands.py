@@ -837,9 +837,10 @@ def cmd_faults(args) -> int:
 
 
 def cmd_sched_stats(args) -> int:
-    """Operator view of the served scheduling pipeline: the same stage
-    timers and flow counters bench.py prints, live from the leader's
-    workers (see the README's stats-key table for what each means)."""
+    """Operator view of the served scheduling pipeline: the stage timers
+    and flow counters of /v1/agent/debug/sched-stats, live from the
+    leader's workers (see the README's stats-key table for what each
+    means)."""
     client = _client(args)
     out = client.agent.sched_stats()
     if args.json:

@@ -23,9 +23,9 @@ class FederationConfig:
     enabled: bool = False
     # Follower-snapshot scheduling (snapshots.py): False keeps region
     # routing/forwarding/QoS-view on but has every worker pin a fresh
-    # live-store watermark per window — the all-on-leader baseline the
-    # bench's config7_federation A/B measures the snapshot source
-    # against (the ONLY delta between the two sides).
+    # live-store watermark per window — the all-on-leader baseline
+    # (the snapshot source is the ONLY delta between the two values;
+    # nothing measures it today: ROADMAP Named debts, D6).
     follower_snapshots: bool = True
     # Staleness bound (seconds) on the shared scheduling snapshot:
     # enforced at DEQUEUE — a worker asking for a snapshot older than

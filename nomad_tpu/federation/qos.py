@@ -38,7 +38,7 @@ from .config import FederationConfig
 
 class FederationHealth:
     """Cached per-region QoS health, fed by the leader's poll loop (and
-    directly by tests/benches that skip gossip)."""
+    directly by tests that skip gossip)."""
 
     _concurrency = guarded_by("_lock", "_regions")
 

@@ -39,8 +39,8 @@ def scheduling_mesh(devices: Optional[Sequence[jax.Device]] = None) -> Mesh:
 def pow2_prefix(devices: Sequence[jax.Device]) -> Sequence[jax.Device]:
     """Largest power-of-two prefix of a device list — the mesh-sizing rule
     (node rows pad to powers of two, so the sharded axis must divide
-    evenly). THE single definition; server boot, bench.py and
-    chip_smoke.py all use it."""
+    evenly). THE single definition; server boot and chip_smoke.py both
+    use it."""
     n = 1
     while n * 2 <= len(devices):
         n *= 2

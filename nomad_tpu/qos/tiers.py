@@ -115,7 +115,7 @@ class QoSCounters:
     """Cross-thread QoS flow counters (admission verdicts, preemption
     outcomes, window cuts), shared by the server's admission controller,
     the scheduler's preemption path, and the workers; read by the
-    sched-stats endpoint and bench.py."""
+    sched-stats endpoint and the QoS tests."""
 
     _concurrency = guarded_by("_lock", "_counts")
 

@@ -6,8 +6,8 @@ Yates node shuffle, computed-class-memoized feasibility with escape hatch,
 BinPack scoring over proposed usage, job anti-affinity, and the
 max(2, ceil(log2 n)) LimitIterator with MaxScore selection.
 
-Used as (a) the baseline the TPU path must beat (bench.py) and (b) the
-golden model for placement-quality parity tests.
+Used as the golden model of the placement-quality parity tests
+(tests/test_tensor_and_kernels.py, tests/test_mixed_window_equivalence.py).
 """
 
 from __future__ import annotations
@@ -131,8 +131,8 @@ class CPUReferenceStack:
 
 class CPUReferenceServedStack:
     """GenericScheduler-compatible stack running the reference's host-side
-    iterator chain against LIVE cluster state — the honest denominator for
-    the served benchmark: same broker, plan applier, raft, and status paths
+    iterator chain against LIVE cluster state (scheduler_impl=
+    "cpu-reference"): same broker, plan applier, raft, and status paths
     as the TPU stack, with only the placement engine swapped.
 
     Semantics mirror CPUReferenceStack (Fisher-Yates shuffle, class-memoized

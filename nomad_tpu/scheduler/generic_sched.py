@@ -178,8 +178,9 @@ class GenericScheduler:
         self.batch = batch
         self.rng = rng or random.Random()
         # "tpu" (device placement kernels) or "cpu-reference" (the
-        # reference's host-side iterator chain) — the benchmark denominator
-        # runs through this seam so both engines share every other stage.
+        # reference's host-side iterator chain) — the parity tests' golden
+        # model runs through this seam so both engines share every other
+        # stage.
         self.impl = impl
 
         self.eval: Optional[Evaluation] = None

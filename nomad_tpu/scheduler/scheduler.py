@@ -54,7 +54,8 @@ def new_scheduler(name: str, state: State, planner: Planner,
     tindex is the TensorIndex backing the placement kernels; when None, one is
     built from the state snapshot (simple mode for tests/tools). impl selects
     the placement engine for the generic schedulers: "tpu" (device kernels)
-    or "cpu-reference" (host-side iterator chain, the benchmark denominator).
+    or "cpu-reference" (host-side iterator chain, the parity tests' golden
+    model).
     """
     factory = BUILTIN_SCHEDULERS.get(name)
     if factory is None:

@@ -281,18 +281,11 @@ class GenericStack:
     """Stack for service/batch jobs (reference: stack.go:35-173)."""
 
     def __init__(self, ctx: EvalContext, tindex: TensorIndex, batch: bool,
-                 rng: Optional[random.Random] = None,
-                 columnar: bool = True):
+                 rng: Optional[random.Random] = None):
         self.ctx = ctx
         self.tindex = tindex
         self.batch = batch
         self.rng = rng or random.Random()
-        # Columnar service commits: the all-placed window build attaches a
-        # SweepBatch descriptor (kind="service") so the plan replicates as
-        # ONE ApplySweepBatch raft entry + SweepSegment scatter instead of
-        # per-object upserts. False keeps the per-object commit (the
-        # equivalence oracle and the bench A/B's object side).
-        self.columnar = columnar
         self.job: Optional[Job] = None
         self.elig: Optional[ClassEligibility] = None
         self._cand_mask: Optional[np.ndarray] = None
@@ -838,8 +831,8 @@ class GenericStack:
         serialisation) has them stamped from the templates on first ask.
         Rows that take the exact path today (failed placements, network
         asks, vanished nodes) never reach this build, so the descriptor
-        always covers the whole plan. Without `columnar` (or on a plan
-        that already holds placements) the objects are stamped here."""
+        always covers the whole plan. On a plan that already holds
+        placements the objects are stamped here, beside the descriptor."""
         from .system_sweep import SweepBatch
 
         nt = self.tindex.nt
@@ -894,15 +887,13 @@ class GenericStack:
         alloc_ids_l = generate_uuids(n)
         names_l = [tup.Name for tup in place]
 
-        as_columns = self.columnar and not plan.NodeAllocation
+        as_columns = not plan.NodeAllocation
         if not as_columns:
             tpl_dicts = [t.__dict__ for t in templates]
             for p in range(n):
                 plan.append_alloc(stamp_alloc(
                     tpl_dicts[alloc_tg_l[p]], alloc_ids_l[p], names_l[p],
                     ids_list[p]))
-            if not self.columnar:
-                return True
         # Columnar descriptor: unique placed rows with summed demand, plus
         # the per-alloc columns sorted into row order so chunk slices stay
         # contiguous (same layout the system sweep emits). The delta uses

@@ -150,7 +150,7 @@ class SweepBatch:
         }
 
 
-# Escape hatch for A/B benchmarks and oracle runs: True routes every
+# Escape hatch for the equivalence tests' oracle runs: True routes every
 # system eval onto the exact per-node path regardless of applicability.
 FORCE_EXACT = False
 

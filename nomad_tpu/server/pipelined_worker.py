@@ -104,8 +104,9 @@ FILL_TIMEOUT = 0.002
 
 # THE declared stats schema: every counter and stage timer the worker
 # maintains, pre-seeded at construction so the debug endpoint
-# (/v1/agent/debug/sched-stats), bench.py's reset/aggregate loops, and
-# tests can rely on key presence instead of .get() defaults that drift.
+# (/v1/agent/debug/sched-stats), the benchmark's readers
+# (benchmark/readers/worker_stats*.py) and the tests can rely on key
+# presence instead of .get() defaults that drift.
 # README's "Serving pipeline observability" section documents each key.
 STATS_COUNTERS = (
     "fast",       # evals committed via the device-chained fast path
@@ -273,16 +274,10 @@ class PipelinedWorker(Worker):
     """Drop-in Worker with windowed device-chained placement."""
 
     def __init__(self, *args, window: int = 32, host_placement: bool = True,
-                 chain_arbiter: Optional[ChainArbiter] = None,
-                 service_columnar: bool = True, **kwargs):
+                 chain_arbiter: Optional[ChainArbiter] = None, **kwargs):
         super().__init__(*args, **kwargs)
         self.window = max(1, window)
         self.host_placement = host_placement
-        # Columnar service commits (ServerConfig.service_columnar): the
-        # all-placed window build attaches a SweepBatch descriptor so the
-        # plan commits as ONE ApplySweepBatch entry + SweepSegment scatter.
-        # False keeps the per-object commit path (bench A/B oracle side).
-        self.service_columnar = service_columnar
         self._noise: Optional[np.ndarray] = None
         # Observability: how evals flowed (fast = device-chained window,
         # slow = per-eval GenericScheduler, fallback = fast dispatch that
@@ -840,16 +835,11 @@ class PipelinedWorker(Worker):
         work.taint_seq = lease.taint_seq
         return work
 
-    def reset_stats(self) -> None:
-        """Zero every schema key IN PLACE (readers like the debug endpoint
-        and bench.py hold a reference to the dict, not a copy). Call
-        quiesce() first when the zeros must not race in-flight windows."""
-        self.stats.update(new_stats())
-
     def quiesce(self, timeout: float = 30.0) -> bool:
         """Wait until every dispatched window — across ALL workers sharing
         the chain arbiter — has fully finished (drained, built, acked).
-        For tests/benchmarks that read or reset `stats`: eval completion
+        For tests and the benchmark's deploy modules, which read `stats`
+        after a window: eval completion
         becomes visible at the EvalUpdate apply, which is BEFORE the build
         stage's final stats writes for that window."""
         return self._arbiter.wait_drained(timeout)
@@ -888,8 +878,7 @@ class PipelinedWorker(Worker):
         # jobs are value-frozen in the state store and the plan only reads.
         plan = ev.make_plan(job, copy_job=False)
         ctx = EvalContext(snap, plan, logger)
-        stack = GenericStack(ctx, self.tindex, batch,
-                             columnar=self.service_columnar)
+        stack = GenericStack(ctx, self.tindex, batch)
         dc_key = tuple(sorted(job.Datacenters))
         cached = node_cache.get(dc_key)
         if cached is None:

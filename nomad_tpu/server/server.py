@@ -103,8 +103,8 @@ class ServerConfig:
     pipelined_scheduling: bool = True
     scheduler_window: int = 32
     # Placement engine for generic schedulers: "tpu" (device kernels) or
-    # "cpu-reference" (the reference's host iterator chain — the benchmark
-    # denominator runs THROUGH the same served path with this set).
+    # "cpu-reference" (the reference's host iterator chain, run THROUGH
+    # the same served path: the golden model of the parity tests).
     scheduler_impl: str = "tpu"
     # Multi-chip serving: "all" shards the node tensor (and every placement
     # kernel) over all local devices with jax.sharding — the SERVED windows
@@ -121,12 +121,11 @@ class ServerConfig:
     # serving tests and chip_smoke.py --chips 4 use that to prove the
     # device path compiles and runs.
     host_placement: bool = True
-    # Columnar service commits: all-placed pipelined windows ride the
-    # sweep-batch machinery end to end — one ApplySweepBatch raft entry +
-    # one SweepSegment store scatter per plan instead of per-object
-    # upserts (README "Columnar state store"). False keeps the per-object
-    # commit path (the bench `service_columnar` A/B's object side).
-    service_columnar: bool = True
+    # Not an option (no annotation: the constructor does not take it). The
+    # benchmark's configuration files list it among the settings their
+    # deploy modules read back from a running server, and a PR may not
+    # edit those files; a `benchmark` issue drops the key, then this line.
+    service_columnar = True
     # Server-side coalescing of Node.UpdateAlloc: concurrent client RPCs
     # within this window share ONE raft entry / future (reference:
     # batchUpdateInterval + batchFuture, node_endpoint.go:530-593). At 10k
@@ -260,9 +259,9 @@ class Server:
         # path bit-identical to pre-federation behavior.
         self.fed = self.config.federation
         if federation_enabled(self.fed):
-            # follower_snapshots=False is the bench's all-on-leader
-            # baseline arm: routing/forwarding/health identical, but
-            # workers pin fresh live-store watermarks per window.
+            # follower_snapshots=False is the all-on-leader arm:
+            # routing/forwarding/health identical, but workers pin fresh
+            # live-store watermarks per window (ROADMAP Named debts, D6).
             self.fed_source = (SnapshotSource(self.state, self.fed)
                                if self.fed.follower_snapshots else None)
             self.fed_health = FederationHealth(self.fed)
@@ -443,9 +442,7 @@ class Server:
                                     window=self.config.scheduler_window,
                                     host_placement=self.config
                                     .host_placement,
-                                    chain_arbiter=arbiter,
-                                    service_columnar=self.config
-                                    .service_columnar)
+                                    chain_arbiter=arbiter)
             else:
                 w = Worker(self.raft, self.eval_broker, self.plan_queue,
                            self.blocked_evals, self.tindex, schedulers)
@@ -573,8 +570,7 @@ class Server:
             self._alloc_flush_thread.join(timeout=30.0)
         # Join every thread that can touch JAX before returning: a daemon
         # thread still inside an XLA dispatch races CPython/XLA teardown
-        # and aborts the interpreter (round-3 regression: BENCH rc=134,
-        # MULTICHIP ok:false). Workers were signalled above, so joins
+        # and aborts the interpreter. Workers were signalled above, so joins
         # overlap their wind-down; the deadline bounds a wedged thread.
         deadline = time.monotonic() + 60.0
         for w in remote + self._retired_workers:

@@ -286,7 +286,8 @@ class Worker:
         self.blocked_evals = blocked_evals
         self.tindex = tindex
         self.schedulers = schedulers or ["service", "batch", "system"]
-        self.scheduler_impl = "tpu"  # or "cpu-reference" (bench denominator)
+        # "tpu", or "cpu-reference" (the parity tests' golden model)
+        self.scheduler_impl = "tpu"
         self.backend = backend or LocalBackend(raft, eval_broker, plan_queue)
         # Stable identity for per-worker observability (sched-stats keys
         # its report by this) and stage-thread names; start() overwrites
@@ -335,7 +336,7 @@ class Worker:
         path calls this from the raft notify thread). The Server keeps a
         reference and joins retired workers at shutdown — a worker thread
         left inside an XLA dispatch at interpreter exit aborts the whole
-        process (round-3 regression: bench rc=134)."""
+        process."""
         self._stop.set()
 
     def join(self, timeout: float = 30.0) -> None:

@@ -36,6 +36,7 @@ from nomad_tpu.structs import (
     Node,
     PeriodicLaunch,
     from_dict,
+    stamp_alloc,
     to_dict,
 )
 from nomad_tpu.structs.structs import (
@@ -147,19 +148,11 @@ class SweepSegment:
         if obj is not None:
             return obj
         template = self.templates[self.tg_idx[pos] if self.tg_idx else 0]
-        obj = object.__new__(Allocation)
-        obj.__dict__ = dict(template.__dict__)
-        obj.ID = self.alloc_ids[pos]
-        obj.Name = self.names[pos]
-        obj.NodeID = self.node_ids[pos]
-        obj.Services = {}
-        obj.TaskStates = {}
+        obj = stamp_alloc(template.__dict__, self.alloc_ids[pos],
+                          self.names[pos], self.node_ids[pos])
         obj.CreateIndex = self.index
         obj.ModifyIndex = self.index
         obj.AllocModifyIndex = self.index
-        vec = getattr(template, "_resvec_cache", None)
-        if vec is not None:
-            obj._resvec_cache = vec
         self._objs[pos] = obj
         return obj
 

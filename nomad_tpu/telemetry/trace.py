@@ -31,7 +31,7 @@ error trace you never recorded), so ``sample_ratio`` is a memory/noise
 knob, not a CPU one — enabling tracing is itself the opt-in to the
 recording overhead. Disarmed (``enabled=False``, the default) every
 entry point is one module-attribute truthiness check and a shared no-op
-context manager: ``bench.py --smoke`` parity is the gate.
+context manager (tests/test_trace.py holds the disarmed path inert).
 """
 
 from __future__ import annotations

@@ -1,6 +1,6 @@
 """JAX backend start-up for every process that schedules on a device.
 
-One call, made before anything compiles (Server.__init__, bench.py,
+One call, made before anything compiles (Server.__init__,
 chip_smoke.py): it initializes the configured backend and lets a failure
 raise — a scheduler that silently continues on another platform reports
 numbers for hardware it is not running on — and it places the persistent

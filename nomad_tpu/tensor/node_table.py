@@ -698,7 +698,7 @@ class ChainArbiter:
                 self._cond.notify_all()
 
     def wait_drained(self, timeout: Optional[float] = None) -> bool:
-        """Block until no window is in flight (quiesce for tests/bench)."""
+        """Block until no window is in flight (PipelinedWorker.quiesce)."""
         return self._drained.wait(timeout)
 
     def wait_dispatch_idle(self, timeout: float) -> bool:

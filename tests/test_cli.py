@@ -213,8 +213,7 @@ class TestClusterCommands:
 
     def test_sched_stats_prints_pipeline_timers(self, capsys, address):
         """`nomad-tpu sched-stats` surfaces the pipelined worker's stage
-        timers/counters (the numbers bench.py prints) via the debug-gated
-        endpoint."""
+        timers/counters via the debug-gated endpoint."""
         rc, out, _ = run_cli(capsys, "sched-stats", "-address", address)
         assert rc == 0
         assert "PipelinedWorker" in out

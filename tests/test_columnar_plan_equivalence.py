@@ -744,12 +744,12 @@ def test_a_reader_on_the_way_is_counted_and_changes_nothing(monkeypatch,
 
 
 def test_the_object_build_without_the_columnar_commit_is_unchanged():
-    """service_columnar off (the A/B's object side): objects at collect,
-    in placement order, no descriptor, counted as objects."""
+    """A job with a network ask (ports are per-placement offers, so its
+    window takes the exact build): objects at collect, in placement
+    order, no descriptor, counted as objects."""
     srv, worker = _server([mock.node() for _ in range(3)])
-    worker.service_columnar = False
     try:
-        job = svc_job(count=5)
+        job = svc_job(count=5, networks=True)
         srv.job_register(job)
         work = _dispatch(worker)
         _finish(worker, work)

@@ -219,9 +219,8 @@ class TestCommitPathParity:
         assert all(d["Kind"] == "service" for d in shadow.allocs.values())
 
 
-def _storm_server(columnar=True, event_buffer_size=4096):
+def _storm_server(event_buffer_size=4096):
     return Server(ServerConfig(num_schedulers=1, scheduler_window=8,
-                               service_columnar=columnar,
                                event_buffer_size=event_buffer_size,
                                min_heartbeat_ttl=3600.0,
                                heartbeat_grace=3600.0))
@@ -240,8 +239,10 @@ class TestLiveStormOracle:
         """A live service storm through a real server — placements, a
         deregister's evictions, eval lifecycle — folds from the event
         stream into exactly the store's membership, on BOTH service
-        commit paths (columnar batch events vs per-object updates)."""
-        srv = _storm_server(columnar=columnar)
+        commit paths (columnar batch events vs per-object updates). What
+        selects the per-object path is the job: a network ask (ports are
+        per-placement offers) keeps its plans as objects."""
+        srv = _storm_server()
         srv.establish_leadership()
         try:
             broker = srv.fsm.events
@@ -249,7 +250,7 @@ class TestLiveStormOracle:
                                    queue_size=100_000)
             for i in range(6):
                 srv.node_register(make_node(i))
-            jobs = [svc_job() for _ in range(4)]
+            jobs = [svc_job(networks=not columnar) for _ in range(4)]
             eval_ids = [srv.job_register(j)[0] for j in jobs]
             _wait_complete(srv, eval_ids)
             # Deregister one job: its evictions must stream as

@@ -1,4 +1,4 @@
-"""Tensor layer, kernel, pipelined placer, and multi-chip sharding tests."""
+"""Tensor layer, kernel, windowed placement, and multi-chip sharding tests."""
 
 import random
 
@@ -181,41 +181,60 @@ class TestPlaceBatchKernel:
         assert len(set(placed.tolist())) == 4
 
 
+def _served_stack(nodes, job, seed):
+    """A GenericStack over `nodes` as the served path holds one: a state
+    store with the node table attached, an eval's plan and context."""
+    from nomad_tpu.scheduler.context import EvalContext
+    from nomad_tpu.scheduler.stack import GenericStack
+    from nomad_tpu.state.state_store import StateStore
+
+    store = StateStore()
+    tindex = TensorIndex.attach(store)
+    for i, n in enumerate(nodes):
+        store.upsert_node(i + 1, n)
+    ev = mock.eval()
+    ev.JobID = job.ID
+    ctx = EvalContext(store.snapshot(), ev.make_plan(job, copy_job=False))
+    stack = GenericStack(ctx, tindex, batch=False, rng=random.Random(seed))
+    stack.set_nodes(nodes)
+    stack.set_job(job)
+    return stack
+
+
 class TestPipelinedPlacer:
+    """A window's placements through GenericStack.prepare_batch + dispatch,
+    the calls the pipelined worker makes."""
+
     def test_chained_contention(self):
         """Evals in one window contend for capacity device-side."""
-        from nomad_tpu.scheduler.pipeline import EvalRequest, PipelinedPlacer
-
         node = mock.node()  # 3900 usable CPU
-        tindex = TensorIndex()
-        tindex.nt.upsert_node(node)
-        placer = PipelinedPlacer(tindex, [node], rng=random.Random(1),
-                                 window=10)
         job = mock.job()
         job.TaskGroups[0].Tasks[0].Resources.CPU = 1000
         job.TaskGroups[0].Tasks[0].Resources.Networks = []
+        stack = _served_stack([node], job, seed=1)
+        prep = stack.prepare_batch([job.TaskGroups[0]])
         # 6 evals x 1 placement x 1000 CPU on one 3900-CPU node: 3 fit.
+        # Each eval's usage input is the previous one's usage_after, never
+        # copied back (the window's device chain).
+        usage, results = None, []
         for _ in range(6):
-            placer.submit(EvalRequest(job=job, tgs=[job.TaskGroups[0]]))
-        results = placer.flush()
-        placed = sum(int((r.chosen_rows >= 0).sum()) for r in results)
+            res = stack.dispatch(prep, usage_override=usage)
+            usage = res.usage_after
+            results.append(res.packed)
+        placed = sum(int((np.asarray(r)[:prep.n_valid, 0] >= 0).sum())
+                     for r in results)
         assert placed == 3
 
     def test_matches_stack_semantics(self):
-        from nomad_tpu.scheduler.pipeline import EvalRequest, PipelinedPlacer
-
         nodes = [mock.node() for _ in range(8)]
-        tindex = TensorIndex()
-        for n in nodes:
-            tindex.nt.upsert_node(n)
-        placer = PipelinedPlacer(tindex, nodes, rng=random.Random(1))
         job = mock.job()
         job.TaskGroups[0].Tasks[0].Resources.Networks = []
-        placer.submit(EvalRequest(job=job, tgs=[job.TaskGroups[0]] * 8))
-        (res,) = placer.flush()
-        assert (res.chosen_rows >= 0).all()
+        stack = _served_stack(nodes, job, seed=1)
+        prep = stack.prepare_batch([job.TaskGroups[0]] * 8)
+        chosen_rows = np.asarray(stack.dispatch(prep).packed)[:8, 0]
+        assert (chosen_rows >= 0).all()
         # Anti-affinity spreads over all 8 nodes.
-        assert len(set(res.chosen_rows.tolist())) == 8
+        assert len(set(chosen_rows.tolist())) == 8
 
 
 class TestSharding:
@@ -458,7 +477,6 @@ class TestPlacementQualityParity:
         """Global argmax must reach >= the reference iterator chain's total
         bin-pack score on the same workload."""
         from nomad_tpu.scheduler.cpu_reference import CPUReferenceStack
-        from nomad_tpu.scheduler.pipeline import EvalRequest, PipelinedPlacer
 
         nodes = []
         rng = np.random.default_rng(11)
@@ -474,13 +492,10 @@ class TestPlacementQualityParity:
         job.TaskGroups[0].Tasks[0].Resources.Networks = []
         tgs = [job.TaskGroups[0]] * 20
 
-        tindex = TensorIndex()
-        for n in nodes:
-            tindex.nt.upsert_node(n)
-        placer = PipelinedPlacer(tindex, nodes, rng=random.Random(5))
-        placer.submit(EvalRequest(job=job, tgs=tgs))
-        (res,) = placer.flush()
-        tpu_scores = res.scores[res.chosen_rows >= 0]
+        stack = _served_stack(nodes, job, seed=5)
+        prep = stack.prepare_batch(tgs)
+        packed = np.asarray(stack.dispatch(prep).packed)[:len(tgs)]
+        tpu_scores = packed[packed[:, 0] >= 0, 1]
         # Remove the tie-break noise contribution before comparing.
         tpu_total = float(tpu_scores.sum()) - 1e-3 * len(tpu_scores)
 

@@ -16,8 +16,9 @@ import random
 import threading
 import time
 from collections import OrderedDict
+from itertools import accumulate
 from dataclasses import dataclass, field
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -36,14 +37,16 @@ from nomad_tpu.structs.structs import (
     ConstraintDistinctHosts,
     JobTypeBatch,
     generate_uuid,
-    generate_uuids,
     stamp_alloc,
+    uuid_rows,
+    uuid_strings,
 )
 from nomad_tpu.tensor import ClassEligibility, TensorIndex, alloc_vec, resources_vec
 from nomad_tpu.tensor.node_table import DIM_NAMES, RES_DIMS
 
 from . import kernels
 from .context import EvalContext
+from .system_sweep import SweepBatch
 from .util import task_group_constraints
 
 # Anti-affinity penalties (reference: stack.go:10-19)
@@ -139,6 +142,190 @@ class WindowAccumulator:
             self._vecs.clear()
         return self._usage
 
+
+class _Queued(NamedTuple):
+    """One record of a window that WindowCollect builds as columns."""
+
+    stack: "GenericStack"
+    prep: "PreparedBatch"
+    cr: "kernels.CompactResult"
+    eval_id: str
+    job: Job
+    place: Sequence
+    plan: object
+
+
+class WindowCollect:
+    """The collect pass of one scheduling window: compacted kernel output
+    to plans, for all of the window's evals at once.
+
+    `add` is the ordered walk, one call an eval in chain order. An eval
+    whose every placement found a row and whose groups ask for no network
+    (the storm case) only queues its rows on the window's accumulator and
+    waits for `build`; any other runs the exact per-placement loop there
+    and then, so that its exhaustion diagnostics read the usage the
+    kernel saw. `build` then makes the queued evals' plans in ONE
+    columnar pass: one stable sort on (eval, row), the unique rows,
+    counts and summed demand of every eval from the sorted runs, one
+    gather of node ids, one id draw, one permutation of the names. What
+    is left per eval is what is per eval: the metric snapshot with its
+    scores, a template Allocation a task group, and slices of the
+    window's columns into the plan's SweepBatch.
+
+    The columns ride each plan as a SweepBatch descriptor
+    (kind="service"): the applier bulk-verifies it as one vector op,
+    replicates it as one ApplySweepBatch raft entry, and the store
+    scatter-applies it as a SweepSegment. plan.NodeAllocation is a
+    ColumnarPlacements view over the same descriptor, so the all-fit
+    path never holds an object per placement; a reader that wants them
+    (partial verdict, refused descriptor, exact verify, serialisation)
+    has them stamped from the templates on first ask. The evals may
+    belong to different PreparedBatches, stacks and node sets. On a plan
+    that already holds placements the objects are stamped at once, beside
+    the descriptor."""
+
+    __slots__ = ("nt", "acc", "_queued")
+
+    def __init__(self, nt, acc: Optional[WindowAccumulator] = None):
+        self.nt = nt
+        self.acc = acc if acc is not None else WindowAccumulator(nt.n_rows)
+        self._queued: List[_Queued] = []
+
+    def add(self, stack: "GenericStack", prep: "PreparedBatch", cr,
+            eval_id: str, job: Job, place, plan,
+            failed_tg_allocs) -> Optional[bool]:
+        """The window's next eval, in chain order. None: queued for
+        `build`, whose verdicts come in the order of these calls.
+        Otherwise the exact build's verdict (False: a winner failed
+        host-side network assignment or its node vanished, and the caller
+        falls back to the exact per-eval path)."""
+        if cr.ok and not prep.has_network_asks:
+            n = len(place)
+            # A node that turns out to have vanished leaves these rows in
+            # the accumulator: the kernel saw them too, and the phantom-
+            # usage quarantine re-runs whatever failed behind them.
+            self.acc.add(cr.chosen[:n], prep.demands[:n])
+            self._queued.append(
+                _Queued(stack, prep, cr, eval_id, job, place, plan))
+            return None
+        return stack._collect_build_exact(prep, cr, eval_id, job, place,
+                                          plan, failed_tg_allocs, self.acc)
+
+    def build(self) -> List[bool]:
+        """Plans for the queued evals, one verdict each (False: a chosen
+        node vanished mid-window, row freed or reused; that eval alone
+        falls back, as when the per-placement lookup fails)."""
+        queued, self._queued = self._queued, []
+        return self._build(queued) if queued else []
+
+    def _build(self, queued: List[_Queued]) -> List[bool]:
+        nt = self.nt
+        n_rows = nt.n_rows
+        n_evals = len(queued)
+        layouts = [q.stack._template_layout(q.prep) for q in queued]
+        ns = [len(q.place) for q in queued]
+        offs = list(accumulate(ns, initial=0))
+        total = offs[-1]
+
+        # One stable sort on (eval, row): an eval's placements stay
+        # together, row-sorted, in placement order within a row.
+        rows = key = np.concatenate(
+            [q.cr.chosen[:n] for q, n in zip(queued, ns)], dtype=np.int64)
+        if n_evals > 1:  # a window of one has nothing to offset
+            key = rows + np.repeat(
+                np.arange(0, n_evals * n_rows, n_rows), ns)
+        order = np.argsort(key, kind="stable")
+        key = key[order]
+        first = np.empty(total, dtype=bool)  # of its run, one a (eval, row)
+        first[0] = True
+        np.not_equal(key[1:], key[:-1], out=first[1:])
+        run_starts = np.flatnonzero(first)
+        run_ends = np.append(run_starts, total)
+        urows = rows[order[run_starts]]
+        run_of = np.searchsorted(run_starts, offs).tolist()
+
+        id_arr = nt.node_id_array()
+        epoch = nt.row_epoch
+        node_ids = id_arr[urows].tolist()
+        verdicts = [q.stack._nodes_by_id.keys() >= set(node_ids[lo:hi])
+                    for q, lo, hi in zip(queued, run_of, run_of[1:])]
+        if not all(verdicts):
+            # Rare: the vanished evals leave, the others' columns are
+            # made without them.
+            kept = iter(self._build(
+                [q for q, ok in zip(queued, verdicts) if ok])
+                if any(verdicts) else ())
+            return [ok and next(kept) for ok in verdicts]
+
+        # Summed demand per (eval, row) from the template resource
+        # vectors: exactly what alloc_vec() yields for every stamped
+        # clone, so the applier's bulk verify and the optimistic overlay
+        # account the same bytes the object path would.
+        delta = np.add.reduceat(
+            np.concatenate([lay.placed_vecs for lay in layouts])[order],
+            run_starts, axis=0)
+        counts = run_ends[1:] - run_starts
+        alloc_tg = np.concatenate(
+            [lay.alloc_tg for lay in layouts])[order].tolist()
+
+        id_rows = uuid_rows(total)
+        alloc_ids = uuid_strings(id_rows[order])
+        names = [tup.Name for q in queued for tup in q.place]
+        alloc_names = np.asarray(names, dtype=object)[order].tolist()
+        placed_ids = id_arr[rows]
+        score_keys = (placed_ids + ".binpack").tolist()
+        scores = np.concatenate(
+            [q.cr.scores[:n] for q, n in zip(queued, ns)]).tolist()
+
+        for k, (q, layout) in enumerate(zip(queued, layouts)):
+            a, b = offs[k], offs[k + 1]
+            lo, hi = run_of[k], run_of[k + 1]
+            metrics_ = q.stack.ctx.metrics
+            metrics_.Scores.update(zip(score_keys[a:b], scores[a:b]))
+            q.stack._fill_metrics(q.prep, layout.last_ti, q.cr.nf_last)
+            # Scoring is final now: one immutable metric snapshot shared
+            # by every placed alloc (reference: alloc.Metrics). Templates
+            # are per eval (EvalID and metrics are); their task-resource
+            # dict and vector come from the shared prep memo.
+            shared_metric = metrics_.copy()
+            templates = []
+            for tg_name, tr, vec in layout.groups:
+                template = Allocation(
+                    EvalID=q.eval_id,
+                    JobID=q.job.ID,
+                    TaskGroup=tg_name,
+                    TaskResources=tr,
+                    Metrics=shared_metric,
+                    DesiredStatus=AllocDesiredStatusRun,
+                    ClientStatus=AllocClientStatusPending,
+                )
+                template._resvec_cache = vec
+                templates.append(template)
+            plan = q.plan
+            as_columns = not plan.NodeAllocation
+            if not as_columns:
+                tpl_dicts = [t.__dict__ for t in templates]
+                for tg, alloc_id, name, node_id in zip(
+                        layout.alloc_tg.tolist(),
+                        uuid_strings(id_rows[a:b]), names[a:b],
+                        placed_ids[a:b].tolist()):
+                    plan.append_alloc(stamp_alloc(
+                        tpl_dicts[tg], alloc_id, name, node_id))
+            # Same layout the system sweep emits: unique placed rows with
+            # summed demand, and the per-alloc columns in row order so
+            # chunk slices stay contiguous.
+            plan._sweep = SweepBatch(
+                rows=urows[lo:hi], node_ids=node_ids[lo:hi],
+                delta=delta[lo:hi], epoch=epoch, n_rows=n_rows,
+                counts=counts[lo:hi], starts=run_ends[lo:hi + 1] - a,
+                alloc_ids=alloc_ids[a:b], alloc_names=alloc_names[a:b],
+                alloc_tg=alloc_tg[a:b], templates=templates,
+                kind="service")
+            if as_columns:
+                plan.NodeAllocation = ColumnarPlacements.over(plan._sweep)
+        return [True] * n_evals
+
+
 # Row-steps (node rows x padded placements) under which an eval places via
 # the numpy mirror (kernels.place_batch_host) instead of a device dispatch:
 # a shallow window then needs no dispatch, readback or cold compile. The
@@ -207,6 +394,19 @@ class PreparedBatch:
     # alloc._resvec_cache — anything that changes resources replaces the
     # objects).
     tr_templates: Optional[dict] = None
+    # Lazily built template columns of the same build (_TemplateLayout).
+    tpl_layout: Optional["_TemplateLayout"] = None
+
+
+class _TemplateLayout(NamedTuple):
+    """What WindowCollect reads of a PreparedBatch: its placements' task
+    groups as template indexes, in order of first appearance."""
+
+    alloc_tg: np.ndarray    # [n_valid] int64 template index per placement
+    groups: List[tuple]     # per template (task group name, task resources,
+    #                         resource vector)
+    placed_vecs: np.ndarray  # [n_valid, RES_DIMS] f32 the vector per placement
+    last_ti: int            # unique-TG index of the last placement
 
 
 def _pad_pow2(n: int, floor: int = 8) -> int:
@@ -809,137 +1009,61 @@ class GenericStack:
             ent = templates[ti] = (tr, vec)
         return ent
 
-    def _collect_build_all_placed(self, prep: PreparedBatch, cr,
-                                  eval_id: str, job: Job, place, plan,
-                                  acc: "WindowAccumulator") -> bool:
-        """Vectorized build for the storm case: every placement found a
-        row and no group asks for networks. One fancy-index gather maps
-        chosen rows to node IDs, scores land in the metrics dict via one
-        zip pass, the window-usage contribution queues as one batch, and
-        the placements leave as COLUMNS: ids from one batched draw, names
-        from `place`, the node id and the task group's template index per
-        placement. The only Allocations constructed are the templates, one
-        a task group (the sweep path's frozen template).
-
-        The columns ride the plan as a SweepBatch descriptor
-        (kind="service"): the applier bulk-verifies it as one vector op,
-        replicates it as one ApplySweepBatch raft entry, and the store
-        scatter-applies it as a SweepSegment. plan.NodeAllocation is a
-        ColumnarPlacements view over the same descriptor, so the all-fit
-        path never holds an object per placement; a reader that wants
-        them (partial verdict, refused descriptor, exact verify,
-        serialisation) has them stamped from the templates on first ask.
-        Rows that take the exact path today (failed placements, network
-        asks, vanished nodes) never reach this build, so the descriptor
-        always covers the whole plan. On a plan that already holds
-        placements the objects are stamped here, beside the descriptor."""
-        from .system_sweep import SweepBatch
-
-        nt = self.tindex.nt
-        n = len(place)
-        rows = cr.chosen[:n]
-        id_arr = nt.node_id_array()
-        ids = id_arr[rows]
-        nodes_by_id = self._nodes_by_id
-        ids_list = ids.tolist()
-        for nid in set(ids_list):
-            # Node vanished mid-window (row freed/reused): exact path owns
-            # it — identical outcome to the per-placement lookup failing.
-            if nid is None or nid not in nodes_by_id:
-                return False
-
-        metrics_ = self.ctx.metrics
-        scores_list = cr.scores[:n].tolist()
-        Scores = metrics_.Scores
-        for nid, s in zip(ids_list, scores_list):
-            Scores[f"{nid}.binpack"] = s
-        tg_index = prep.tg_index
-        tgs = prep.tgs
-        self._fill_metrics(prep, tg_index[tgs[n - 1].Name], cr.nf_last)
-        rows64 = rows.astype(np.int64, copy=False)
-        acc.add(rows64, prep.demands[:n])
-
-        # Scoring is final now: one immutable metric snapshot shared by
-        # every placed alloc (reference: alloc.Metrics). Templates are
-        # per-CALL (eval_id/metrics are per-eval) but their task-resource
-        # dict + vector come from the shared prep memo.
-        shared_metric = metrics_.copy()
-        templates: List[Allocation] = []
-        tpl_of: Dict[int, int] = {}
-        alloc_tg_l: List[int] = []
-        for p, ti in enumerate(prep.tg_ids[:n].tolist()):
-            k = tpl_of.get(ti)
-            if k is None:
-                tr, vec = self._tg_template(prep, ti)
-                template = Allocation(
-                    EvalID=eval_id,
-                    JobID=job.ID,
-                    TaskGroup=tgs[p].Name,
-                    TaskResources=tr,
-                    Metrics=shared_metric,
-                    DesiredStatus=AllocDesiredStatusRun,
-                    ClientStatus=AllocClientStatusPending,
-                )
-                template._resvec_cache = vec
-                k = tpl_of[ti] = len(templates)
-                templates.append(template)
-            alloc_tg_l.append(k)
-        alloc_ids_l = generate_uuids(n)
-        names_l = [tup.Name for tup in place]
-
-        as_columns = not plan.NodeAllocation
-        if not as_columns:
-            tpl_dicts = [t.__dict__ for t in templates]
-            for p in range(n):
-                plan.append_alloc(stamp_alloc(
-                    tpl_dicts[alloc_tg_l[p]], alloc_ids_l[p], names_l[p],
-                    ids_list[p]))
-        # Columnar descriptor: unique placed rows with summed demand, plus
-        # the per-alloc columns sorted into row order so chunk slices stay
-        # contiguous (same layout the system sweep emits). The delta uses
-        # the template resource vectors — exactly what alloc_vec() yields
-        # for every stamped clone, so the applier's bulk verify and the
-        # optimistic overlay account the same bytes the object path would.
-        alloc_tg = np.asarray(alloc_tg_l, dtype=np.int64)
-        ur, inv = np.unique(rows64, return_inverse=True)
-        tpl_vecs = np.stack([t._resvec_cache for t in templates])
-        delta = np.zeros((len(ur), RES_DIMS), dtype=np.float32)
-        np.add.at(delta, inv, tpl_vecs[alloc_tg])
-        order = np.argsort(rows64, kind="stable")
-        counts = np.bincount(inv, minlength=len(ur)).astype(np.int64)
-        starts = np.concatenate([np.zeros(1, dtype=np.int64),
-                                 np.cumsum(counts, dtype=np.int64)])
-        plan._sweep = SweepBatch(
-            rows=ur, node_ids=id_arr[ur].tolist(), delta=delta,
-            epoch=nt.row_epoch, n_rows=nt.n_rows,
-            counts=counts, starts=starts,
-            alloc_ids=np.asarray(alloc_ids_l, dtype=object)[order].tolist(),
-            alloc_names=np.asarray(names_l, dtype=object)[order].tolist(),
-            alloc_tg=alloc_tg[order].tolist(),
-            templates=templates, kind="service")
-        if as_columns:
-            plan.NodeAllocation = ColumnarPlacements.over(plan._sweep)
-        return True
+    def _template_layout(self, prep: PreparedBatch) -> "_TemplateLayout":
+        """The template columns of the vectorised build, which depend on
+        the PreparedBatch alone: built once, shared by every eval that
+        adopts it."""
+        layout = prep.tpl_layout
+        if layout is None:
+            tis: List[int] = []
+            local: List[int] = []
+            tpl_of: Dict[int, int] = {}
+            for ti in prep.tg_ids[:prep.n_valid].tolist():
+                k = tpl_of.get(ti)
+                if k is None:
+                    k = tpl_of[ti] = len(tis)
+                    tis.append(ti)
+                local.append(k)
+            entries = [self._tg_template(prep, ti) for ti in tis]
+            names = {ti: name for name, ti in prep.tg_index.items()}
+            layout = prep.tpl_layout = _TemplateLayout(
+                alloc_tg=np.asarray(local, dtype=np.int64),
+                groups=[(names[ti], tr, vec)
+                        for ti, (tr, vec) in zip(tis, entries)],
+                placed_vecs=np.stack([vec for _, vec in entries])[local],
+                last_ti=int(prep.tg_ids[prep.n_valid - 1]))
+        return layout
 
     def collect_build(self, prep: PreparedBatch, cr,
                       eval_id: str, job: Job, place,
                       plan, failed_tg_allocs,
                       acc: "WindowAccumulator") -> bool:
-        """Fused collect + build_placement_allocs for the pipelined fast
-        path: ONE pass from the compacted kernel output (CompactResult —
-        chosen rows, scores, per-eval success) to plan allocations,
-        skipping the SelectedOption list and the placed_counts/hosts
-        accumulators the windowed caller never reads (they exist for the
-        sync path's banned-row retry loop). The all-placed no-network case
-        — the storm window — takes the vectorized build above; failures
-        and network asks keep the exact per-placement loop. Returns False
-        when a winner fails host-side network assignment or its node
-        vanished — the caller falls back to the exact per-eval path, same
-        as a non-empty failed_rows from collect()."""
-        if cr.ok and not prep.has_network_asks:
-            return self._collect_build_all_placed(prep, cr, eval_id, job,
-                                                  place, plan, acc)
+        """Fused collect + build_placement_allocs for one eval of the
+        pipelined fast path: a WindowCollect of one record (the window
+        worker hands it the whole window at once). Returns False when a
+        winner fails host-side network assignment or its node vanished:
+        the caller falls back to the exact per-eval path, same as a
+        non-empty failed_rows from collect()."""
+        window = WindowCollect(self.tindex.nt, acc)
+        ok = window.add(self, prep, cr, eval_id, job, place, plan,
+                        failed_tg_allocs)
+        if ok is None:
+            [ok] = window.build()
+        return ok
 
+    def _collect_build_exact(self, prep: PreparedBatch, cr,
+                             eval_id: str, job: Job, place,
+                             plan, failed_tg_allocs,
+                             acc: "WindowAccumulator") -> bool:
+        """The exact per-placement build: ONE pass from the compacted
+        kernel output (CompactResult: chosen rows, scores, per-eval
+        success) to plan allocations, skipping the SelectedOption list and
+        the placed_counts/hosts accumulators the windowed caller never
+        reads (they exist for the sync path's banned-row retry loop).
+        Serves what WindowCollect does not build as columns (failed
+        placements, network asks) and is the oracle of what it does.
+        Returns False when a winner fails host-side network assignment or
+        its node vanished."""
         nt = self.tindex.nt
         chosen_list = cr.chosen.tolist()
         scores_list = cr.scores.tolist()

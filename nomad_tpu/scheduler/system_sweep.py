@@ -105,7 +105,7 @@ class SweepBatch:
     alloc_tg: List[int] = None      # [K] index into templates
     templates: List = None          # per-TG frozen template Allocations
     # Which emit path built the batch: "system" (tensor sweep) or
-    # "service" (pipelined service window, stack._collect_build_all_placed).
+    # "service" (pipelined service window, stack.WindowCollect.build).
     # Carried through the raft entry into the SweepSegment so operators
     # can see which commit path a storm took (sched-stats `Store` block).
     kind: str = "system"

@@ -74,7 +74,7 @@ from nomad_tpu.scheduler import kernels
 from nomad_tpu.scheduler.stack import (
     GenericStack,
     PreparedBatch,
-    WindowAccumulator,
+    WindowCollect,
     device_input,
 )
 from nomad_tpu.scheduler.util import (
@@ -137,6 +137,11 @@ STATS_COUNTERS = (
     "plans_objects",   # every other one: objects built at collect, or
     #                    asked for later by any reader (partial verdict,
     #                    refused descriptor, exact verify, serialisation)
+    "collect_windowed",  # non-stale fast evals whose plan the window's one
+    #                      columnar collect pass built (stack.WindowCollect)
+    "collect_exact",     # every other one: the exact per-placement loop
+    #                      (failed placements, network asks), or refused by
+    #                      the pass (vanished node) and re-run per eval
 )
 STATS_TIMERS_MS = (
     "t_lease_ms",        # waiting for the shared chain-lease (ChainArbiter)
@@ -982,36 +987,52 @@ class PipelinedWorker(Worker):
                     rec.span.finish()
 
     def _submit_window(self, work: _WindowWork) -> None:
-        """The build stage: packed results -> plans, enqueued back-to-back
-        (the applier verifies plan i while we materialize plan i+1's
-        ports host-side)."""
+        """The build stage: packed results -> plans. The whole window is
+        collected first (one columnar pass for the evals that placed
+        everything without network asks, the exact loop in chain order
+        for the others), then its plans are enqueued together, in chain
+        order, with one `enqueue_all`."""
         fast, packed = work.fast, work.packed
-        nt = self.tindex.nt
         # The kernels ran chained: eval k saw evals 1..k-1's placements.
-        # The shared accumulator can reproduce that chain host-side so
+        # The window's accumulator can reproduce that chain host-side so
         # exhaustion diagnostics diff against the usage the kernel actually
         # saw — but it stays DEFERRED (queued batches, no scatter) until an
         # exhaustion actually reads it, which an all-placed storm window
         # never does.
-        acc = WindowAccumulator(nt.n_rows)
+        window = WindowCollect(self.tindex.nt)
         submit: List[_FastEval] = []
         with self._stage("collect", work.number):
-            for rec, cr in zip(fast, packed):
-                if rec.stale:
-                    continue  # redelivered between stages: abandoned
+            # Redelivered between stages: abandoned.
+            live = [(rec, cr) for rec, cr in zip(fast, packed)
+                    if not rec.stale]
+            queued: List[_FastEval] = []
+            for rec, cr in live:
                 try:
-                    ok = rec.stack.collect_build(
-                        rec.prep, cr, rec.ev.ID, rec.plan.Job, rec.place,
-                        rec.plan, rec.failed_tg_allocs, acc)
+                    ok = window.add(
+                        rec.stack, rec.prep, cr, rec.ev.ID, rec.plan.Job,
+                        rec.place, rec.plan, rec.failed_tg_allocs)
                 except Exception:
                     logger.exception("collect failed for eval %s", rec.ev.ID)
-                    rec.fallback = True
-                    continue
-                if not ok:
+                    ok = False
+                if ok is None:
+                    queued.append(rec)
+                elif not ok:
                     # Port collision against the cached index (or a node that
                     # vanished mid-window): rare; the sync path's banned-row
                     # retry loop owns it.
                     rec.fallback = True
+            try:
+                built = window.build()
+            except Exception:
+                logger.exception("collect failed for window %d", work.number)
+                built = [False] * len(queued)
+            for rec, ok in zip(queued, built):
+                if not ok:
+                    rec.fallback = True  # a vanished node: as above
+            self.stats["collect_windowed"] += sum(built)
+            self.stats["collect_exact"] += len(live) - sum(built)
+            for rec, _ in live:
+                if rec.fallback:
                     continue
                 if rec.plan.is_no_op() and not rec.failed_tg_allocs:
                     rec.fallback = True  # nothing placeable: sync path decides

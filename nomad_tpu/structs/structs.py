@@ -17,6 +17,8 @@ from dataclasses import dataclass, field, replace
 from collections.abc import KeysView
 from typing import Any, Dict, List, Optional
 
+import numpy as np
+
 # --- Duration helpers (Go time.Duration is int64 nanoseconds on the wire) ---
 NANOSECOND = 1
 MICROSECOND = 1000 * NANOSECOND
@@ -146,16 +148,36 @@ _UUID_VERSION = bytes((b & 0x0F) | 0x40 for b in range(256))
 _UUID_VARIANT = bytes((b & 0x3F) | 0x80 for b in range(256))
 
 
-def generate_uuids(n: int) -> List[str]:
-    """`n` IDs of generate_uuid's shape from ONE os.urandom draw: what a
-    window's build mints for an eval's placements, as a column."""
+def uuid_rows(n: int) -> np.ndarray:
+    """`n` IDs of generate_uuid's shape from ONE os.urandom draw, as the
+    rows of a [n, 37] matrix of ASCII bytes (36 characters and a newline):
+    a column that is permuted or sliced as bytes before any string of it
+    exists. uuid_strings makes the strings."""
     raw = bytearray(os.urandom(16 * n))
     raw[6::16] = raw[6::16].translate(_UUID_VERSION)
     raw[8::16] = raw[8::16].translate(_UUID_VARIANT)
-    hx = raw.hex()
-    return ["-".join((hx[i:i + 8], hx[i + 8:i + 12], hx[i + 12:i + 16],
-                      hx[i + 16:i + 20], hx[i + 20:i + 32]))
-            for i in range(0, 32 * n, 32)]
+    hx = np.frombuffer(raw.hex().encode("ascii"), np.uint8).reshape(n, 32)
+    out = np.empty((n, 37), np.uint8)
+    out[:, 8] = out[:, 13] = out[:, 18] = out[:, 23] = 0x2D  # "-"
+    out[:, 36] = 0x0A
+    out[:, 0:8] = hx[:, 0:8]
+    out[:, 9:13] = hx[:, 8:12]
+    out[:, 14:18] = hx[:, 12:16]
+    out[:, 19:23] = hx[:, 16:20]
+    out[:, 24:36] = hx[:, 20:32]
+    return out
+
+
+def uuid_strings(rows: np.ndarray) -> List[str]:
+    """The IDs of uuid_rows' rows (or of any selection of them), in row
+    order: one decode and one split for the lot."""
+    return rows.tobytes().decode("ascii").splitlines()
+
+
+def generate_uuids(n: int) -> List[str]:
+    """`n` IDs of generate_uuid's shape from ONE os.urandom draw: what a
+    window's build mints for its placements, as a column."""
+    return uuid_strings(uuid_rows(n))
 
 
 class ValidationError(Exception):

@@ -2,12 +2,13 @@
 columns only (ISSUE 29).
 
 The all-placed, no-network build of the pipelined worker
-(GenericStack._collect_build_all_placed) leaves a plan whose
-NodeAllocation is a ColumnarPlacements view over its SweepBatch: no
-Allocation per placement exists unless a reader asks for one. The same
-windows are run twice, once as shipped and once with the build this
-replaced (one cloned object per placement beside the descriptor: kept here
-as the reference, fed from the same seeded entropy), and must agree on
+(stack.WindowCollect: one columnar pass a window since ISSUE 31) leaves a
+plan whose NodeAllocation is a ColumnarPlacements view over its
+SweepBatch: no Allocation per placement exists unless a reader asks for
+one. The same windows are run twice, once as shipped and once with the
+build this replaced (one eval at a time, one cloned object per placement
+beside the descriptor: kept here as the reference, fed from the same
+seeded entropy), and must agree on
 everything a replica or a reader can see: store reads by job and by node,
 the ApplySweepBatch payloads, the usage table, the replica digest chain,
 and, once materialised, the plan itself field for field.
@@ -32,7 +33,7 @@ import pytest
 from benchmark.deploy.dev_agent_dcs import build_fleet, seeded_uuid
 from nomad_tpu import mock
 from nomad_tpu.scheduler import stack as stack_mod
-from nomad_tpu.scheduler.stack import GenericStack
+from nomad_tpu.scheduler.stack import GenericStack, WindowCollect
 from nomad_tpu.scheduler.system_sweep import SweepBatch
 from nomad_tpu.server import Server, ServerConfig
 from nomad_tpu.server.fsm import MessageType
@@ -84,10 +85,12 @@ def _seed_ids(monkeypatch, seed):
     structs_mod._UUID_POOL.clear()
 
 
-def _reference_build(self, prep, cr, eval_id, job, place, plan, acc):
-    """The build this PR replaced: one cloned Allocation per placement in
-    plan.NodeAllocation, and the descriptor beside them. IDs come from the
-    same batched draw, so that both builds spend the stream alike."""
+def _reference_build(self, prep, cr, eval_id, job, place, plan):
+    """The build ISSUE 29 replaced, for one eval: one cloned Allocation per
+    placement in plan.NodeAllocation, and the descriptor beside them from
+    numpy calls of its own (unique, add.at, argsort, bincount). IDs come
+    from a batched draw an eval: the window's one draw spends the seeded
+    stream alike, 16 bytes a placement in chain order."""
     nt = self.tindex.nt
     n = len(place)
     rows = cr.chosen[:n]
@@ -102,7 +105,6 @@ def _reference_build(self, prep, cr, eval_id, job, place, plan, acc):
     tg_index, tgs = prep.tg_index, prep.tgs
     self._fill_metrics(prep, tg_index[tgs[n - 1].Name], cr.nf_last)
     rows64 = rows.astype(np.int64, copy=False)
-    acc.add(rows64, prep.demands[:n])
     shared_metric = metrics_.copy()
     templates, tpl_of = [], {}
     alloc_ids, names = generate_uuids(n), []
@@ -147,6 +149,11 @@ def _reference_build(self, prep, cr, eval_id, job, place, plan, acc):
         alloc_tg=alloc_tg[order].tolist(), templates=templates,
         kind="service")
     return True
+
+
+def _reference_window(self, queued):
+    """WindowCollect._build as the reference: one eval at a time."""
+    return [_reference_build(*q) for q in queued]
 
 
 def _server(nodes, host_placement=True, window=16):
@@ -204,8 +211,7 @@ def _run(monkeypatch, shape, reference):
         patch.setattr(stack_mod, "make_noise_vec",
                       lambda n, rng: noise(n, random.Random(29)))
         if reference:
-            patch.setattr(GenericStack, "_collect_build_all_placed",
-                          _reference_build)
+            patch.setattr(WindowCollect, "_build", _reference_window)
         srv, worker = _server(
             build_fleet(CONFIG["fleet"], 96, random.Random(28)), host)
         try:
@@ -359,8 +365,7 @@ def test_before_any_commit_the_two_builds_hold_equal_plans(monkeypatch, count,
     columns = service_window(svc_job(count=count), n_nodes=n_nodes)
     with monkeypatch.context() as patch:
         _seed_ids(patch, 31)
-        patch.setattr(GenericStack, "_collect_build_all_placed",
-                      _reference_build)
+        patch.setattr(WindowCollect, "_build", _reference_window)
         objects = service_window(svc_job(count=count), n_nodes=n_nodes)
     assert isinstance(columns.plan.NodeAllocation, ColumnarPlacements)
     assert type(objects.plan.NodeAllocation) is dict
@@ -396,6 +401,242 @@ def test_the_counters_say_which_build_ran(runs, shape):
         assert run.stats["fast"] == run.evals and run.stats["fallback"] == 0
     host = SHAPES[shape][0]
     assert (columns.stats["host"] == columns.evals) is host
+
+
+# ------------------------------- a mixed window, against one eval at a time
+# (ISSUE 31) What the window's one pass builds for seven evals of four
+# kinds, against the same window built one eval at a time, and against the
+# reference above. Two preps over different node sets (dc1, dc2) and a
+# second eval on the first's, an eval of two task groups over all four
+# datacenters, repeated rows (50 placements on ~60 nodes and fewer), a
+# record whose placements fail half-way (so the exact loop reads the
+# accumulator the pass only queued on), a stale record, and a record whose
+# node vanished. The device path chains a prep's evals together, so the
+# roles go by job and the short one stands where both orders leave it
+# before the stale and the vanished one (behind either, the phantom-usage
+# quarantine would re-run it).
+MIXED = ["local-dc1", "global-2tg", "local-dc4", "local-dc2", "local-dc1",
+         "local-dc3", "local-dc2"]
+SHORT, STALE, VANISHED = "local-dc4-2", "local-dc2-3", "local-dc3-5"
+ORACLES = {
+    "one-eval-at-a-time": lambda patch: patch.setattr(
+        WindowCollect, "add", _add_then_build),
+    "issue-29-reference": lambda patch: patch.setattr(
+        WindowCollect, "_build", _reference_window),
+}
+_add, _build_queued = WindowCollect.add, WindowCollect.build
+
+
+def _add_then_build(self, *record):
+    ok = _add(self, *record)
+    if ok is None:
+        [ok] = _build_queued(self)
+    return ok
+
+
+def _mixed_window(monkeypatch, host, oracle=None):
+    with monkeypatch.context() as patch:
+        _seed_ids(patch, 31)
+        noise = stack_mod.make_noise_vec
+        patch.setattr(stack_mod, "make_noise_vec",
+                      lambda n, rng: noise(n, random.Random(31)))
+        if oracle is not None:
+            ORACLES[oracle](patch)
+        read, usage = [], stack_mod.WindowAccumulator.usage
+        patch.setattr(stack_mod.WindowAccumulator, "usage", lambda acc: (
+            read.append(float(usage(acc)[:, 0].sum())), usage(acc))[1])
+        srv, worker = _server(
+            build_fleet(CONFIG["fleet"], 160, random.Random(28)), host)
+        try:
+            rng = random.Random(2031)
+            for i, template in enumerate(MIXED):
+                job = from_dict(Job, CONFIG["jobs"][template])
+                job.ID = seeded_uuid(rng)
+                job.Name = f"{template}-{i}"
+                if job.Name == SHORT:  # one a node, and dc4 has not fifty
+                    job.TaskGroups[0].Tasks[0].Resources.CPU = 3000
+                srv.job_register(job)
+            work = _dispatch(worker)
+            assert len(work.fast) == len(MIXED)
+            chain = [rec.plan.Job.Name for rec in work.fast]
+            assert chain.index(SHORT) < min(chain.index(STALE),
+                                            chain.index(VANISHED))
+            work.fast[chain.index(STALE)].stale = True
+            gone = work.fast[chain.index(VANISHED)]
+            row = int(work.packed[chain.index(VANISHED)].chosen[0])
+            gone.stack._nodes_by_id = {
+                nid: node for nid, node in gone.stack._nodes_by_id.items()
+                if nid != srv.tindex.nt.node_id_array()[row]}
+            _finish(worker, work)
+            return types.SimpleNamespace(
+                recs=work.fast, chain=chain, stats=dict(worker.stats),
+                n_rows=srv.tindex.nt.n_rows, accumulator_cpu=read)
+        finally:
+            srv.shutdown()
+
+
+def _metric(m):
+    plain = _without_clock(to_dict(m))
+    # Insertion order too: a reader that lists the scores sees one order.
+    return plain, list(m.Scores.items())
+
+
+@pytest.fixture(scope="module")
+def mixed():
+    """(host, oracle or None) -> that run of the mixed window, made once."""
+    patch = pytest.MonkeyPatch()
+    made = {}
+
+    def get(host, oracle=None):
+        if (host, oracle) not in made:
+            made[host, oracle] = _mixed_window(patch, host, oracle)
+        return made[host, oracle]
+
+    yield get
+    patch.undo()
+
+
+@pytest.mark.parametrize("host", [True, False],
+                         ids=["host-placement", "device"])
+@pytest.mark.parametrize("oracle", list(ORACLES))
+def test_the_windows_one_pass_builds_what_one_eval_at_a_time_builds(
+        mixed, host, oracle):
+    window, single = mixed(host), mixed(host, oracle)
+    assert window.stats["collect_windowed"] == 4
+    assert window.stats["collect_exact"] == 2  # the short one, the vanished
+    assert window.stats["stale"] == single.stats["stale"] == 1
+    assert window.chain == single.chain
+    if host:
+        assert window.chain == [f"{t}-{i}" for i, t in enumerate(MIXED)]
+    minted = []
+    for i, (mine, twin) in enumerate(zip(window.recs, single.recs)):
+        who = window.chain[i]
+        assert (mine.stale, mine.fallback) == (twin.stale, twin.fallback), i
+        assert mine.stale is (who == STALE)
+        assert mine.fallback is (who == VANISHED)
+        assert {k: _metric(v) for k, v in mine.failed_tg_allocs.items()} \
+            == {k: _metric(v) for k, v in twin.failed_tg_allocs.items()}
+        assert bool(mine.failed_tg_allocs) is (who == SHORT)
+        assert _metric(mine.ctx.metrics) == _metric(twin.ctx.metrics)
+        a, b = (getattr(r.plan, "_sweep", None) for r in (mine, twin))
+        if who in (STALE, SHORT, VANISHED):
+            assert a is None and b is None
+            if who == SHORT:
+                # The exact loop's plan: objects, their metrics filled from
+                # the accumulator the three evals before it queued on.
+                mine_l, twin_l = ([x for v in r.plan.NodeAllocation.values()
+                                   for x in v] for r in (mine, twin))
+                assert 0 < len(mine_l) == len(twin_l) < 50
+                for x, y in zip(mine_l, twin_l):
+                    dx, dy = to_dict(x), to_dict(y)
+                    minted.append(dx.pop("ID"))
+                    del dy["ID"]
+                    assert _without_clock(dx) == _without_clock(dy)
+                [m] = mine.failed_tg_allocs.values()
+                assert set(m.DimensionExhausted) == {"cpu"}
+                assert m.CoalescedFailures == 50 - len(mine_l) - 1
+                # Every failure read the chain's usage: the placements
+                # queued before this eval, and its own.
+                queued = sum(float(r.prep.demands[:50, 0].sum())
+                             for r in window.recs[:i])
+                assert queued >= 6800.0  # 90 at 20 MHz and 10 at 500
+                assert window.accumulator_cpu == single.accumulator_cpu \
+                    == [queued + 3000.0 * len(mine_l)] * (50 - len(mine_l))
+            continue
+        for name in ("rows", "delta", "counts", "starts"):
+            x, y = getattr(a, name), getattr(b, name)
+            assert x.dtype == y.dtype, (i, name)
+            np.testing.assert_array_equal(x, y, err_msg=f"{i} {name}")
+        assert a.rows.dtype == a.counts.dtype == a.starts.dtype == np.int64
+        assert a.delta.dtype == np.float32
+        assert np.all(np.diff(a.rows) > 0)  # unique, ascending
+        for name in ("node_ids", "alloc_names", "alloc_tg", "epoch",
+                     "n_rows", "kind"):
+            assert getattr(a, name) == getattr(b, name), (i, name)
+        assert (a.n_rows, a.kind) == (window.n_rows, "service")
+        assert all(type(getattr(a, name)) is list
+                   for name in ("node_ids", "alloc_ids", "alloc_names",
+                                "alloc_tg"))
+        assert all(type(t) is int for t in a.alloc_tg)
+        assert len(a.alloc_ids) == len(b.alloc_ids) == 50 == a.starts[-1]
+        minted.extend(a.alloc_ids)
+        # Placement order within a row: the names of one node's
+        # placements rise as the job's instance indexes do.
+        index = [int(n[n.rindex("[") + 1:-1]) for n in a.alloc_names]
+        for lo, hi in zip(a.starts[:-1], a.starts[1:]):
+            groups = {}
+            for p in range(lo, hi):
+                groups.setdefault(a.alloc_tg[p], []).append(index[p])
+            assert all(v == sorted(v) for v in groups.values())
+        assert len(a.templates) == len(b.templates) \
+            == (2 if who.startswith("global-2tg") else 1)
+        for t, u in zip(a.templates, b.templates):
+            assert _without_clock(to_dict(t)) == _without_clock(to_dict(u))
+            assert _metric(t.Metrics) == _metric(u.Metrics)
+            assert t.Metrics.Scores and t.Metrics is a.templates[0].Metrics
+            np.testing.assert_array_equal(t._resvec_cache, u._resvec_cache)
+        np.testing.assert_array_equal(
+            a.delta.sum(axis=0),
+            sum(a.templates[t]._resvec_cache for t in a.alloc_tg))
+        assert isinstance(mine.plan.NodeAllocation, ColumnarPlacements)
+        assert list(mine.plan.NodeAllocation) == a.node_ids
+    # An eval with repeated rows, and one that took its prep from another.
+    by_name = dict(zip(window.chain, window.recs))
+    assert len(by_name["local-dc2-6"].plan._sweep.rows) < 50
+    assert by_name["local-dc1-0"].prep is by_name["local-dc1-4"].prep
+    assert by_name["local-dc1-0"].prep is not by_name["local-dc2-6"].prep
+    # The ids: each of generate_uuid's shape (version 4, variant 8-b), and
+    # no two of the window's alike.
+    assert len(minted) > 200 and all(UUID4.match(x) for x in minted)
+    assert len(set(minted)) == len(minted)
+
+
+@pytest.mark.parametrize("host", [True, False],
+                         ids=["host-placement", "device"])
+def test_a_vanished_node_refuses_its_eval_alone(mixed, host):
+    """The eval whose node vanished leaves the pass and re-runs per eval;
+    the window's other plans are the ones made without it."""
+    window = mixed(host)
+    gone = window.recs[window.chain.index(VANISHED)]
+    assert gone.fallback and gone.pending is None
+    assert not gone.plan.NodeAllocation
+    assert getattr(gone.plan, "_sweep", None) is None
+    assert window.stats["fallback"] == 1
+    assert window.stats["fast"] == len(MIXED) - 2  # the stale one, and it
+    assert window.stats["plans_columnar"] == 4
+
+
+def test_a_plan_that_already_holds_placements_gets_objects_beside_the_columns(
+        monkeypatch):
+    """What decides between columns only and stamped objects is what the
+    plan holds when its eval is collected: the descriptor is the same."""
+    held = mock.alloc()
+    collect = GenericStack.collect_build
+
+    def holding_one(self, prep, cr, eval_id, job, place, plan, failed, acc):
+        plan.append_alloc(held)
+        return collect(self, prep, cr, eval_id, job, place, plan, failed,
+                       acc)
+
+    monkeypatch.setattr(GenericStack, "collect_build", holding_one)
+    ns = service_window(svc_job(count=7), n_nodes=3)
+    assert ns.ok and type(ns.plan.NodeAllocation) is dict
+    assert ns.plan.NodeAllocation[held.NodeID] == [held]
+    sweep = ns.plan._sweep
+    stamped = {a.ID: a for v in ns.plan.NodeAllocation.values() for a in v
+               if a is not held}
+    assert sorted(stamped) == sorted(sweep.alloc_ids) and len(stamped) == 7
+    assert all(UUID4.match(i) for i in stamped)
+    # In placement order within a node, as the exact loop appends them.
+    for nid, lo, hi in zip(sweep.node_ids, sweep.starts, sweep.starts[1:]):
+        assert [a.ID for a in ns.plan.NodeAllocation[nid]] \
+            == sweep.alloc_ids[lo:hi]
+        for p, a in zip(range(lo, hi), ns.plan.NodeAllocation[nid]):
+            template = sweep.templates[sweep.alloc_tg[p]]
+            assert (a.Name, a.NodeID) == (sweep.alloc_names[p], nid)
+            assert a.Metrics is template.Metrics
+            assert a.TaskResources is template.TaskResources
+            assert a.EvalID == ns.plan.EvalID and a.JobID == ns.job.ID
 
 
 # ------------------------------------------- reads that build no object
@@ -839,3 +1080,33 @@ def test_a_batched_draw_has_the_shape_and_the_entropy_of_single_ids(
         urandom=lambda n: (asked.append(n), os.urandom(n))[1]))
     generate_uuids(50)
     assert asked == [800]
+
+
+def test_a_windows_draw_is_a_hundred_thousand_distinct_ids_of_the_one_shape():
+    """The window's id column (ISSUE 31): one draw, a byte matrix that is
+    permuted and sliced before any string exists."""
+    one = structs_mod.generate_uuid()
+    assert UUID4.match(one) and one[14] == "4" and one[19] in "89ab"
+    ids = generate_uuids(100_000)
+    assert len(ids) == 100_000 == len(set(ids))
+    assert all(type(i) is str and UUID4.match(i) for i in ids)
+    assert generate_uuids(0) == []
+    [lone] = generate_uuids(1)
+    assert UUID4.match(lone)
+    rows = structs_mod.uuid_rows(7)
+    assert rows.shape == (7, 37) and rows.dtype == np.uint8
+    whole = structs_mod.uuid_strings(rows)
+    order = np.array([6, 0, 3, 3])
+    assert structs_mod.uuid_strings(rows[order]) == [whole[i] for i in order]
+    assert structs_mod.uuid_strings(rows[2:5]) == whole[2:5]
+    assert structs_mod.uuid_strings(rows[:0]) == []
+
+
+def test_the_seeded_stream_is_spent_alike_by_one_draw_or_many(monkeypatch):
+    """What lets the gate above compare ids at all: a window's one draw
+    reads the bytes that its evals' draws would have read, in order."""
+    _seed_ids(monkeypatch, 5)
+    together = generate_uuids(120)
+    _seed_ids(monkeypatch, 5)
+    apart = generate_uuids(50) + generate_uuids(20) + generate_uuids(50)
+    assert together == apart

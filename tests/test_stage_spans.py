@@ -159,8 +159,7 @@ with open(pipelined_worker.__file__) as _f:
 STAGES = sorted(set(re.findall(r'_stage\(\s*"([a-z_]+)"', WORKER_SOURCE)))
 
 
-@pytest.fixture(scope="module")
-def served():
+def _served():
     srv = Server(ServerConfig(num_schedulers=0, pipelined_scheduling=True,
                               scheduler_window=8))
     srv.establish_leadership()
@@ -172,6 +171,12 @@ def served():
     worker.name = "w-test"
     yield srv, worker
     srv.shutdown()
+
+
+served = pytest.fixture(scope="module")(_served)
+# A server of the test's own: a stale or fallback record taints the chain,
+# and the next lease would wait for whatever an earlier test left in flight.
+served_alone = pytest.fixture()(_served)
 
 
 def test_the_worker_times_every_stage_the_issue_lists():
@@ -275,21 +280,29 @@ def _plain_job():
     return job
 
 
-def test_a_columns_only_window_still_opens_one_collect_span(served, spans):
-    srv, worker = served
+def _window_of(srv, worker, jobs):
     ready0 = srv.eval_broker.stats.TotalReady
-    for _ in range(2):
-        srv.job_register(_plain_job())
-    assert wait_for(lambda: srv.eval_broker.stats.TotalReady - ready0 >= 2,
-                    interval=0.002)
-    before = dict(worker.stats)
-    batch = worker._dequeue_window()
-    work = worker._dispatch_window(batch)
-    assert len(work.fast) == 2
+    for job in jobs:
+        srv.job_register(job)
+    assert wait_for(lambda: srv.eval_broker.stats.TotalReady - ready0
+                    >= len(jobs), interval=0.002)
+    work = worker._dispatch_window(worker._dequeue_window())
+    assert len(work.fast) == len(jobs)
     work.packed = worker._drain_window(work)
+    return work
+
+
+def _settle(worker, work):
     worker._finish_fast(work)
     worker._arbiter.mark_settled(work.chain_seq)
     worker._arbiter.finish_window()
+
+
+def test_a_columns_only_window_still_opens_one_collect_span(served, spans):
+    srv, worker = served
+    before = dict(worker.stats)
+    work = _window_of(srv, worker, [_plain_job(), _plain_job()])
+    _settle(worker, work)
     assert worker.stats["plans_columnar"] - before["plans_columnar"] == 2
     assert worker.stats["plans_objects"] == before["plans_objects"]
     assert worker.stats["t_collect_ms"] > before["t_collect_ms"]
@@ -297,6 +310,64 @@ def test_a_columns_only_window_still_opens_one_collect_span(served, spans):
     assert [a for n, a in mine if n == "nomad.worker.collect"] \
         == [{"worker": "w-test", "window": work.number}]
     assert [n for n, _ in mine].count("nomad.worker.build") == 1
+
+
+@pytest.mark.parametrize("case", ["all-placed", "a-port-asked",
+                                  "one-of-each", "a-stale-record"])
+def test_a_record_is_counted_once_by_the_build_that_made_its_plan(
+        served_alone, case):
+    """`collect_windowed` / `collect_exact` (ISSUE 31): a storm's evals,
+    which place everything and ask for no network, are all built by the
+    window's one pass; mock.job's port ask takes the exact loop; a stale
+    record is counted by neither."""
+    srv, worker = served_alone
+    jobs = {"all-placed": [_plain_job(), _plain_job(), _plain_job()],
+            "a-port-asked": [mock.job()],
+            "one-of-each": [_plain_job(), mock.job(), _plain_job()],
+            "a-stale-record": [_plain_job(), _plain_job()]}[case]
+    work = _window_of(srv, worker, jobs)
+    before = dict(worker.stats)
+    if case == "a-stale-record":
+        work.fast[0].stale = True
+    _settle(worker, work)
+    moved = {k: worker.stats[k] - before[k]
+             for k in ("collect_windowed", "collect_exact", "stale",
+                       "fallback")}
+    plain = sum(1 for j in jobs
+                if not j.TaskGroups[0].Tasks[0].Resources.Networks)
+    if case == "a-stale-record":
+        assert moved == {"collect_windowed": 1, "collect_exact": 0,
+                         "stale": 1, "fallback": 0}
+    else:
+        assert moved["collect_windowed"] == plain
+        assert moved["collect_exact"] == len(jobs) - plain
+        assert moved["stale"] == moved["fallback"] == 0
+
+
+def test_a_pass_that_raises_sends_its_evals_to_the_exact_path(served_alone,
+                                                              monkeypatch):
+    """The window's pass is one call for many evals: if it raises, each of
+    them re-runs per eval (counted exact, then fallback), none is lost."""
+    from nomad_tpu.scheduler.stack import WindowCollect
+
+    srv, worker = served_alone
+    jobs = [_plain_job(), _plain_job()]
+    work = _window_of(srv, worker, jobs)
+    before = dict(worker.stats)
+
+    def boom(self, queued):
+        raise RuntimeError("injected")
+
+    with monkeypatch.context() as patch:
+        patch.setattr(WindowCollect, "_build", boom)
+        _settle(worker, work)
+    assert worker.stats["collect_windowed"] == before["collect_windowed"]
+    assert worker.stats["collect_exact"] - before["collect_exact"] == 2
+    assert worker.stats["fallback"] - before["fallback"] == 2
+    for job in jobs:
+        placed = [a for a in srv.state.allocs_by_job(job.ID)
+                  if not a.terminal_status()]
+        assert len(placed) == job.TaskGroups[0].Count
 
 
 with open(os.path.join(ROOT, "README.md")) as _f:
@@ -318,7 +389,11 @@ def test_a_stats_key_is_seeded_and_the_readme_says_what_it_counts(key):
 
 
 def test_the_schema_counts_how_a_fast_plan_carried_its_placements():
-    assert {"plans_columnar", "plans_objects"} <= set(STATS_COUNTERS)
+    assert {"plans_columnar", "plans_objects", "collect_windowed",
+            "collect_exact"} <= set(STATS_COUNTERS)
+    [row] = [ln for ln in README_STATS
+             if "`collect_windowed`" in ln.split("|")[1]]
+    assert "`collect_exact`" in row.split("|")[1]
     assert len(set(STATS_COUNTERS + STATS_TIMERS_MS)) \
         == len(STATS_COUNTERS) + len(STATS_TIMERS_MS)
     [row] = [ln for ln in README_STATS if "`plans_columnar`" in ln]

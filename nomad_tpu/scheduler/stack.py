@@ -416,6 +416,14 @@ def _pad_pow2(n: int, floor: int = 8) -> int:
     return p
 
 
+def eval_pad(n_evals: int) -> int:
+    """Evals a window's run of n_evals same-shaped evals is launched as: a
+    run of one as it is (dispatch), two or more (dispatch_multi) padded to
+    a power of two, at least 4, so that jit compiles one program per
+    bucket and not per window fill."""
+    return _pad_pow2(n_evals, floor=4) if n_evals >= 2 else 1
+
+
 def score_fit_rows(usage2: np.ndarray, score_cap: np.ndarray) -> np.ndarray:
     """BestFit-v3 host-side, in float64 like the Go reference
     (funcs.go:102-137): 20 - 10^freeCpuPct - 10^freeMemPct, clamped [0,18],
@@ -828,7 +836,7 @@ class GenericStack:
         usage = usage_override if usage_override is not None else d["usage"]
         usage = _chain_to_device(usage, node_sh)
 
-        e_pad = _pad_pow2(n_evals, floor=4)
+        e_pad = eval_pad(n_evals)
         p = prep.p_pad
         # Tiled per-placement inputs: byte-identical across a storm's
         # windows, so the content-addressed cache uploads them once.

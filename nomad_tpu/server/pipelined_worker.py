@@ -76,6 +76,7 @@ from nomad_tpu.scheduler.stack import (
     PreparedBatch,
     WindowCollect,
     device_input,
+    eval_pad,
 )
 from nomad_tpu.scheduler.util import (
     BLOCKED_EVAL_FAILED_PLACEMENTS,
@@ -83,7 +84,8 @@ from nomad_tpu.scheduler.util import (
     materialize_task_groups,
     tainted_nodes,
 )
-from nomad_tpu.structs import AllocMetric, Evaluation, Plan, columns_only
+from nomad_tpu.structs import (AllocMetric, Evaluation, Plan, columns_only,
+                               placed_count)
 from nomad_tpu.telemetry import metrics, trace
 from nomad_tpu.tensor.node_table import ChainArbiter
 from nomad_tpu.structs.structs import (
@@ -132,11 +134,16 @@ STATS_COUNTERS = (
     "launches",        # device placement dispatches (fused or single)
     "launch_keys",     # unique task groups (keys) summed over them
     "launch_evals",    # evals placed by them
+    "launch_steps",    # serial replay steps they dispatched: e_pad x p_pad
+    #                    a fused launch, p_pad a single one
+    "launch_placements",  # real placements in those steps (n_valid)
     "plans_columnar",  # submitted fast plans whose placements stayed columns
     #                    until their window settled: no object was built
     "plans_objects",   # every other one: objects built at collect, or
     #                    asked for later by any reader (partial verdict,
     #                    refused descriptor, exact verify, serialisation)
+    "plan_rows",       # placements in those plans (both kinds), counted
+    #                    where the window settles
     "collect_windowed",  # non-stale fast evals whose plan the window's one
     #                      columnar collect pass built (stack.WindowCollect)
     "collect_exact",     # every other one: the exact per-placement loop
@@ -736,9 +743,16 @@ class PipelinedWorker(Worker):
                                    else id(rec), []).append(rec)
         runs = list(by_prep.values())
         pend = [r for run in runs for r in run]
+        # What each run costs the device: its serial replay steps (a fused
+        # run pads its evals as dispatch_multi does) and the real
+        # placements among them.
+        sizes = [(eval_pad(len(run)) * run[0].prep.p_pad,
+                  len(run) * run[0].prep.n_valid) for run in runs]
         with self._stage("launch", number, runs=len(runs),
-                         dc_sets=len(node_cache)):
-            for run in runs:
+                         dc_sets=len(node_cache),
+                         steps=sum(s for s, _ in sizes),
+                         placements=sum(p for _, p in sizes)):
+            for run, (steps, placements) in zip(runs, sizes):
                 rec = run[0]
                 try:
                     if len(run) >= 2:
@@ -760,6 +774,8 @@ class PipelinedWorker(Worker):
                     self.stats["launches"] += 1
                     self.stats["launch_keys"] += rec.prep.tg_masks.shape[0]
                     self.stats["launch_evals"] += len(run)
+                    self.stats["launch_steps"] += steps
+                    self.stats["launch_placements"] += placements
                     fl = getattr(usage_chain, "flag", None)
                     if fl is not None:
                         mesh_flags.append(fl)
@@ -1163,6 +1179,8 @@ class PipelinedWorker(Worker):
                 self.stats["plans_columnar"
                            if columns_only(rec.plan.NodeAllocation)
                            else "plans_objects"] += 1
+                self.stats["plan_rows"] += placed_count(
+                    rec.plan.NodeAllocation)
             if rec.fallback or rec.stale:
                 continue
             eval_updates.extend(self._status_evals(rec))

@@ -208,8 +208,9 @@ def test_a_stage_is_a_stats_key_a_sample_and_a_span(served, spans, samples,
 def test_the_spans_of_one_window_share_its_number(served, spans, samples):
     srv, worker = served
     ready0 = srv.eval_broker.stats.TotalReady
-    for _ in range(3):
-        srv.job_register(mock.job())
+    jobs = [mock.job() for _ in range(3)]
+    for job in jobs:
+        srv.job_register(job)
     # The fill below lingers FILL_TIMEOUT (2 ms) for stragglers: under a
     # loaded machine that is no time at all, so the three evals have to
     # stand in the ready queue before the first is taken.
@@ -217,6 +218,7 @@ def test_the_spans_of_one_window_share_its_number(served, spans, samples):
                     interval=0.002)
     fill0, wait0 = worker.stats["t_fill_ms"], worker.stats["t_stagewait_ms"]
     objects0 = worker.stats["plans_objects"]
+    rows0 = worker.stats["plan_rows"]
     first = worker._dequeue_first()
     batch = [first]
     work = worker._dispatch_window(batch, fill=True)
@@ -233,6 +235,12 @@ def test_the_spans_of_one_window_share_its_number(served, spans, samples):
     # so their placements were objects from collect on.
     assert worker.stats["plans_objects"] - objects0 == 3
     assert worker.stats["plans_columnar"] == 0
+    # ... and the rows they carried to the applier: what was placed.
+    assert worker.stats["plan_rows"] - rows0 == sum(
+        len(srv.state.allocs_by_job(job.ID)) for job in jobs) > 0
+    # Placed on the host: no serial replay step was launched.
+    assert worker.stats["launch_steps"] == 0
+    assert worker.stats["launch_placements"] == 0
     assert worker.stats["t_stagewait_ms"] - wait0 >= 2.0
     assert worker.stats["t_fill_ms"] > fill0
     by_name = {}
@@ -246,8 +254,10 @@ def test_the_spans_of_one_window_share_its_number(served, spans, samples):
                   "evalupd"):
         # The launch span also says what the window is about to cost: the
         # device launches it makes (none here: three small evals place on
-        # the host) and the node contexts it looked up.
-        extra = {"runs": 0, "dc_sets": 1} if stage == "launch" else {}
+        # the host, so no serial step and no placement is launched) and the
+        # node contexts it looked up.
+        extra = {"runs": 0, "dc_sets": 1, "steps": 0, "placements": 0} \
+            if stage == "launch" else {}
         assert by_name[stage] == [{"worker": "w-test",
                                    "window": work.number, **extra}], stage
     # Nesting as the timeline shows it: refresh, nodectx, launch and
@@ -305,6 +315,7 @@ def test_a_columns_only_window_still_opens_one_collect_span(served, spans):
     _settle(worker, work)
     assert worker.stats["plans_columnar"] - before["plans_columnar"] == 2
     assert worker.stats["plans_objects"] == before["plans_objects"]
+    assert worker.stats["plan_rows"] - before["plan_rows"] == 2 * 3
     assert worker.stats["t_collect_ms"] > before["t_collect_ms"]
     mine = [(n, a) for n, a in spans.opened if a.get("worker") == "w-test"]
     assert [a for n, a in mine if n == "nomad.worker.collect"] \
@@ -389,8 +400,9 @@ def test_a_stats_key_is_seeded_and_the_readme_says_what_it_counts(key):
 
 
 def test_the_schema_counts_how_a_fast_plan_carried_its_placements():
-    assert {"plans_columnar", "plans_objects", "collect_windowed",
-            "collect_exact"} <= set(STATS_COUNTERS)
+    assert {"plans_columnar", "plans_objects", "plan_rows",
+            "collect_windowed", "collect_exact", "launch_steps",
+            "launch_placements"} <= set(STATS_COUNTERS)
     [row] = [ln for ln in README_STATS
              if "`collect_windowed`" in ln.split("|")[1]]
     assert "`collect_exact`" in row.split("|")[1]

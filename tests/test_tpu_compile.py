@@ -25,6 +25,7 @@ from nomad_tpu.tensor import node_table
 
 ROWS = 16_384            # 10,000 nodes padded to a power of two
 DC_ROWS = 65_536         # dc-50k: 50,000 nodes in four datacenters
+C1M_ROWS = 8_192         # c1m-5k: 5,000 nodes, jobs of 1,000 padded to 1,024
 WINDOW_P = 32 * 64       # 32 evals x 50 placements, each padded to 64
 MESH_ROWS = 1 << 20
 
@@ -115,9 +116,22 @@ def test_keyed_program_compiles_at_dc50k_widths(one_chip, keys, evals):
               *_tail_inputs(DC_ROWS, evals * 64, one_chip, reset=True))
 
 
-def test_compact_window_compiles(one_chip):
-    _compiles(kernels.compact_window, _shape((32, 64, 3), F32, one_chip),
-              _shape((32, 64), BOOL, one_chip), _shape((32,), I32, one_chip))
+def test_keyed_program_compiles_at_c1m_widths(one_chip):
+    """benchmark/configs/c1m-5k.json: a full window of 32 jobs of 1,000 is
+    one scan of 32,768 steps whose candidate count (32,768) is clipped to
+    the 8,192-row table: lax.top_k over the whole node axis, no trim."""
+    k = kernels.keyed_cand_count(32 * 1000)
+    assert k == 32768 > C1M_ROWS and k <= 1 << 17
+    _compiles(kernels._keyed_program(None, k),
+              *_node_inputs(C1M_ROWS, one_chip), _shape((1, 5), F32, one_chip),
+              *_tail_inputs(C1M_ROWS, 32 * 1024, one_chip, reset=True))
+
+
+@pytest.mark.parametrize("p_pad", [64, 1024])
+def test_compact_window_compiles(one_chip, p_pad):
+    _compiles(kernels.compact_window, _shape((32, p_pad, 3), F32, one_chip),
+              _shape((32, p_pad), BOOL, one_chip),
+              _shape((32,), I32, one_chip))
 
 
 def test_refresh_scatter_compiles(one_chip):

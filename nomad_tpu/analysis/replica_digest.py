@@ -17,8 +17,10 @@ The "effect" is a cheap canonical READBACK of what the entry changed
 (node/eval/alloc ids + statuses re-read from the store after the
 handler ran) — readback is what makes real store corruption visible,
 not just payload divergence. Columnar ApplySweepBatch entries digest
-their column arrays directly (ids, rows, delta — dtype/shape/tobytes),
-never materializing a row.
+their columns directly, never materializing a row: rows, counts and
+delta as dtype/shape/tobytes, the two id columns each as ONE byte
+stream (:class:`StrColumn`: the bytes a list of strings folds to, made
+by one join and one encode and handed to the hasher in one update).
 
 Every `interval` folds the chain value is recorded as a checkpoint.
 The leader piggybacks its latest checkpoint on AppendEntries; a
@@ -30,7 +32,8 @@ chain) but never exchanges — concurrent
 dev applies can fold out of index order, which is harmless because
 nothing compares the value.
 
-Stats keys: ``nomad.fsm.digest.{folds,exchanged,diverged,verify_ms}``.
+Stats keys: ``nomad.fsm.digest.{folds,exchanged,diverged,verify_ms,
+column_folds,row_folds}``.
 """
 
 from __future__ import annotations
@@ -74,10 +77,51 @@ class ReplicaDivergenceError(Exception):
 
 
 # ------------------------------------------------------ canonical encoding
-def _fold_obj(h, obj: Any) -> None:
+class StrColumn:
+    """One string column of a columnar group (alloc ids, node ids),
+    folded as the list of its values: the same bytes, no visit per item.
+
+    ``stream`` is what ``_fold_obj`` feeds the hasher for ``list(values)``
+    (``L<n>:`` then ``S<len>:<utf-8>`` an item) when every value is an
+    ASCII string of one length, so that one join on the constant
+    ``S<len>:`` prefix and one encode give it; else None, and the column
+    folds item by item as any list does. Lists (the msgpack round trip)
+    and object arrays give the same stream."""
+
+    __slots__ = ("values", "stream")
+
+    def __init__(self, values):
+        if isinstance(values, np.ndarray):
+            values = values.tolist()
+        elif not isinstance(values, (list, tuple)):
+            values = list(values)
+        self.values = values
+        self.stream = self._encode(values)
+
+    @staticmethod
+    def _encode(values) -> Optional[bytes]:
+        n = len(values)
+        if n == 0:
+            return b"L0:"
+        try:
+            width = len(values[0])
+            if set(map(len, values)) != {width}:
+                return None
+            prefix = "S%d:" % width
+            body = prefix + prefix.join(values)
+        except TypeError:  # a value without a length, or not a str
+            return None
+        if not body.isascii():
+            return None
+        return b"L%d:" % n + body.encode("ascii")
+
+
+def _fold_obj(h, obj: Any, tally: Optional[list] = None) -> None:
     """Fold one value with unambiguous type tags. Dicts fold in sorted
     key order; ndarrays fold dtype/shape/raw bytes (no materialization,
-    no Python-object hashing — nothing process-local)."""
+    no Python-object hashing — nothing process-local). `tally`, where
+    given, counts [string columns folded whole, values of string columns
+    folded one by one]."""
     if obj is None:
         h.update(b"N")
     elif obj is True:
@@ -99,15 +143,24 @@ def _fold_obj(h, obj: Any) -> None:
         h.update(b"A" + str(obj.dtype).encode() + b"|"
                  + str(obj.shape).encode() + b"|")
         h.update(np.ascontiguousarray(obj).tobytes())
+    elif isinstance(obj, StrColumn):
+        if obj.stream is not None:
+            h.update(obj.stream)
+            if tally is not None:
+                tally[0] += 1
+        else:
+            _fold_obj(h, obj.values, tally)
+            if tally is not None:
+                tally[1] += len(obj.values)
     elif isinstance(obj, (list, tuple)):
         h.update(b"L" + str(len(obj)).encode() + b":")
         for item in obj:
-            _fold_obj(h, item)
+            _fold_obj(h, item, tally)
     elif isinstance(obj, dict):
         h.update(b"M" + str(len(obj)).encode() + b":")
         for key in sorted(obj):
-            _fold_obj(h, key)
-            _fold_obj(h, obj[key])
+            _fold_obj(h, key, tally)
+            _fold_obj(h, obj[key], tally)
     else:
         # Unknown leaf (an already-constructed struct riding a dev-mode
         # payload): fold its type name only — replicated entries are
@@ -129,6 +182,8 @@ class ReplicaDigest:
         self._synced = True         # False: fold but never verify
         self._unsynced_reason = ""
         self._folds = 0
+        self._column_folds = 0      # string columns folded whole
+        self._row_folds = 0         # their values folded one by one
         self._exchanged = 0
         self._diverged = 0
 
@@ -138,14 +193,17 @@ class ReplicaDigest:
         the apply path serialized (raft's FSM lock / DevRaft callers);
         the internal lock only protects readers on other threads."""
         h = hashlib.blake2b(digest_size=_DIGEST_SIZE)
+        tally = [0, 0]
         with self._lock:
             h.update(self._chain)
             _fold_obj(h, index)
             _fold_obj(h, msg_type)
-            _fold_obj(h, effect)
+            _fold_obj(h, effect, tally)
             self._chain = h.digest()
             self._last_index = index
             self._folds += 1
+            self._column_folds += tally[0]
+            self._row_folds += tally[1]
             bucket = index // self.interval
             if bucket > self._bucket:
                 self._bucket = bucket
@@ -153,6 +211,12 @@ class ReplicaDigest:
                 while len(self._checkpoints) > _CHECKPOINT_KEEP:
                     self._checkpoints.popitem(last=False)
         metrics.incr_counter(("nomad", "fsm", "digest", "folds"))
+        if tally[0]:
+            metrics.incr_counter(
+                ("nomad", "fsm", "digest", "column_folds"), tally[0])
+        if tally[1]:
+            metrics.incr_counter(
+                ("nomad", "fsm", "digest", "row_folds"), tally[1])
 
     # ------------------------------------------------------------ exchange
     def checkpoint(self) -> Optional[Tuple[int, str]]:
@@ -243,6 +307,8 @@ class ReplicaDigest:
                 "Synced": self._synced,
                 "UnsyncedReason": self._unsynced_reason,
                 "Folds": self._folds,
+                "ColumnFolds": self._column_folds,
+                "RowFolds": self._row_folds,
                 "Exchanged": self._exchanged,
                 "Diverged": self._diverged,
             }
@@ -327,9 +393,10 @@ def _alloc_effects(state, payload: Dict[str, Any]) -> list:
 
 
 def _sweep_effects(state, payload: Dict[str, Any]) -> list:
-    """Columnar groups digest their column arrays directly — ids, rows,
+    """Columnar groups digest their columns directly — ids, rows,
     counts, usage delta — plus readbacks for any object co-groups. No
-    row is ever materialized for the digest."""
+    row is ever materialized for the digest, and no id is visited alone
+    (StrColumn). Instance names are not folded."""
     groups = payload.get("Batch")
     if groups is None:
         groups = [payload]
@@ -344,8 +411,8 @@ def _sweep_effects(state, payload: Dict[str, Any]) -> list:
                             else alloc.DesiredStatus))
             continue
         out.append((
-            list(sweep["AllocIDs"]),
-            list(sweep["RowNodeIDs"]),
+            StrColumn(sweep["AllocIDs"]),
+            StrColumn(sweep["RowNodeIDs"]),
             np.asarray(sweep["Counts"], dtype=np.int64),
             np.asarray(sweep["Rows"], dtype=np.int64),
             np.asarray(sweep["Delta"], dtype=np.float32),

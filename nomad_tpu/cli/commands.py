@@ -882,6 +882,8 @@ def cmd_sched_stats(args) -> int:
               f" (verified @{digest.get('VerifiedIndex', 0)}, "
               f"interval {digest.get('Interval')})")
         print(f"  folds={digest.get('Folds', 0)}  "
+              f"column_folds={digest.get('ColumnFolds', 0)}  "
+              f"row_folds={digest.get('RowFolds', 0)}  "
               f"exchanged={digest.get('Exchanged', 0)}  "
               f"diverged={digest.get('Diverged', 0)}")
     workers = out.get("Workers") or []

@@ -22,6 +22,8 @@ from __future__ import annotations
 
 from typing import Any, Callable, Dict, List
 
+from nomad_tpu.structs import column_list
+
 from .schema import new_event
 
 __all__ = ["build_events"]
@@ -33,12 +35,6 @@ def _f(obj: Any, name: str, default: Any = "") -> Any:
     if isinstance(obj, dict):
         return obj.get(name, default)
     return getattr(obj, name, default)
-
-
-def _aslist(value: Any) -> List[Any]:
-    if isinstance(value, list):
-        return value
-    return list(value)
 
 
 def _alloc_event(etype: str, alloc: Any, job: Any = None) -> Dict[str, Any]:
@@ -162,7 +158,7 @@ def _sweep_batch(fsm, req):
         events.extend(_alloc_event("AllocUpdated", a, job)
                       for a in group.get("Updates", ()))
         templates = sweep["Templates"]
-        alloc_ids = _aslist(sweep["AllocIDs"])
+        alloc_ids = column_list(sweep["AllocIDs"])
         events.append(new_event(
             "AllocationBatch", "AllocationBatchCommitted",
             _f(templates[0], "JobID"), {
@@ -171,9 +167,9 @@ def _sweep_batch(fsm, req):
                 "Kind": sweep.get("Kind", "system"),
                 "Count": len(alloc_ids),
                 "AllocIDs": alloc_ids,
-                "Names": _aslist(sweep["Names"]),
-                "RowNodeIDs": _aslist(sweep["RowNodeIDs"]),
-                "Counts": [int(c) for c in sweep["Counts"]],
+                "Names": column_list(sweep["Names"]),
+                "RowNodeIDs": column_list(sweep["RowNodeIDs"]),
+                "Counts": column_list(sweep["Counts"]),
             }))
     return events
 

@@ -18,6 +18,21 @@ from .log import EntryType, InMemLogStore
 from .node import NotLeaderError, RaftConfig, RaftNode
 
 
+def _pack_default(obj: Any) -> Any:
+    tolist = getattr(obj, "tolist", None)  # ndarray, numpy scalar
+    if tolist is None:
+        raise TypeError(f"cannot serialize {type(obj).__name__}")
+    return tolist()
+
+
+def encode_command(msg_type, payload: Dict[str, Any]) -> bytes:
+    """One typed message as the log entry's bytes. This is the boundary at
+    which a columnar entry's ndarrays become plain lists (the form every
+    follower decodes) and its structs plain dicts, and nowhere earlier."""
+    return msgpack.packb((int(msg_type), to_dict(payload)),
+                         use_bin_type=True, default=_pack_default)
+
+
 class RaftBackend:
     """Owns a RaftNode wired to an FSM. The Server calls apply(); followers
     receive the same entries through replication and apply them to their own
@@ -113,9 +128,8 @@ class RaftBackend:
         """Replicate + apply one mutation; returns its raft index. Raises
         NotLeaderError on non-leaders so RPC endpoints can forward
         (reference: rpc.go:177-242 forward + structs.ErrNoLeader)."""
-        data = msgpack.packb((int(msg_type), to_dict(payload)),
-                             use_bin_type=True)
-        index, result = self.node.apply_command(data)
+        index, result = self.node.apply_command(
+            encode_command(msg_type, payload))
         if isinstance(result, Exception):
             raise result
         return index

@@ -131,20 +131,24 @@ class SweepBatch:
                           templates=self.templates, kind=self.kind)
 
     def wire(self) -> dict:
-        """msgpack-safe encoding for the ApplySweepBatch raft entry (numpy
-        arrays become lists; templates stay Allocation objects — to_dict
-        flattens them at the consensus boundary). Per-alloc node ids are
-        NOT shipped: they re-expand from (node_ids, counts) at apply."""
+        """The ApplySweepBatch raft entry's `Sweep`: the columns as they
+        stand, none copied or converted. Numeric columns stay ndarrays
+        and string columns the lists the emit made; every consumer of
+        the entry (FSM, digest, event builder) takes either form. An
+        array becomes a list where a transport needs one and nowhere
+        earlier: `RaftBackend.apply`'s msgpack encoding (templates are
+        flattened there too, by to_dict). Per-alloc node ids are NOT
+        shipped: they re-expand from (node_ids, counts) on a read."""
         return {
             "Kind": self.kind,
             "Templates": self.templates,
-            "TGIdx": list(self.alloc_tg),
-            "AllocIDs": list(self.alloc_ids),
-            "Names": list(self.alloc_names),
-            "RowNodeIDs": list(self.node_ids),
-            "Counts": [int(c) for c in self.counts],
-            "Rows": [int(r) for r in self.rows],
-            "Delta": self.delta.tolist(),
+            "TGIdx": self.alloc_tg,
+            "AllocIDs": self.alloc_ids,
+            "Names": self.alloc_names,
+            "RowNodeIDs": self.node_ids,
+            "Counts": self.counts,
+            "Rows": self.rows,
+            "Delta": self.delta,
             "Epoch": self.epoch,
             "NRows": self.n_rows,
         }

@@ -31,6 +31,7 @@ from nomad_tpu.structs import (
     Node,
     PeriodicLaunch,
     ServiceRegistration,
+    column_list,
     from_dict,
     to_dict,
 )
@@ -347,20 +348,21 @@ class FSM:
                 for t in templates:
                     if t.Job is None and job is not None:
                         t.Job = job
-                row_node_ids = list(sweep["RowNodeIDs"])
-                counts = np.asarray(sweep["Counts"], dtype=np.int64)
-                node_per_alloc = np.repeat(
-                    np.asarray(row_node_ids, dtype=object),
-                    counts).tolist()
+                # Columns as the entry carries them: arrays and lists
+                # from DevRaft, lists from a decoded log entry. Nothing
+                # is copied, and the per-allocation node column is left
+                # to the segment (it expands on the first read).
+                row_node_ids = column_list(sweep["RowNodeIDs"])
                 seg = SweepSegment(
                     index=index,
                     job_id=templates[0].JobID,
                     eval_id=templates[0].EvalID,
                     templates=templates,
-                    tg_idx=list(sweep["TGIdx"]),
-                    alloc_ids=list(sweep["AllocIDs"]),
-                    names=list(sweep["Names"]),
-                    node_ids=node_per_alloc,
+                    tg_idx=column_list(sweep["TGIdx"]),
+                    alloc_ids=column_list(sweep["AllocIDs"]),
+                    names=column_list(sweep["Names"]),
+                    row_node_ids=row_node_ids,
+                    counts=np.asarray(sweep["Counts"], dtype=np.int64),
                     kind=sweep.get("Kind", "system"))
                 self.state.apply_sweep_segment(
                     index, seg,

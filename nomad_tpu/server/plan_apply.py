@@ -26,7 +26,7 @@ import os
 import threading
 import time
 from concurrent.futures import ThreadPoolExecutor
-from typing import Dict, List, Optional, Set, Tuple
+from typing import Any, Dict, List, Optional, Set, Tuple
 
 import numpy as np
 
@@ -34,11 +34,9 @@ from nomad_tpu.resilience import failpoints
 from nomad_tpu.tensor.node_table import RES_DIMS, alloc_vec
 from nomad_tpu.structs import (
     Allocation,
-    ColumnarPlacements,
     Plan,
     PlanResult,
     allocs_fit,
-    columns_only,
     remove_allocs,
 )
 from nomad_tpu.structs.structs import NodeStatusReady
@@ -129,9 +127,11 @@ class OptimisticSnapshot:
         self.snap = snap
         self.nt = nt
         self._added: Dict[str, List[Allocation]] = {}
-        # In-flight results whose placements exist as columns only: read
-        # (and only then built into objects) by allocs_by_node_terminal.
-        self._added_columns: List[ColumnarPlacements] = []
+        # In-flight results that came with a sweep descriptor, each kept
+        # whole (a ColumnarPlacements, or a system sweep's per-node dict)
+        # and asked by allocs_by_node_terminal: columns are built into
+        # objects only then, and a 10k-node dict is never merged in.
+        self._added_columns: List[Any] = []
         self._removed: Set[str] = set()
         self.row_delta: Dict[int, np.ndarray] = {}
         # Dense in-flight usage overlay, allocated lazily by the first
@@ -152,8 +152,8 @@ class OptimisticSnapshot:
             # per-alloc row overlay. The descriptor covers every
             # NodeAllocation key (evaluate_plan only attaches it then),
             # so nothing is missed. The exact verify path of a LATER plan
-            # in the group reads _added: objects join it per node, a
-            # columns-only result is kept whole and asked when read.
+            # in the group reads the placements through
+            # allocs_by_node_terminal: kept whole here, asked when read.
             if self.row_dense is None:
                 self.row_dense = np.zeros((self.nt.n_rows, RES_DIMS),
                                           dtype=np.float32)
@@ -164,12 +164,7 @@ class OptimisticSnapshot:
                 grown[:self.row_dense.shape[0]] = self.row_dense
                 self.row_dense = grown
             np.add.at(self.row_dense, sweep.rows, sweep.delta)
-            placements = result.NodeAllocation
-            if columns_only(placements):
-                self._added_columns.append(placements)
-                return
-            for node_id, placed in placements.items():
-                self._added.setdefault(node_id, []).extend(placed)
+            self._added_columns.append(result.NodeAllocation)
             return
         for node_id, placed in result.NodeAllocation.items():
             self._added.setdefault(node_id, []).extend(placed)

@@ -27,6 +27,8 @@ from bisect import bisect_right
 from dataclasses import replace
 from typing import Any, Callable, Dict, Iterable, List, Optional, Set, Tuple
 
+import numpy as np
+
 from nomad_tpu.analysis import guarded_by, requires_lock
 from nomad_tpu.telemetry import metrics
 from nomad_tpu.structs import (
@@ -116,13 +118,15 @@ class SweepSegment:
     (segments are never shared between stores)."""
 
     __slots__ = ("index", "job_id", "eval_id", "templates", "tg_idx",
-                 "alloc_ids", "names", "node_ids", "live", "n_live",
-                 "kind", "_objs")
+                 "alloc_ids", "names", "_node_ids", "row_node_ids",
+                 "counts", "live", "n_live", "kind", "_objs")
 
     def __init__(self, index: int, job_id: str, eval_id: str,
                  templates: List[Allocation], tg_idx: Optional[List[int]],
                  alloc_ids: List[str], names: List[str],
-                 node_ids: List[str], kind: str = "system"):
+                 node_ids: Optional[List[str]] = None,
+                 kind: str = "system",
+                 row_node_ids: Optional[List[str]] = None, counts=None):
         self.index = index
         self.job_id = job_id
         self.eval_id = eval_id
@@ -130,13 +134,40 @@ class SweepSegment:
         self.tg_idx = tg_idx  # None => single template for every row
         self.alloc_ids = alloc_ids
         self.names = names
-        self.node_ids = node_ids
+        # The node column, per allocation, comes either whole (a restored
+        # snapshot) or as the commit's own (row_node_ids, counts): one id
+        # a placed node row and the allocations folded into it. The
+        # commit never expands it; the first read does (`node_ids`).
+        self._node_ids = node_ids
+        self.row_node_ids = row_node_ids
+        self.counts = counts
         # Which commit path built the batch ("system" sweep / "service"
         # window) — operator observability only, no read-path semantics.
         self.kind = kind
         self.live = [True] * len(alloc_ids)
         self.n_live = len(alloc_ids)
         self._objs: Dict[int, Allocation] = {}  # pos -> materialized
+
+    @property
+    def node_ids(self) -> List[str]:
+        """Node id of every row, aligned with `alloc_ids`: one vectorised
+        repeat of the commit's (row_node_ids, counts), kept; the row ids
+        themselves where every row holds one allocation."""
+        ids = self._node_ids
+        if ids is None:
+            ids = self.row_node_ids
+            if len(ids) != len(self.alloc_ids):
+                ids = np.repeat(np.asarray(ids, dtype=object),
+                                self.counts).tolist()
+            self._node_ids = ids
+            self.row_node_ids = self.counts = None
+        return ids
+
+    def touched_node_ids(self):
+        """The distinct nodes' ids (with repeats where the per-allocation
+        column is all there is): what a commit's watch scope is made of."""
+        return (self._node_ids if self.row_node_ids is None
+                else self.row_node_ids)
 
     def materialize(self, pos: int) -> Allocation:
         """Stamp (and cache) the real Allocation for one row. The clone is
@@ -502,7 +533,7 @@ class StateStore(_ReadAPI):
 
     # ----------------------------------------------------------------- writes
     def _commit(self, index: int, tables: Iterable[str], watch_items: Items,
-                scoped: Optional[Dict[str, Set[str]]] = None) -> None:
+                scoped: Optional[Dict[str, Iterable[str]]] = None) -> None:
         # Dedup order is immaterial: every table gets the SAME index and
         # watch items land in a set — no replicated value depends on it.
         # lint: allow(apply_pure, order-independent index assignment)
@@ -548,8 +579,8 @@ class StateStore(_ReadAPI):
             touched = self._set_job_statuses(index, watch_items, jobs,
                                              eval_delete=False)
             self._commit(index, ["allocs"] + touched, watch_items,
-                         scoped={"alloc_node": set(seg.node_ids),
-                                 "alloc": set(seg.alloc_ids)})
+                         scoped={"alloc_node": seg.touched_node_ids(),
+                                 "alloc": seg.alloc_ids})
             for cb in self._listeners:
                 sweep_cb = getattr(cb, "on_sweep_batch", None)
                 if sweep_cb is not None and delta is not None:

@@ -81,28 +81,33 @@ class NotifyGroup:
                         self._waiters.pop(item, None)
 
     def notify(self, items: Iterable[Item],
-               scoped: "Dict[str, Set[str]]" = None) -> None:
+               scoped: "Dict[str, Iterable[str]]" = None) -> None:
         """Wake waiters of `items`, plus — via `scoped` — waiters whose
-        single-field key falls inside a bulk key set ({field: {values}}).
+        single-field key falls inside a bulk key column ({field: values}).
 
         The scoped form exists for columnar batch commits: a 10k-alloc
         sweep touches 10k (alloc, alloc_node) keys, and building+hashing
         an Item per key would put an O(batch) loop back on the commit
         path. Intersecting against the REGISTERED waiters instead costs
         O(waiters), and waiters are bounded by connected blocking queries,
-        not by batch size."""
+        not by batch size. A field's column is hashed into a set by the
+        first waiter registered on that field, so a commit nobody watches
+        by node or by allocation hashes nothing."""
         with self._lock:
             fired: Set[threading.Event] = set()
             for item in items:
                 for ev in self._waiters.get(item, ()):
                     fired.add(ev)
             if scoped:
+                sets: Dict[str, Set[str]] = {}
                 for item, evs in self._waiters.items():
-                    key = item._key
-                    if not (isinstance(key[0], str)):
+                    field = item._key[0]
+                    if not isinstance(field, str) or field not in scoped:
                         continue
-                    values = scoped.get(key[0])
-                    if values is not None and key[1] in values:
+                    values = sets.get(field)
+                    if values is None:
+                        values = sets[field] = set(scoped[field])
+                    if item._key[1] in values:
                         fired.update(evs)
         for ev in fired:
             ev.set()

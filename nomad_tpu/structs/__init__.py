@@ -9,7 +9,7 @@ from .structs import (  # explicit re-exports for the commonly used names
     ServiceRegistration, Task,
     TaskArtifact, TaskEvent, TaskGroup, TaskState, UpdateStrategy,
     ValidationError, generate_uuid, generate_uuids, job_stub, placed_count,
-    stamp_alloc, uuid_rows, uuid_strings,
+    column_list, stamp_alloc, uuid_rows, uuid_strings,
 )
 from .bitmap import Bitmap  # noqa: F401
 from .funcs import allocs_fit, filter_terminal_allocs, remove_allocs, score_fit  # noqa: F401

@@ -1238,6 +1238,17 @@ def stamp_alloc(template: Dict[str, Any], alloc_id: str, name: str,
     return alloc
 
 
+def column_list(values) -> list:
+    """A column of a columnar raft entry as a plain list: a list as it is
+    (no copy), an ndarray by one `tolist` (plain ints and strings, never
+    numpy scalars). The entry's consumers (FSM, store, event builder) take
+    the arrays DevRaft hands on and the lists a decoded log entry holds."""
+    if isinstance(values, list):
+        return values
+    tolist = getattr(values, "tolist", None)
+    return tolist() if tolist is not None else list(values)
+
+
 class _PlacedColumns:
     """What a plan's ColumnarPlacements and every copy made of it share:
     the columns, and the per-node lists once any reader had them built."""

@@ -357,7 +357,7 @@ def test_debug_sched_stats_exports_worker_schema(dev_agent):
     # sync mode / flow counters (README "Replica determinism").
     digest = out["Digest"]
     for key in ("Interval", "LastIndex", "Chain", "Synced", "Folds",
-                "Exchanged", "Diverged", "VerifiedIndex"):
+                "ColumnFolds", "RowFolds", "Exchanged", "Diverged", "VerifiedIndex"):
         assert key in digest, f"Digest key {key} missing from endpoint"
     assert digest["Diverged"] == 0
 

@@ -27,11 +27,13 @@ import random
 import re
 import types
 
+import msgpack
 import numpy as np
 import pytest
 
 from benchmark.deploy.dev_agent_dcs import build_fleet, seeded_uuid
 from nomad_tpu import mock
+from nomad_tpu.raft.backend import encode_command
 from nomad_tpu.scheduler import stack as stack_mod
 from nomad_tpu.scheduler.stack import GenericStack, WindowCollect
 from nomad_tpu.scheduler.system_sweep import SweepBatch
@@ -220,7 +222,9 @@ def _run(monkeypatch, shape, reference):
 
             def recording_apply(msg_type, payload):
                 if msg_type is MessageType.ApplySweepBatch:
-                    entries.append(_without_clock(to_dict(payload)))
+                    # As a follower decodes it: the arrays are lists there.
+                    entries.append(_without_clock(msgpack.unpackb(
+                        encode_command(msg_type, payload), raw=False)[1]))
                 return apply(msg_type, payload)
 
             enqueue_all = srv.plan_queue.enqueue_all
@@ -373,7 +377,9 @@ def test_before_any_commit_the_two_builds_hold_equal_plans(monkeypatch, count,
         == objects.plan._sweep.wire().keys()
     for key, value in columns.plan._sweep.wire().items():
         if key != "Templates":
-            assert value == objects.plan._sweep.wire()[key], key
+            twin = objects.plan._sweep.wire()[key]
+            assert type(value) is type(twin), key
+            np.testing.assert_array_equal(value, twin, err_msg=key)
 
     def plain(plan):
         out = _without_clock(to_dict(plan))

@@ -29,6 +29,7 @@ import msgpack
 import pytest
 
 from nomad_tpu import mock
+from nomad_tpu.raft.backend import encode_command
 from nomad_tpu.resilience import failpoints
 from nomad_tpu.scheduler.system_sched import SystemScheduler
 from nomad_tpu.server.fsm import FSM, MessageType
@@ -122,18 +123,21 @@ def sweep_plan(n_nodes=8, count=2):
     return job, plan
 
 
-def commit_columnar(plan):
-    """Commit the sweep through the REAL columnar entry, including a
-    msgpack round-trip (the consensus wire shape)."""
+def commit_columnar(plan, transport="msgpack"):
+    """Commit the sweep through the REAL columnar entry: by default with
+    a msgpack round-trip (the consensus wire shape, lists all), or as
+    DevRaft hands it to the FSM (`transport="devraft"`: the applier's own
+    arrays and lists, nothing encoded)."""
     result = PlanResult(NodeUpdate=dict(plan.NodeUpdate),
                         NodeAllocation=dict(plan.NodeAllocation))
     result._sweep = plan._sweep
     element, is_sweep = _encode_result(plan, result)
     assert is_sweep
-    blob = msgpack.packb(
-        (int(MessageType.ApplySweepBatch), to_dict({"Batch": [element]})),
-        use_bin_type=True)
-    msg, payload = msgpack.unpackb(blob, raw=False)
+    msg, payload = MessageType.ApplySweepBatch, {"Batch": [element]}
+    if transport == "msgpack":
+        # The replicated backend's own encoding: where arrays become lists.
+        msg, payload = msgpack.unpackb(encode_command(msg, payload),
+                                       raw=False)
     fsm = FSM()
     fsm.apply(APPLY_INDEX, MessageType(msg), payload)
     assert fsm.state._col_segments, "sweep did not commit columnar"
@@ -479,6 +483,101 @@ def service_window(job, n_nodes=6, seed=7, vanish=False):
                              failed, WindowAccumulator(nt.n_rows))
     return types.SimpleNamespace(job=job, plan=plan, ok=ok, failed=failed,
                                  store=store, tindex=tindex)
+
+
+COMMIT_SHAPES = {
+    # one allocation a node row; several a row (the per-allocation node
+    # column is a repeat of the row ids); a service window's rows.
+    "system-one-a-row": lambda: sweep_plan(n_nodes=8, count=1),
+    "system-two-a-row": lambda: sweep_plan(n_nodes=8, count=2),
+    "service-window": lambda: _service_plan(svc_job()),
+    "service-rows-folded": lambda: _service_plan(svc_job(count=5), n_nodes=2),
+}
+
+
+def _service_plan(job, **kw):
+    ns = service_window(job, **kw)
+    assert ns.ok and not ns.failed
+    return ns.job, ns.plan
+
+
+@pytest.mark.parametrize("transport", ["devraft", "msgpack"])
+@pytest.mark.parametrize("shape", sorted(COMMIT_SHAPES))
+def test_reads_after_a_columnar_commit_are_the_object_paths(shape, transport):
+    """ISSUE 35: the entry's columns reach the store as they are (arrays
+    and the emit's lists from DevRaft, lists from a decoded entry), and the
+    segment keeps (row node ids, counts) in place of a per-allocation node
+    column until a read needs one. Every read surface, a snapshot and its
+    restore are those of the per-object commit all the same."""
+    job, plan = COMMIT_SHAPES[shape]()
+    fsm_col = commit_columnar(plan, transport)
+    fsm_obj = commit_objects(plan)
+    [seg] = fsm_col.state._col_segments
+    sweep = plan._sweep
+    # The commit expanded nothing and copied no string column.
+    assert seg._node_ids is None
+    assert list(seg.row_node_ids) == list(sweep.node_ids)
+    assert seg.touched_node_ids() is seg.row_node_ids
+    if transport == "devraft":
+        assert seg.alloc_ids is sweep.alloc_ids
+        assert seg.names is sweep.alloc_names
+        assert seg.row_node_ids is sweep.node_ids
+    by_node = {nid: [a.ID for a in placed]
+               for nid, placed in plan.NodeAllocation.items()}
+    want = [nid for nid in sweep.node_ids for _ in by_node[nid]]
+    assert_same_state(fsm_col, fsm_obj, job, plan)
+    assert seg.node_ids == want and seg.row_node_ids is None
+    if len(want) == len(sweep.node_ids):  # one a row: no copy at all
+        assert seg.node_ids is (sweep.node_ids if transport == "devraft"
+                                else seg._node_ids)
+    for nid, placed in by_node.items():
+        assert fsm_col.state.client_alloc_map(nid) \
+            == ({aid: APPLY_INDEX for aid in placed}, APPLY_INDEX)
+        for aid in placed:
+            assert fsm_col.state.alloc_by_id(aid).NodeID == nid
+    snap = fsm_col.snapshot()
+    assert snap["columnar_allocs"] and not snap["allocs"]
+    assert snap["columnar_allocs"][0]["NodeIDs"] == want
+    assert_same_state(roundtrip(fsm_col), roundtrip(fsm_obj), job, plan)
+
+
+@pytest.mark.parametrize("watched", ["a-placed-node", "a-placed-alloc",
+                                     "the-job", "another-node",
+                                     "another-alloc"])
+def test_a_columnar_commit_wakes_who_watches_what_it_touched(watched):
+    """The commit hands the watch scope its columns as they are; a field's
+    column is hashed only once a waiter is registered on that field."""
+    import threading
+
+    from nomad_tpu.state.watch import Item
+
+    job, plan = sweep_plan(n_nodes=6, count=2)
+    sweep = plan._sweep
+    item, fires = {
+        "a-placed-node": (Item(alloc_node=sweep.node_ids[3]), True),
+        "a-placed-alloc": (Item(alloc=sweep.alloc_ids[-1]), True),
+        "the-job": (Item(alloc_job=job.ID), True),
+        "another-node": (Item(alloc_node="node-9999"), False),
+        "another-alloc": (Item(alloc="no-such-alloc"), False),
+    }[watched]
+    fsm = FSM()
+    woke = threading.Event()
+    fsm.state.watch([item], woke)
+    result = PlanResult(NodeUpdate={}, NodeAllocation=dict(plan.NodeAllocation))
+    result._sweep = sweep
+    element, _ = _encode_result(plan, result)
+    fsm.apply(APPLY_INDEX, MessageType.ApplySweepBatch, {"Batch": [element]})
+    assert woke.is_set() is fires
+
+
+def test_a_snapshot_taken_before_any_read_holds_the_node_column():
+    """The per-allocation node column is first asked for by the snapshot's
+    own serialisation here: no read came between commit and persist."""
+    job, plan = sweep_plan(n_nodes=6, count=2)
+    fsm_col = commit_columnar(plan, "devraft")
+    assert fsm_col.state._col_segments[0]._node_ids is None
+    restored = roundtrip(fsm_col)
+    assert_same_state(restored, commit_objects(plan), job, plan)
 
 
 class TestServiceColumnarEquivalence:

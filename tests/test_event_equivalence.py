@@ -22,6 +22,7 @@ import pytest
 
 from nomad_tpu import mock
 from nomad_tpu.events import EventBroker, expand_batch
+from nomad_tpu.raft.backend import encode_command
 from nomad_tpu.resilience import failpoints
 from nomad_tpu.server import Server, ServerConfig
 from nomad_tpu.server.fsm import FSM, MessageType
@@ -63,9 +64,8 @@ def columnar_entry(plan):
     result._sweep = plan._sweep
     element, is_sweep = _encode_result(plan, result)
     assert is_sweep
-    blob = msgpack.packb(
-        (int(MessageType.ApplySweepBatch), to_dict({"Batch": [element]})),
-        use_bin_type=True)
+    # The replicated backend's own encoding: where arrays become lists.
+    blob = encode_command(MessageType.ApplySweepBatch, {"Batch": [element]})
     return msgpack.unpackb(blob, raw=False)
 
 

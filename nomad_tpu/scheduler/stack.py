@@ -16,6 +16,7 @@ import random
 import threading
 import time
 from collections import OrderedDict
+from contextlib import nullcontext
 from itertools import accumulate
 from dataclasses import dataclass, field
 from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
@@ -184,11 +185,16 @@ class WindowCollect:
     that already holds placements the objects are stamped at once, beside
     the descriptor."""
 
-    __slots__ = ("nt", "acc", "_queued")
+    __slots__ = ("nt", "acc", "net_span", "_queued")
 
-    def __init__(self, nt, acc: Optional[WindowAccumulator] = None):
+    def __init__(self, nt, acc: Optional[WindowAccumulator] = None,
+                 net_span=nullcontext):
         self.nt = nt
         self.acc = acc if acc is not None else WindowAccumulator(nt.n_rows)
+        # () -> context manager round an eval's network assignments (the
+        # window worker's `netassign` stage); entered only by an eval
+        # whose groups ask for a network.
+        self.net_span = net_span
         self._queued: List[_Queued] = []
 
     def add(self, stack: "GenericStack", prep: "PreparedBatch", cr,
@@ -209,7 +215,8 @@ class WindowCollect:
                 _Queued(stack, prep, cr, eval_id, job, place, plan))
             return None
         return stack._collect_build_exact(prep, cr, eval_id, job, place,
-                                          plan, failed_tg_allocs, self.acc)
+                                          plan, failed_tg_allocs, self.acc,
+                                          self.net_span)
 
     def build(self) -> List[bool]:
         """Plans for the queued evals, one verdict each (False: a chosen
@@ -499,6 +506,12 @@ class GenericStack:
         self._cand_mask: Optional[np.ndarray] = None
         self._nodes_by_id: Dict[str, Node] = {}
         self._netidx_cache: Dict[str, NetworkIndex] = {}
+        # What the network asks of this stack's eval cost (touched only
+        # where a group asks for a network): placements given an offer,
+        # NetworkIndexes built (the cache's misses), assignments refused.
+        self.net_offers = 0
+        self.netidx_builds = 0
+        self.net_refused = 0
 
     # ------------------------------------------------------------- wiring
     def set_job(self, job: Job) -> None:
@@ -1062,7 +1075,8 @@ class GenericStack:
     def _collect_build_exact(self, prep: PreparedBatch, cr,
                              eval_id: str, job: Job, place,
                              plan, failed_tg_allocs,
-                             acc: "WindowAccumulator") -> bool:
+                             acc: "WindowAccumulator",
+                             net_span=nullcontext) -> bool:
         """The exact per-placement build: ONE pass from the compacted
         kernel output (CompactResult: chosen rows, scores, per-eval
         success) to plan allocations, skipping the SelectedOption list and
@@ -1071,10 +1085,20 @@ class GenericStack:
         Serves what WindowCollect does not build as columns (failed
         placements, network asks) and is the oracle of what it does.
         Returns False when a winner fails host-side network assignment or
-        its node vanished."""
+        its node vanished. Where a group asks for a network, the winners'
+        ports and bandwidth are assigned first, in placement order, inside
+        `net_span` (the window worker's `netassign` stage: one span an
+        eval, not one a placement)."""
         nt = self.tindex.nt
         chosen_list = cr.chosen.tolist()
         scores_list = cr.scores.tolist()
+        options = None
+        if prep.has_network_asks:
+            with net_span():
+                options = self._assign_placed_networks(
+                    prep, chosen_list, scores_list, len(place))
+            if options is None:
+                return False
 
         node_of = nt.node_of
         nodes_by_id = self._nodes_by_id
@@ -1123,12 +1147,16 @@ class GenericStack:
                 # sync path's build_placement_allocs records.
                 failed_counts[tg.Name] = failed_counts.get(tg.Name, 0) + 1
                 continue
-            node = nodes_by_id.get(node_of[row])
-            if node is None:
-                return False
-            option = self._assign_networks(node, tg, scores_list[p])
-            if option is None:
-                return False
+            if options is not None:
+                option = options[p]
+                node = option.node
+            else:
+                node = nodes_by_id.get(node_of[row])
+                if node is None:
+                    return False
+                option = self._assign_networks(node, tg, scores_list[p])
+                if option is None:
+                    return False
             score_node(node, "binpack", scores_list[p])
             placed_rows.append(row)
             placed_ps.append(p)
@@ -1207,6 +1235,27 @@ class GenericStack:
                 counts[row] += sum(1 for a in placed if a.JobID == self.job.ID)
         return counts
 
+    def _assign_placed_networks(self, prep: PreparedBatch, chosen_list,
+                                scores_list, n: int
+                                ) -> Optional[List[Optional[SelectedOption]]]:
+        """_assign_networks for every placement that found a row, in
+        placement order (None at a failed placement). None when a node
+        vanished or an assignment was refused: the eval falls back."""
+        node_of = self.tindex.nt.node_of
+        options: List[Optional[SelectedOption]] = [None] * n
+        for p in range(n):
+            row = chosen_list[p]
+            if row < 0:
+                continue
+            node = self._nodes_by_id.get(node_of[row])
+            if node is None:
+                return None
+            option = options[p] = self._assign_networks(
+                node, prep.tgs[p], scores_list[p])
+            if option is None:
+                return None
+        return options
+
     def _assign_networks(self, node: Node, tg: TaskGroup,
                          score: float) -> Optional[SelectedOption]:
         """Host-side port/bandwidth assignment for a chosen node."""
@@ -1227,6 +1276,7 @@ class GenericStack:
             netidx.set_node(node)
             netidx.add_allocs(self.ctx.proposed_allocs(node.ID))
             self._netidx_cache[node.ID] = netidx
+            self.netidx_builds += 1
         option = SelectedOption(node=node, score=score)
         staged = []
         for task in tg.Tasks:
@@ -1240,11 +1290,13 @@ class GenericStack:
                     # Staged reservations from this partial TG poison the
                     # cached index; drop it so the next user rebuilds clean.
                     self._netidx_cache.pop(node.ID, None)
+                    self.net_refused += 1
                     return None
                 netidx.add_reserved(offer)
                 staged.append(offer)
                 resources.Networks = [offer]
             option.task_resources[task.Name] = resources
+        self.net_offers += 1
         return option
 
     def _fill_metrics(self, prep: PreparedBatch, ti: int,

@@ -149,6 +149,13 @@ STATS_COUNTERS = (
     "collect_exact",     # every other one: the exact per-placement loop
     #                      (failed placements, network asks), or refused by
     #                      the pass (vanished node) and re-run per eval
+    "net_offers",     # fast-path placements given a network offer (ports
+    #                   and bandwidth on the chosen node): 0 for a job that
+    #                   asks for no network
+    "netidx_builds",  # NetworkIndexes built for them: one a node an eval
+    #                   touches (an eval's index cache is its own)
+    "net_refused",    # assignments the index refused (bandwidth or ports
+    #                   exhausted on the chosen node): the eval falls back
 )
 STATS_TIMERS_MS = (
     "t_lease_ms",        # waiting for the shared chain-lease (ChainArbiter)
@@ -164,6 +171,8 @@ STATS_TIMERS_MS = (
     "t_drain_ms",        # whole drain stage
     "t_drain_fetch_ms",  # blocking device->host readback
     "t_collect_ms",      # packed output -> plan allocations
+    "t_netassign_ms",    # of it: ports and bandwidth for the winners of
+    #                      evals that ask for a network (one span an eval)
     "t_build_ms",        # whole plan build/submit pass
     "t_planwait_ms",     # waiting on the plan applier
     "t_evalupd_ms",      # consensus EvalUpdate batch
@@ -258,7 +267,15 @@ def _prep_sig(job, place, batch: bool) -> Optional[tuple]:
     """Value signature of a prepared batch: two jobs with equal constraints,
     task shapes, and placement sequence produce byte-identical device inputs,
     so their PreparedBatch can be shared within a window. Returns None when
-    sharing is unsafe (network asks need per-node port bookkeeping)."""
+    sharing is unsafe (network asks need per-node port bookkeeping).
+
+    What None costs: the eval's prepared batch is built anew
+    (stack.prepare_batch, neither taken from nor kept in the node context)
+    and the eval is not `shareable`, so _launch_window gives it a run, and
+    a device launch, of its own: a window of 32 such evals is 32 chained
+    `dispatch` calls where 32 signed ones are one `dispatch_multi`. Its
+    plan is then collected by the exact loop and committed as objects
+    (benchmark cell web-10k.storm; PERF.md section 5)."""
     from nomad_tpu.tensor.constraints import constraint_sig
 
     tg_sigs = {}
@@ -1015,7 +1032,9 @@ class PipelinedWorker(Worker):
         # saw — but it stays DEFERRED (queued batches, no scatter) until an
         # exhaustion actually reads it, which an all-placed storm window
         # never does.
-        window = WindowCollect(self.tindex.nt)
+        window = WindowCollect(
+            self.tindex.nt,
+            net_span=lambda: self._stage("netassign", work.number))
         submit: List[_FastEval] = []
         with self._stage("collect", work.number):
             # Redelivered between stages: abandoned.
@@ -1030,6 +1049,10 @@ class PipelinedWorker(Worker):
                 except Exception:
                     logger.exception("collect failed for eval %s", rec.ev.ID)
                     ok = False
+                if rec.prep.has_network_asks:
+                    self.stats["net_offers"] += rec.stack.net_offers
+                    self.stats["netidx_builds"] += rec.stack.netidx_builds
+                    self.stats["net_refused"] += rec.stack.net_refused
                 if ok is None:
                     queued.append(rec)
                 elif not ok:

@@ -208,6 +208,11 @@ class OptimisticSnapshot:
         return self.snap.get_index(table)
 
 
+# allocs_fit's verdicts that come from the NetworkIndex, not from CPU,
+# memory, disk or IOPS.
+_NETWORK_DIMS = ("reserved port collision", "bandwidth exhausted")
+
+
 def _alloc_asks_network(alloc: Allocation) -> bool:
     if alloc.Resources is not None and alloc.Resources.Networks:
         return True
@@ -324,6 +329,14 @@ def _vector_fit(snap, plan: Plan, nt, node_ids: List[str]
         ok = np.all(usage + d <= capacity, axis=1)
         for nid, fit in zip(row_ids, ok):
             fits[nid] = bool(fit)
+    # Which half a plan's nodes took: the share of `exact_nodes` is what a
+    # network ask costs the applier (a NetworkIndex a node).
+    if fits:
+        metrics.incr_counter(("nomad", "plan", "verify", "vector_nodes"),
+                             len(fits))
+    if exact:
+        metrics.incr_counter(("nomad", "plan", "verify", "exact_nodes"),
+                             len(exact))
     return fits, exact
 
 
@@ -418,9 +431,13 @@ def _evaluate_node_plan(snap, plan: Plan, node_id: str) -> bool:
     proposed = remove_allocs(list(existing), remove)
     proposed.extend(plan.NodeAllocation.get(node_id, ()))
     try:
-        fit, _, _ = allocs_fit(node, proposed)
+        fit, dim, _ = allocs_fit(node, proposed)
     except ValueError:
         return False
+    if not fit and dim in _NETWORK_DIMS:
+        # Two plans drew the same port blind to each other, or together
+        # overcommit the device: the node is refused, the plan is partial.
+        metrics.incr_counter(("nomad", "plan", "partial", "ports"))
     return fit
 
 

@@ -182,7 +182,8 @@ served_alone = pytest.fixture()(_served)
 def test_the_worker_times_every_stage_the_issue_lists():
     assert set(STAGES) == {"lease", "fill", "dispatch", "refresh", "nodectx",
                            "launch", "drain_stack", "drain", "drain_fetch",
-                           "build", "collect", "planwait", "evalupd", "slow"}
+                           "build", "collect", "netassign", "planwait",
+                           "evalupd", "slow"}
     # The per-eval timers of _try_dispatch_fast are all that is left of
     # the hand-written pairs: they add to `stats` alone, by design.
     assert WORKER_SOURCE.count("perf_counter()") == 5

@@ -127,7 +127,19 @@ def test_keyed_program_compiles_at_c1m_widths(one_chip):
               *_tail_inputs(C1M_ROWS, 32 * 1024, one_chip, reset=True))
 
 
-@pytest.mark.parametrize("p_pad", [64, 1024])
+def test_keyed_program_compiles_at_web10k_widths(one_chip):
+    """benchmark/configs/web-10k.json: a task that asks for a network
+    shares no prepared batch, so every eval is a launch of its own
+    (stack.dispatch): 10 placements padded to 16 with 16 candidates, one
+    key, at 16,384 rows; 32 of them chained make a window."""
+    k = kernels.keyed_cand_count(10)
+    assert k == 16
+    _compiles(kernels._keyed_program(None, k),
+              *_node_inputs(ROWS, one_chip), _shape((1, 5), F32, one_chip),
+              *_tail_inputs(ROWS, 16, one_chip, reset=True))
+
+
+@pytest.mark.parametrize("p_pad", [16, 64, 1024])
 def test_compact_window_compiles(one_chip, p_pad):
     _compiles(kernels.compact_window, _shape((32, p_pad, 3), F32, one_chip),
               _shape((32, p_pad), BOOL, one_chip),

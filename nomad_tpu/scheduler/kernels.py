@@ -34,6 +34,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from . import replay_kernel
+
 
 _LOG2_10 = float(np.log2(10.0))
 
@@ -67,25 +69,33 @@ class PlacementResult(NamedTuple):
         return self.packed[:, 2].astype(jnp.int32)
 
 
-def _score(usage2: jax.Array, score_cap: jax.Array) -> jax.Array:
+def _score_cols(use_cpu: jax.Array, use_mem: jax.Array,
+                cap_cpu: jax.Array, cap_mem: jax.Array) -> jax.Array:
     """BestFit-v3: 20 - 10^freeCpuPct - 10^freeMemPct, clamped to [0, 18].
 
-    usage2 [..., 2] is proposed (cpu, mem) utilization including reserved;
-    score_cap [..., 2] is capacity minus reserved (broadcastable). Division
-    by zero follows IEEE (Inf/NaN) exactly like the Go reference; NaN
-    sanitizes to 0. THE one definition of the formula for the device
-    programs (the monolithic scan and the keyed kernel's three passes);
-    the numpy mirror (place_batch_host) repeats the same f32 operations.
-    Tests assert all of them bit-for-bit equal on XLA's CPU backend. A
-    chip's divide and exp2 need not round as numpy's do: PERF.md records
-    what chip_smoke.py observed on the v5e.
+    use_* is proposed (cpu, mem) utilization including reserved; cap_* is
+    capacity minus reserved (broadcastable). Division by zero follows IEEE
+    (Inf/NaN) exactly like the Go reference; NaN sanitizes to 0. THE one
+    definition of the formula for the device programs (the monolithic
+    scan, the keyed kernel's passes and its resident replay loop, which
+    holds a resource a column); the numpy mirror (place_batch_host)
+    repeats the same f32 operations. Tests assert all of them bit-for-bit
+    equal on XLA's CPU backend. A chip's divide and exp2 need not round as
+    numpy's do: PERF.md records what was observed on the v5e.
     """
-    free_pct = 1.0 - usage2 / score_cap
+    free_cpu = 1.0 - use_cpu / cap_cpu
+    free_mem = 1.0 - use_mem / cap_mem
     # 10^x on the MXU-friendly path: exp2(x * log2 10).
-    total = (jnp.exp2(free_pct[..., 0] * _LOG2_10)
-             + jnp.exp2(free_pct[..., 1] * _LOG2_10))
+    total = (jnp.exp2(free_cpu * _LOG2_10) + jnp.exp2(free_mem * _LOG2_10))
     score = jnp.clip(20.0 - total, 0.0, 18.0)
     return jnp.nan_to_num(score, nan=0.0, posinf=18.0, neginf=0.0)
+
+
+def _score(usage2: jax.Array, score_cap: jax.Array) -> jax.Array:
+    """`_score_cols` over usage2 [..., 2] (cpu, mem) and score_cap
+    [..., 2] (broadcastable)."""
+    return _score_cols(usage2[..., 0], usage2[..., 1],
+                       score_cap[..., 0], score_cap[..., 1])
 
 
 def _make_step(capacity, score_cap, tg_masks, noise, penalty,
@@ -400,11 +410,33 @@ def place_batch_host(capacity, score_cap, usage, tg_masks, job_counts,
 # oracle the mesh pipeline is gated against bit-for-bit.
 
 
+def keyed_replay_resident(n_rows: int, r_dims: int, n_keys: int,
+                          k_cand: int) -> bool:
+    """Whether the single-device keyed program for a launch of this static
+    shape (table rows and resource columns, keys, candidate budget) runs
+    its replay as the loop resident on the chip (`replay_kernel`) or as
+    the `lax.scan`. THE one rule: the program builder asks it, and so does
+    whoever counts such launches (PipelinedWorker's `launch_resident`), so
+    the count cannot disagree with what ran. A shape is declined only
+    where its candidate columns would not fit the kernel's share of VMEM
+    (many keys over a large candidate table; no served cell comes near)."""
+    return replay_kernel.fits(n_keys * min(k_cand, n_rows), n_keys, r_dims)
+
+
 @functools.lru_cache(maxsize=64)
-def _keyed_program(mesh, k_cand: int):
+def _keyed_program(mesh, k_cand: int, replay_mode: str | None = None):
     """Build the jitted single-device keyed-candidate program (mesh is
     accepted for cache-key compatibility but must be None; mesh execution
-    goes through `_mesh_keyed_program`)."""
+    goes through `_mesh_keyed_program`). `replay_mode` says how step 3 runs
+    where the shape allows the resident loop: None, the served path, is
+    the kernel as this process's backend runs it (Mosaic on a TPU, Pallas'
+    interpreter anywhere else); "mosaic" is the chip's form whatever the
+    backend (tests that lower for a described chip from a CPU host);
+    "scan" keeps the `lax.scan`, the exact path the resident loop is gated
+    against (tests only)."""
+    if replay_mode is None:
+        replay_mode = ("mosaic" if jax.default_backend() == "tpu"
+                       else "interpret")
     assert mesh is None, "mesh windows run the shard-local pipeline"
 
     def local_fn(capacity, score_cap, usage, tg_masks, job_counts0,
@@ -541,9 +573,33 @@ def _keyed_program(mesh, k_cand: int):
             ])
             return (c_use, c_cnt, c_ban), out
 
-        (c_use_f, _, _), packed = jax.lax.scan(
-            replay, (c_use0, c_cnt0, c_ban0),
-            (tg_ids, valid, reset, kd_p))                # [P, 3]
+        def scan_replay():
+            (c_use_f, _, _), packed = jax.lax.scan(
+                replay, (c_use0, c_cnt0, c_ban0),
+                (tg_ids, valid, reset, kd_p))            # [P, 3]
+            return packed, c_use_f
+
+        def resident_replay(interpret):
+            # The same chain as one loop over VMEM-resident columns: it
+            # answers with candidate indexes, mapped to rows here.
+            out, c_use_f = replay_kernel.resident_replay(
+                _score_cols, c_cap, c_sc, c_use0, c_cnt0, c_ban0, c_noise,
+                c_elig & keep[:, None],
+                nf0 - jnp.sum(ok0c, axis=0).astype(jnp.int32),
+                key_demands, tg_ids, valid, reset, penalty, distinct,
+                interpret=interpret)
+            i = out[:, 0].astype(jnp.int32)
+            row = jnp.where(i >= 0, rows_s[jnp.maximum(i, 0)], -1)
+            return (jnp.concatenate(
+                [row.astype(jnp.float32)[:, None], out[:, 1:]], axis=1),
+                c_use_f)
+
+        if replay_mode == "scan" or not keyed_replay_resident(
+                n_loc, r_dims, n_keys, k_cand):
+            packed, c_use_f = scan_replay()
+        else:
+            packed, c_use_f = resident_replay(
+                interpret=replay_mode != "mosaic")
 
         # Publish the replay's FINAL candidate usage into the owning
         # shard's rows by scatter-SET: c_use_f accumulated each row's won

@@ -717,6 +717,19 @@ class GenericStack:
             return "keyed"
         return "scan"
 
+    def replay_resident(self, prep: PreparedBatch, n_valid: int) -> bool:
+        """Whether a device launch of n_valid real placements of this
+        prepared batch replays as the loop resident on the chip: the keyed
+        program on one device, at a shape its builder does not decline
+        (kernels.keyed_replay_resident, the builder's own rule). The mesh
+        pipeline and the monolithic scans keep their lax.scan."""
+        nt = self.tindex.nt
+        return (self._device_kind(prep, n_valid) == "keyed"
+                and (nt.mesh is None or nt.mesh.devices.size == 1)
+                and kernels.keyed_replay_resident(
+                    nt.n_rows, RES_DIMS, prep.tg_masks.shape[0],
+                    kernels.keyed_cand_count(n_valid)))
+
     def _launch_device(self, d, usage, kind: str, dev: tuple, n_valid: int):
         nt = self.tindex.nt
         if kind == "keyed":

@@ -137,6 +137,9 @@ STATS_COUNTERS = (
     "launch_steps",    # serial replay steps they dispatched: e_pad x p_pad
     #                    a fused launch, p_pad a single one
     "launch_placements",  # real placements in those steps (n_valid)
+    "launch_resident",    # launches whose replay ran as the loop resident
+    #                       on the chip, not the lax.scan: what the static
+    #                       shape decides (kernels.keyed_replay_resident)
     "plans_columnar",  # submitted fast plans whose placements stayed columns
     #                    until their window settled: no object was built
     "plans_objects",   # every other one: objects built at collect, or
@@ -793,6 +796,8 @@ class PipelinedWorker(Worker):
                     self.stats["launch_evals"] += len(run)
                     self.stats["launch_steps"] += steps
                     self.stats["launch_placements"] += placements
+                    self.stats["launch_resident"] += \
+                        rec.stack.replay_resident(rec.prep, placements)
                     fl = getattr(usage_chain, "flag", None)
                     if fl is not None:
                         mesh_flags.append(fl)

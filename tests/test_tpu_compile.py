@@ -97,7 +97,7 @@ def test_keyed_window_program_compiles(one_chip):
     argsort and in-scan scatters."""
     k = kernels.keyed_cand_count(32 * 50)
     assert k == 2048
-    _compiles(kernels._keyed_program(None, k),
+    _compiles(kernels._keyed_program(None, k, "mosaic"),
               *_node_inputs(ROWS, one_chip), _shape((1, 5), F32, one_chip),
               *_tail_inputs(ROWS, WINDOW_P, one_chip, reset=True))
 
@@ -110,7 +110,7 @@ def test_keyed_program_compiles_at_dc50k_widths(one_chip, keys, evals):
     up to a whole window of one shape (stack.dispatch_multi)."""
     k = kernels.keyed_cand_count(evals * 50)
     assert keys * k <= 1 << 17  # stack.KEYED_CAND_BUDGET: the keyed path
-    _compiles(kernels._keyed_program(None, k),
+    _compiles(kernels._keyed_program(None, k, "mosaic"),
               *_node_inputs(DC_ROWS, one_chip, keys),
               _shape((keys, 5), F32, one_chip),
               *_tail_inputs(DC_ROWS, evals * 64, one_chip, reset=True))
@@ -122,7 +122,7 @@ def test_keyed_program_compiles_at_c1m_widths(one_chip):
     the 8,192-row table: lax.top_k over the whole node axis, no trim."""
     k = kernels.keyed_cand_count(32 * 1000)
     assert k == 32768 > C1M_ROWS and k <= 1 << 17
-    _compiles(kernels._keyed_program(None, k),
+    _compiles(kernels._keyed_program(None, k, "mosaic"),
               *_node_inputs(C1M_ROWS, one_chip), _shape((1, 5), F32, one_chip),
               *_tail_inputs(C1M_ROWS, 32 * 1024, one_chip, reset=True))
 
@@ -134,9 +134,30 @@ def test_keyed_program_compiles_at_web10k_widths(one_chip):
     key, at 16,384 rows; 32 of them chained make a window."""
     k = kernels.keyed_cand_count(10)
     assert k == 16
-    _compiles(kernels._keyed_program(None, k),
+    _compiles(kernels._keyed_program(None, k, "mosaic"),
               *_node_inputs(ROWS, one_chip), _shape((1, 5), F32, one_chip),
               *_tail_inputs(ROWS, 16, one_chip, reset=True))
+
+
+@pytest.mark.parametrize("rows,keys,n_valid,steps", [
+    (ROWS, 1, 10, 16), (ROWS, 1, 32 * 50, WINDOW_P),
+    (DC_ROWS, 2, 32 * 50, WINDOW_P), (C1M_ROWS, 1, 32 * 1000, 32 * 1024),
+], ids=["web-10k", "svc-10k", "dc-50k-two-keys", "c1m-5k"])
+def test_the_replay_lowers_to_the_resident_kernel_for_the_chip(
+        one_chip, rows, keys, n_valid, steps):
+    """Lowered for the described v5e, the keyed program of every storm
+    cell holds its replay as the Mosaic kernel (scheduler/replay_kernel.py),
+    the scan build of the same bucket does not, and the rule from static
+    shape says which. (The compiles above are of these same programs.)"""
+    k = kernels.keyed_cand_count(n_valid)
+    assert kernels.keyed_replay_resident(rows, 5, keys, k)
+    args = (*_node_inputs(rows, one_chip, keys),
+            _shape((keys, 5), F32, one_chip),
+            *_tail_inputs(rows, steps, one_chip, reset=True))
+    served = kernels._keyed_program(None, k, "mosaic").lower(*args).as_text()
+    assert "tpu_custom_call" in served and "keyed_replay" in served
+    oracle = kernels._keyed_program(None, k, "scan").lower(*args).as_text()
+    assert "tpu_custom_call" not in oracle
 
 
 @pytest.mark.parametrize("p_pad", [16, 64, 1024])

@@ -6,7 +6,12 @@ eligible nodes can hold, submitting stops and the window ends there, so
 that a faster program is not punished with blocked evals.
 
 Traffic parameters: outstanding, poll_ms, templates (weights), fill_guard
-(share of eligible capacity, optional)."""
+(share of eligible capacity, optional).
+
+`progress(asked, limit)`, where the harness gives one, is told once a
+registration how far the window has come towards its guard (limit None
+without a guard): a traced run may anchor its trace to that
+(instruments.Window.progress; the file's trace_guard_share)."""
 
 from __future__ import annotations
 
@@ -16,7 +21,7 @@ from benchmark.ops import pick_template, poll, submit
 from benchmark.reference.guarantees import capacity_allocs
 
 
-def run(dep, traffic, rng, seconds, clock=time.perf_counter):
+def run(dep, traffic, rng, seconds, clock=time.perf_counter, progress=None):
     poll_s = traffic["poll_ms"] / 1e3
     target = int(traffic["outstanding"])
     notes = []
@@ -47,5 +52,7 @@ def run(dep, traffic, rng, seconds, clock=time.perf_counter):
             ops.append(op)
             pending.append(op)
             asked += op.asks
+            if progress is not None:
+                progress(asked, limit)
         time.sleep(max(0.0, min(poll_s, t_end - clock())))
     return {"t0": t0, "t1": now, "gave_up": None, "ops": ops, "notes": notes}

@@ -44,7 +44,10 @@ def gaps(traffic, rng):
         yield from block
 
 
-def run(dep, traffic, rng, seconds, clock=time.perf_counter):
+def run(dep, traffic, rng, seconds, clock=time.perf_counter, progress=None):
+    # `progress` is the harness's hook for a window that a fill guard ends
+    # (generators/closed_loop.py); an open loop has no guard and no use
+    # for it.
     poll_s = traffic["poll_ms"] / 1e3
     gap = gaps(traffic, rng)
     ops, pending = [], []

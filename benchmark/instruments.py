@@ -60,10 +60,12 @@ class CompileLog:
 
 class SampleSink:
     """A telemetry sink (MetricsRegistry.add_sink) that keeps every timer
-    sample with the time it arrived. Added in traced runs only."""
+    sample and every counter increment with the time it arrived. Added in
+    traced runs only."""
 
     def __init__(self):
-        self.rows = []  # (time.perf_counter(), dotted name, value)
+        self.rows = []      # (time.perf_counter(), dotted name, value)
+        self.counters = []  # the same for incr_counter: value is the step
 
     def add_sample(self, key, value):
         self.rows.append((time.perf_counter(), ".".join(key), value))
@@ -72,7 +74,7 @@ class SampleSink:
         pass
 
     def incr_counter(self, key, value):
-        pass
+        self.counters.append((time.perf_counter(), ".".join(key), value))
 
 
 class Window:
@@ -82,23 +84,28 @@ class Window:
     so that a traced run of a cell the device sits out still shows it)."""
 
     def __init__(self, dep, compiles, traced, trace_dir, trace_seconds,
-                 on_chip):
+                 on_chip, trace_guard_share=None, timer=threading.Timer):
         self.dep = dep
         self.on_chip = on_chip
         self.compile_log = compiles
         self.traced = traced
         self.trace_dir = trace_dir
         self.trace_seconds = trace_seconds
+        self.trace_guard_share = trace_guard_share
+        self._new_timer = timer  # a test hands in one it fires itself
         self.gc_events = []       # (generation, seconds) inside the window
         self.compiles = []
         self.stats_delta = {}
         self.trace_stats_delta = {}
         self.device = None
+        self.trace = None  # the parsed trace (xplane.load), for the readers
         self._sink = None
         self._gc_started = None
         self._timer = None
+        self._anchored = False
         self._tracing = threading.Lock()
         self._trace_t0 = None
+        self._trace_by = None
         self._trace_stats0 = None
 
     # ----------------------------------------------------------- window
@@ -111,18 +118,42 @@ class Window:
             metrics.registry.add_sink(self._sink)
             gc.callbacks.append(self._on_gc)
             shutil.rmtree(self.trace_dir, ignore_errors=True)
-            self._timer = threading.Timer(
-                max(0.0, seconds - self.trace_seconds), self._start_trace)
+            self._timer = self._new_timer(
+                max(0.0, seconds - self.trace_seconds), self._start_trace,
+                ("clock",))
             self._timer.daemon = True
             self._timer.start()
         self.t0 = time.perf_counter()
+
+    def progress(self, asked, limit):
+        """The generator's report, once a registration, of the allocations
+        asked for inside the window against its fill guard's limit. Where
+        the traffic file states trace_guard_share, the trace starts when
+        `asked` reaches (1 - trace_guard_share) of the limit, if neither
+        the clock (begin's timer) nor the window's end came first: a window
+        that its guard ends early is traced over its last stretch of work,
+        however fast the program gets through it. Without the key, or
+        without a guard, nothing happens here and the clock decides as it
+        always did."""
+        if not self.traced or self.trace_guard_share is None \
+                or limit is None or self._anchored:
+            return
+        if asked >= (1.0 - self.trace_guard_share) * limit:
+            self._anchored = True  # once: _start_trace itself runs once
+            self._timer.cancel()
+            # On a thread of its own, as the clock's timer starts it: the
+            # generator goes on registering while the profiler starts.
+            self._timer = self._new_timer(0.0, self._start_trace,
+                                          ("guard",))
+            self._timer.daemon = True
+            self._timer.start()
 
     def end(self):
         self.t1 = time.perf_counter()
         if self.traced:
             gc.callbacks.remove(self._on_gc)
             self._timer.cancel()
-            self._start_trace()  # the window ended early: trace the rest
+            self._start_trace("window_end")  # ended early: trace the rest
             self._mark("bench.window_end")
             self.trace_stats_delta = _delta(self._trace_stats0,
                                             self.dep.worker_stats())
@@ -152,6 +183,32 @@ class Window:
                     out.setdefault(name, []).append(value)
         return out
 
+    def trace_facts(self):
+        """Where the trace lay, for the run's note: seconds from the
+        window's opening to the trace's start and, on the chip, the traced
+        span's length and its part inside the window."""
+        if self._trace_t0 is None:
+            return None
+        facts = {"started_after_s": self._trace_t0 - self.t0,
+                 "started_by": self._trace_by}
+        if self.device is not None:
+            facts.update(window_s=self.device["window_s"],
+                         in_window_s=self.device["in_window_s"])
+        return facts
+
+    def counters(self):
+        """In-window sums of the registry's counters by dotted name: {} in
+        a traced run in which none was incremented (a counter that never
+        moved reads 0), None in an untraced run (no sink: nothing to
+        read)."""
+        if self._sink is None:
+            return None
+        out = {}
+        for t, name, value in self._sink.counters:
+            if self.t0 <= t <= self.t1:
+                out[name] = out.get(name, 0.0) + value
+        return out
+
     # ---------------------------------------------------------- private
     def _on_gc(self, phase, info):
         if phase == "start":
@@ -161,12 +218,13 @@ class Window:
                                    time.perf_counter() - self._gc_started))
             self._gc_started = None
 
-    def _start_trace(self):
+    def _start_trace(self, by):
         import jax
 
         with self._tracing:
             if self._trace_t0 is not None:
                 return
+            self._trace_by = by  # "clock", "guard" or "window_end"
             options = jax.profiler.ProfileOptions()
             options.python_tracer_level = 0
             options.host_tracer_level = 1  # enough for the bench.* markers
@@ -192,8 +250,9 @@ class Window:
         if not paths:
             raise RuntimeError("the profiler wrote no trace under "
                                + self.trace_dir)
-        trace = xplane.load(paths[0])
-        self.device = xplane.reduce(trace, window_s=t_stop - self._trace_t0,
+        self.trace = xplane.load(paths[0])  # parsed once a run
+        self.device = xplane.reduce(self.trace,
+                                    window_s=t_stop - self._trace_t0,
                                     in_window_s=self.t1 - self._trace_t0)
 
 

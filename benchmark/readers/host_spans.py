@@ -7,29 +7,16 @@ host_gaps.py): which stage of the program the idle device was waiting for.
   without: names whose open time is taken out again, so that two metrics
            split the idle time without counting a moment twice
 
-Read from the trace the harness left under .bench_work/trace/ (parsed once
-per process). None in a rehearsal (no device plane) and where the trace is
-missing; 0 where the program has no such spans, as before ISSUE 26."""
-
-import functools
-import os
+Read from the run's parsed trace, which the harness parsed once to reduce
+it (instruments.Window, run["trace"]). None in a rehearsal and in an
+untraced run (no trace, or one without a device plane); 0 where the program
+has no such spans, as before ISSUE 26."""
 
 from benchmark.trace import host_gaps
 
-ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))))
-
-
-@functools.lru_cache(maxsize=2)
-def _trace(path, mtime):
-    return host_gaps.load(path)
-
 
 def read(run, spans, without=()):
-    if run["device"] is None:
+    trace = run.get("trace")
+    if trace is None:
         return None
-    path = host_gaps.newest_trace(ROOT)
-    if path is None:
-        return None
-    trace = _trace(path, os.path.getmtime(path))
     return host_gaps.idle_share(trace, spans, without)

@@ -133,16 +133,26 @@ def capacity_allocs(nodes, job):
 
 
 class Verdict:
-    """Named failures with the ids that show them, and counted facts."""
+    """Named failures with the ids that show them, counted facts, and
+    every number compared beside its limit (`compared`, by check, in the
+    order judged: what a run prints as its last lines and under the result
+    line's last key). A check that counts breaches compares the count of
+    the ids that show them (1 where it names none) with 0; a check with a
+    tolerance hands in its `value` and `limit`."""
 
     def __init__(self):
         self.failures = []
         self.facts = {}
+        self.compared = {}
 
-    def require(self, check, ok, detail, ids=()):
+    def require(self, check, ok, detail, ids=(), value=None, limit=0.0):
+        ids = list(ids)
+        if value is None:
+            value = len(ids) or (0 if ok else 1)
+        self.compared[check] = {"value": float(value), "limit": float(limit)}
         if not ok:
             self.failures.append({"check": check, "detail": detail,
-                                  "ids": list(ids)[:MAX_IDS]})
+                                  "ids": ids[:MAX_IDS]})
 
     @property
     def correct(self):
@@ -277,7 +287,8 @@ def check(reads, acknowledged, failed_jobs, device_usage, row_of):
               f"{len(not_read_back)} acknowledged jobs are not in the store",
               not_read_back)
     v.require("6_device_usage", usage_err <= 1e-2,
-              f"largest |device - recomputed| usage = {usage_err}")
+              f"largest |device - recomputed| usage = {usage_err}",
+              value=usage_err, limit=1e-2)
     v.facts = {"nodes": len(nodes), "jobs": len(jobs),
                "allocations": len(allocs), "terminal_allocations": terminal,
                "acknowledged_jobs": len(acknowledged),

@@ -160,13 +160,15 @@ def check(dep, seed, verdict):
     infeasible, gap, err, usage = replay_choices(inp, dev)
     usage_err = float(np.max(np.abs(np.asarray(res.usage_after) - usage)))
     verdict.require("7_kernel_feasible", infeasible == 0,
-                    f"{infeasible} infeasible device choices")
+                    f"{infeasible} infeasible device choices",
+                    value=infeasible)
     verdict.require("7_kernel_best_fit", gap <= SCORE_TOL and err <= SCORE_TOL,
                     f"gap to the best feasible score {gap}, score error "
-                    f"against float64 {err}")
+                    f"against float64 {err}",
+                    value=max(gap, err), limit=SCORE_TOL)
     verdict.require("7_kernel_usage_after", usage_err <= 1e-2,
                     "usage after the window differs from the replay's by "
-                    f"{usage_err}")
+                    f"{usage_err}", value=usage_err, limit=1e-2)
     return {"rows": int(nt.n_rows), "evals": n_evals,
             "placements": int(v.sum()),
             "placed_by_device": int((dev[v, 0] >= 0).sum()),

@@ -216,10 +216,11 @@ def judge(inp, packed, usage_after, verdict):
     verdict.require("7_kernel_best_fit",
                     gap <= SCORE_TOL and err <= SCORE_TOL,
                     f"gap to the key's best feasible score {gap}, score "
-                    f"error against float64 {err}")
+                    f"error against float64 {err}",
+                    value=max(gap, err), limit=SCORE_TOL)
     verdict.require("7_kernel_usage_after", usage_err <= USAGE_TOL,
                     "usage after the window differs from the replay's by "
-                    f"{usage_err}")
+                    f"{usage_err}", value=usage_err, limit=USAGE_TOL)
     return {"infeasible_choices": len(infeasible),
             "max_gap_to_best_feasible": gap,
             "score_max_err_vs_float64": err,

@@ -9,9 +9,11 @@ its configuration (benchmark/configs/), its traffic mix (benchmark/traffic/)
 and its metrics (benchmark/end_to_end/, benchmark/layer_metrics/); the files
 name the deploy, generator and reader modules. It deploys the system, warms
 that cell's shapes (all of it set-up), measures for --seconds, drains,
-recomputes the guarantees, and prints one JSON object as its last line.
-Lines before it are notes of this one run. benchmark/README.md says how a
-later PR adds a cell, a mix, a configuration or a metric as new files only.
+recomputes the guarantees, and prints one JSON object as its last line
+(its last key, `compared`, holds every number `correct` compared beside
+its limit; the same are the last lines on standard error). Lines before it
+are notes of this one run. benchmark/README.md says how a later PR adds a
+cell, a mix, a configuration or a metric as new files only.
 
 Without a TPU, or with fewer chips than the cell asks for, it exits 1 and
 prints no result. --allow-cpu rehearses on the CPU at the configuration's
@@ -96,10 +98,13 @@ def main(argv=None):
                                        ROOT, ".bench_work", "trace"),
                                    trace_seconds=cell.traffic.get(
                                        "trace_seconds", 3),
-                                   on_chip=not rehearsal)
+                                   on_chip=not rehearsal,
+                                   trace_guard_share=cell.traffic.get(
+                                       "trace_guard_share"))
         setup_s = time.perf_counter() - T_PROCESS
         probe.begin(seconds)
-        window = generator.run(dep, cell.traffic, rng, seconds)
+        window = generator.run(dep, cell.traffic, rng, seconds,
+                               progress=probe.progress)
         probe.end()
         for text in window["notes"]:
             note("generator", text=text)
@@ -130,8 +135,10 @@ def main(argv=None):
         "cell": cell, "window": window, "seconds": window["t1"] - window["t0"],
         "ops": window_ops, "failed_jobs": failed, "setup_s": setup_s,
         "stats": probe.stats_delta, "trace_stats": probe.trace_stats_delta,
-        "samples": probe.samples(), "gc": probe.gc_events,
+        "samples": probe.samples(), "counters": probe.counters(),
+        "gc": probe.gc_events,
         "compiles": probe.compiles, "device": probe.device,
+        "trace": probe.trace,
     }
     metrics = cells.read_metrics(
         cell, "per_layer" if args.trace else "end_to_end", run)
@@ -140,6 +147,7 @@ def main(argv=None):
          trace=args.trace, ops=len(window_ops), setup_s=setup_s,
          drain_and_check_s=check_s, compiles_in_window=len(probe.compiles),
          worker_stats_delta=probe.stats_delta, worker_stats=stats_total,
+         trace_span=probe.trace_facts(),
          failed_operations={j: failed[j] for j in list(failed)[:8]},
          guarantees=facts, deployment=dep_facts)
     for failure in verdict.failures:
@@ -155,6 +163,13 @@ def main(argv=None):
         result["device"]["busy_s"] = probe.device["busy_s"]
         result["device"]["window_s"] = probe.device["window_s"]
         result["breakdown"] = probe.device["breakdown"]
+    # Every number `correct` compared, beside its limit: the last lines on
+    # standard error and the last key of the result line.
+    result["compared"] = verdict.compared
+    for check, c in verdict.compared.items():
+        print(f"compared {check}: {c['value']!r} (limit {c['limit']!r})",
+              file=sys.stderr)
+    sys.stderr.flush()
     print(json.dumps(result), flush=True)
     return 0
 

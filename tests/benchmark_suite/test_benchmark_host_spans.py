@@ -20,6 +20,11 @@ with open(os.path.join(ROOT, "BENCHMARK.json")) as _f:
 DISPATCH, PLANWAIT = "nomad.worker.dispatch", "nomad.worker.planwait"
 
 
+def _json(*path):
+    with open(os.path.join(ROOT, *path)) as f:
+        return json.load(f)
+
+
 def _span(name, start, end, **attrs):
     return {"name": name, "start_s": start, "end_s": end, **attrs}
 
@@ -45,14 +50,14 @@ TRACE = {
 
 
 def test_interval_arithmetic():
-    assert host_gaps.union([(3, 4), (1, 2), (1.5, 2.5), (5, 5)]) == [
+    assert xplane.union([(3, 4), (1, 2), (1.5, 2.5), (5, 5)]) == [
         (1, 2.5), (3, 4)]
-    assert host_gaps.intersect([(0, 2), (3, 6)], [(1, 4), (5, 7)]) == [
+    assert xplane.intersect([(0, 2), (3, 6)], [(1, 4), (5, 7)]) == [
         (1, 2), (3, 4), (5, 6)]
-    assert host_gaps.subtract([(0, 10)], [(1, 2), (4, 5), (9, 12)]) == [
+    assert xplane.subtract([(0, 10)], [(1, 2), (4, 5), (9, 12)]) == [
         (0, 1), (2, 4), (5, 9)]
-    assert host_gaps.subtract([(0, 1), (2, 3)], [(0, 1)]) == [(2, 3)]
-    assert host_gaps.length([(0, 1.5), (2, 2.25)]) == pytest.approx(1.75)
+    assert xplane.subtract([(0, 1), (2, 3)], [(0, 1)]) == [(2, 3)]
+    assert xplane.length([(0, 1.5), (2, 2.25)]) == pytest.approx(1.75)
 
 
 def test_idle_time_is_put_down_to_the_span_open_in_it():
@@ -92,6 +97,65 @@ def test_the_gaps_table_names_the_spans_open_in_each_gap():
     assert sum(g["gap_s"] for g in table) == pytest.approx(6.0)
 
 
+def test_the_breakdown_sums_all_idle_time_by_phase_and_stage():
+    """xplane.reduce's breakdown.idle_gaps (ISSUE 38): the traced span's
+    idle seconds, all of them, by the phase they lay in and the stage the
+    host had open, by one precedence: dispatch, else plan wait, else the
+    other span that covers most of the gap, else none."""
+    trace = dict(TRACE, spans=TRACE["spans"] + [
+        _span("nomad.worker.lease", 0.0, 0.4),      # idle 0.0-0.5: most of
+        _span("nomad.worker.drain", 0.35, 0.5),     # it lease (0.4 > 0.15)
+        _span("nomad.plan.evaluate", 7.25, 7.5),    # idle 7.0-8.0
+        _span("bench.device_read", 9.6, 10.5),      # idle 9.5-10.0
+        _span("nomad.fsm.sweep", 7.0, 8.0)])        # not a stage that counts
+    out = xplane.reduce(trace, window_s=10.0, in_window_s=8.0)
+    rows = out["breakdown"]["idle_gaps"]
+    assert rows == sorted(rows, key=lambda r: -r[1])
+    assert dict(map(tuple, rows)) == {
+        "window:" + DISPATCH: pytest.approx(2.0),   # 0.5-1.0, 2.5-4.0
+        "window:" + PLANWAIT: pytest.approx(2.0),   # 2.0-2.5, 5.5-7.0
+        "window:nomad.worker.lease": pytest.approx(0.5),
+        "window:none": pytest.approx(0.5),          # 5.0-5.5
+        # One gap, one name: the whole of 7.0-8.0 goes to the span that
+        # covers most of it, not a quarter of it.
+        "window:nomad.plan.evaluate": pytest.approx(1.0),
+        # After the window: late(3) runs 9.0-9.5; a dispatch is open
+        # 8.5-9.5, the read of check 6 from 9.6.
+        "after_window:" + DISPATCH: pytest.approx(0.5),
+        "after_window:none": pytest.approx(0.5),    # 8.0-8.5
+        "after_window:bench.device_read": pytest.approx(0.5)}
+    assert sum(s for _, s in rows) == pytest.approx(10.0 - out["busy_s"])
+    # The two rows that have a metric of their own agree with it.
+    assert 100 * 2.0 / 8 == pytest.approx(
+        host_gaps.idle_share(trace, [DISPATCH]))
+    assert 100 * 2.0 / 8 == pytest.approx(
+        host_gaps.idle_share(trace, [PLANWAIT], without=[DISPATCH]))
+
+
+def test_more_pairs_than_rows_are_folded_and_the_sum_holds():
+    # Twelve stages, each alone in a gap of its own length, in a window
+    # the device sits out, and the idle second after it: thirteen pairs.
+    # The eight longest keep their names, the rest are one "other" row a
+    # phase (here one phase: nine rows).
+    spans = [_span(f"nomad.worker.s{i:02d}", float(i), i + 0.25 + i * 0.05)
+             for i in range(12)]
+    programs = [(f"p({i})", i + 0.25 + i * 0.05,
+                 0.75 - i * 0.05) for i in range(12)]
+    trace = {"devices": [{"name": "/device:TPU:0", "programs": programs}],
+             "markers": {"bench.trace_begin": 0.0, "bench.window_end": 12.0},
+             "spans": spans}
+    out = xplane.reduce(trace, window_s=13.0, in_window_s=12.0)
+    rows = out["breakdown"]["idle_gaps"]
+    assert len(rows) == 9 and xplane.TOP == 10
+    names = [n for n, _ in rows]
+    assert names[:2] == ["window:other", "after_window:none"]
+    assert "window:nomad.worker.s11" in names
+    assert "window:nomad.worker.s00" not in names  # the shortest: folded
+    assert dict(map(tuple, rows))["window:other"] == pytest.approx(
+        sum(0.25 + i * 0.05 for i in range(5)))
+    assert sum(s for _, s in rows) == pytest.approx(13.0 - out["busy_s"])
+
+
 def test_no_span_reads_zero_and_no_device_plane_reads_nothing():
     bare = dict(TRACE, spans=[])  # a program from before ISSUE 26
     assert host_gaps.idle_share(bare, [DISPATCH]) == 0.0
@@ -105,6 +169,21 @@ def test_no_span_reads_zero_and_no_device_plane_reads_nothing():
 
 def test_the_reader_reads_nothing_in_a_rehearsal():
     assert host_spans.read({"device": None}, [DISPATCH]) is None
+    assert host_spans.read({"device": None, "trace": None},
+                           [DISPATCH]) is None
+
+
+def test_the_reader_reads_the_trace_the_harness_parsed():
+    # run["trace"] is what instruments.Window parsed to reduce the run:
+    # the reader opens no file of its own.
+    run = {"device": {}, "trace": TRACE}
+    assert host_spans.read(run, [DISPATCH]) == pytest.approx(
+        host_gaps.idle_share(TRACE, [DISPATCH]))
+    assert host_spans.read(run, [PLANWAIT], without=[DISPATCH]) \
+        == pytest.approx(host_gaps.idle_share(TRACE, [PLANWAIT],
+                                              without=[DISPATCH]))
+    assert not hasattr(host_spans, "_trace")
+    assert not hasattr(host_gaps, "load")  # xplane.load is the one parser
 
 
 def test_a_stats_key_the_program_lacks_is_left_out_not_raised():
@@ -127,11 +206,26 @@ NEW = ["broker_wait_ms.trickle", "broker_wait_ms.rollout", "fill_ms.trickle",
 
 @pytest.mark.parametrize("name", NEW)
 def test_a_new_metric_is_declared_for_its_cell_and_layer(name):
-    entry = next(m for m in BENCH["per_layer"] if m["name"] == name)
+    declared(BENCH, name)
+
+
+def declared(bench, name):
+    entry = next(m for m in bench["per_layer"] if m["name"] == name)
     cell = {"storm": "svc-10k.storm", "trickle": "svc-10k.trickle",
             "rollout": "sys-10k.rollout"}[name.rsplit(".", 1)[1]]
-    assert entry["workloads"] == [cell] and entry["better"] == "lower"
-    layers = {m["layer"] for m in BENCH["per_layer"] if m["name"] not in NEW}
+    assert entry["workloads"][0] == cell and entry["better"] == "lower"
+    # Every other name is a cell of the same traffic family: one that the
+    # same generator drives and that reports the end-to-end metric the
+    # entry moves (ISSUE 38 put the four .storm entries on all four storm
+    # cells).
+    moved = next(m for m in bench["end_to_end"] if m["name"] == entry["moves"])
+    traffic = {w["name"]: _json("benchmark", "traffic",
+                                w["traffic"] + ".json")
+               for w in bench["workloads"]}
+    for other in entry["workloads"][1:]:
+        assert other in moved["workloads"]
+        assert traffic[other]["generator"] == traffic[cell]["generator"]
+    layers = {m["layer"] for m in bench["per_layer"] if m["name"] not in NEW}
     assert entry["layer"] in layers  # a layer the benchmark already names
     device = name.startswith("device_idle.")
     assert entry["source"] == ("device_trace" if device else "program_span")
@@ -154,7 +248,7 @@ CUT = BEGIN + 0.40
 
 @pytest.fixture(scope="module")
 def recorded():
-    trace = host_gaps.load(SAMPLE)
+    trace = xplane.load(SAMPLE)
     assert trace["markers"] == {"bench.trace_begin": pytest.approx(BEGIN)}
     trace["markers"]["bench.window_end"] = CUT
     return trace
@@ -221,6 +315,45 @@ def test_known_coverage_of_the_recorded_trace(recorded):
     assert host_gaps.idle_share(recorded, [PLANWAIT]) == pytest.approx(
         38.79349, abs=1e-4)
     assert dispatch + planwait <= idle["in_window_idle_share"]
+
+
+def test_the_breakdown_of_the_recorded_trace(recorded):
+    out = xplane.reduce(recorded, window_s=0.45, in_window_s=0.40)
+    rows = out["breakdown"]["idle_gaps"]
+    assert 2 <= len(rows) <= xplane.TOP
+    assert all(n.split(":", 1)[0] in ("window", "after_window")
+               for n, _ in rows)
+    # Its seconds are the span's idle seconds, all of them.
+    assert sum(s for _, s in rows) == pytest.approx(0.45 - out["busy_s"],
+                                                    abs=1e-9)
+    by_name = dict(map(tuple, rows))
+    # The rows for dispatch and plan wait are what the two metrics that
+    # stand read from the same file, to the microsecond.
+    assert 100 * by_name["window:" + DISPATCH] / 0.40 == pytest.approx(
+        host_gaps.idle_share(recorded, [DISPATCH]), abs=1e-6)
+    assert 100 * by_name["window:" + PLANWAIT] / 0.40 == pytest.approx(
+        host_gaps.idle_share(recorded, [PLANWAIT], without=[DISPATCH]),
+        abs=1e-6)
+    assert rows[0][0] == "window:" + DISPATCH
+    assert rows[0][1] == pytest.approx(0.190598935, abs=1e-8)
+    in_window = sum(s for n, s in rows if n.startswith("window:"))
+    assert 100 * in_window / 0.40 == pytest.approx(
+        out["in_window_idle_share"], abs=1e-6)
+    # Without the spans (a program from before PR 26) the same reduction
+    # names no stage.
+    bare = xplane.reduce(dict(recorded, spans=[]), 0.45, 0.40)
+    assert {n for n, _ in bare["breakdown"]["idle_gaps"]} == {
+        "window:none", "after_window:none"}
+    assert bare["breakdown"]["device_ops"] == out["breakdown"]["device_ops"]
+
+
+def test_the_device_read_is_a_marker_and_a_span(recorded):
+    # xplane.load keeps the harness's bench.device_read as a span too
+    # (the recorded file was cut before one): nothing else named bench.*.
+    assert not [s for s in recorded["spans"]
+                if s["name"].startswith("bench.")]
+    assert xplane.DEVICE_READ == "bench.device_read"
+    assert xplane.STAGE_FIRST == (DISPATCH, PLANWAIT)
 
 
 def test_the_tool_prints_the_table_or_says_what_the_file_lacks(capsys):

@@ -133,6 +133,13 @@ def test_an_oversubscribed_node_a_misplaced_allocation_and_a_short_job():
     assert by_check["2_capacity"]["ids"] == [node.ID]
     assert by_check["3_constraints"]["ids"] == [moved.ID]
     assert by_check["4_counts"]["ids"] == [f"{short.ID}: 49 live of 50"]
+    # A check that counts breaches compares their count with 0.
+    assert [verdict.compared[c] for c in ("2_capacity", "3_constraints",
+                                          "4_counts")] == [
+        {"value": 1.0, "limit": 0.0}] * 3
+    assert list(verdict.compared) == [
+        "2_capacity", "3_constraints", "4_identity", "4_counts",
+        "5_read_back", "6_device_usage", "8_platform"]
 
 
 @pytest.mark.parametrize("break_it,check", [
@@ -155,8 +162,16 @@ def test_the_device_usage_table_and_the_platform_are_checked():
     s = State()
     usage = s.device_usage()
     usage[3, 0] += 20.0
-    assert _names(s.judge(usage)[0]) == ["6_device_usage"]
-    assert _names(s.judge(platform="cpu")[0]) == ["8_platform"]
+    verdict = s.judge(usage)[0]
+    assert _names(verdict) == ["6_device_usage"]
+    # The number compared stands beside its limit, sound or not.
+    assert verdict.compared["6_device_usage"] == {"value": 20.0,
+                                                  "limit": 1e-2}
+    assert verdict.compared["2_capacity"] == {"value": 0.0, "limit": 0.0}
+    late = s.judge(platform="cpu")[0]
+    assert _names(late) == ["8_platform"]
+    assert late.compared["8_platform"] == {"value": 1.0, "limit": 0.0}
+    assert late.compared["6_device_usage"]["value"] == 0.0
 
 
 def test_an_eval_still_pending_after_the_drain_is_a_failed_operation():

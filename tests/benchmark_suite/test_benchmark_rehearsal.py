@@ -45,8 +45,19 @@ def test_rehearsal_prints_the_contracts_last_line(cell, trace):
     lines = [json.loads(ln) for ln in proc.stdout.splitlines()]
     last = lines[-1]
     assert set(last) == {"correct", "attempted", "failed", "metrics",
-                         "device"}
+                         "device", "compared"}
     assert last["correct"] is True, lines[:-1]
+    # Every number compared beside its limit: the line's last key, and the
+    # last lines on standard error.
+    assert list(last)[-1] == "compared"
+    compared = last["compared"]
+    assert {"2_capacity", "3_constraints", "4_identity", "4_counts",
+            "5_read_back", "6_device_usage", "8_platform"} <= set(compared)
+    assert all(c["value"] <= c["limit"] for c in compared.values())
+    said = [ln for ln in proc.stderr.splitlines() if ln.strip()]
+    assert said[-len(compared):] == [
+        f"compared {k}: {c['value']!r} (limit {c['limit']!r})"
+        for k, c in compared.items()]
     assert last["attempted"] > 0 and last["failed"] == 0
     assert last["device"] == {"platform": "cpu", "kind": "cpu", "count": 1,
                               "memory_peak_bytes": 0}

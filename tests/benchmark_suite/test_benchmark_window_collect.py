@@ -18,18 +18,28 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 with open(os.path.join(ROOT, "BENCHMARK.json")) as _f:
     BENCH = json.load(_f)
+with open(os.path.join(ROOT, "tests", "benchmark_suite",
+                       "per_layer_at_pr37.json")) as _f:
+    AT_PR37 = json.load(_f)  # the 52 entries as PR 37's tree had them
 NAME = "window_collect_share.storm"
 STORMS = ["svc-10k.storm", "dc-50k.storm"]
 
 
-def test_it_is_declared_beside_the_share_it_follows():
-    entry = next(m for m in BENCH["per_layer"] if m["name"] == NAME)
+def declared(bench):
+    entry = next(m for m in bench["per_layer"] if m["name"] == NAME)
     assert entry == {
         "name": NAME, "unit": "%", "better": "higher",
         "source": "program_counter", "layer": "Stack: scheduler/stack.py",
-        "moves": "placed_per_s", "workloads": STORMS}
-    assert BENCH["per_layer"][-1] is entry  # appended, nothing moved
-    assert entry["layer"] in {m["layer"] for m in BENCH["per_layer"][:-1]}
+        "moves": "placed_per_s", "workloads": entry["workloads"]}
+    # The two cells it was declared for come first; the list may have
+    # grown behind them (ISSUE 38: every storm-family cell).
+    assert entry["workloads"][:2] == STORMS
+    assert bench["per_layer"][51] is entry  # it stands where PR 31 put it
+    assert entry["layer"] in {m["layer"] for m in bench["per_layer"][:51]}
+
+
+def test_it_is_declared_beside_the_share_it_follows():
+    declared(BENCH)
     with open(os.path.join(ROOT, "benchmark", "layer_metrics",
                            NAME + ".json")) as f:
         spec = json.load(f)
@@ -37,6 +47,37 @@ def test_it_is_declared_beside_the_share_it_follows():
     assert spec["args"] == {"num": "collect_windowed",
                             "per": ["collect_windowed", "collect_exact"],
                             "scale": 100.0}
+
+
+def stands(bench, index):
+    """The driver takes a new per-layer entry only at the end of the list
+    and calls any other difference a change to a metric that stands. So
+    the 52 entries of PR 37 keep their places and every field; only a
+    `workloads` list may have grown, behind the cells it had. Whatever
+    comes after index 51 is a later PR's to append."""
+    assert len(AT_PR37) == 52 and len(bench["per_layer"]) >= 52
+    was, now = AT_PR37[index], bench["per_layer"][index]
+    assert list(now) == list(was)  # the same keys in the same order
+    for key in ("name", "unit", "better", "source", "layer", "moves"):
+        assert now[key] == was[key]
+    assert now["workloads"][:len(was["workloads"])] == was["workloads"]
+    assert len(set(now["workloads"])) == len(now["workloads"])
+
+
+@pytest.mark.parametrize("index", range(len(AT_PR37)),
+                         ids=[m["name"] for m in AT_PR37])
+def test_the_list_of_pr_37_stands_as_a_prefix_entry_for_entry(index):
+    stands(BENCH, index)
+
+
+def names(bench):
+    found = [m["name"] for m in bench["per_layer"]]
+    assert found[:52] == [m["name"] for m in AT_PR37]
+    assert len(set(found)) == len(found)
+
+
+def test_names_are_unique_and_later_entries_come_after_the_prefix():
+    names(BENCH)
 
 
 def test_the_share_is_read_from_the_two_counters_or_left_out():

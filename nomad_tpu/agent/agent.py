@@ -193,6 +193,7 @@ class Agent:
         self.http: Optional[HTTPServer] = None
         self.rpc_endpoints = None
         self._rpc_pool = None
+        self._runtime_metrics = False  # holds metrics.runtime while started
         if not config.data_dir:
             config.data_dir = tempfile.mkdtemp(prefix="nomad_tpu_")
         if not config.node_name:
@@ -207,6 +208,10 @@ class Agent:
         trace.configure(enabled=self.config.trace_enabled,
                         sample_ratio=self.config.trace_sample_ratio,
                         ring=self.config.trace_ring)
+        if not self._runtime_metrics:
+            # nomad.runtime.*: one collector a process, shared by its agents.
+            self._runtime_metrics = True
+            metrics.runtime.acquire()
         try:
             if self.config.server_enabled:
                 if self.config.dev_mode:
@@ -387,6 +392,11 @@ class Agent:
 
     def shutdown(self) -> None:
         logging.getLogger().removeHandler(self.log_ring)
+        if self._runtime_metrics:
+            from nomad_tpu.telemetry import metrics
+
+            self._runtime_metrics = False
+            metrics.runtime.release()
         if getattr(self, "_server_service_node_id", None):
             # Graceful departure: pull this server's registry entries so
             # bootstrapping clients stop being handed its addresses. (A

@@ -902,9 +902,14 @@ def cmd_sched_stats(args) -> int:
                     if not k.startswith("t_")}
         print("  " + "  ".join(f"{k}={v}" for k, v in
                                sorted(counters.items())))
-        print(f"  {'stage':<20} {'total ms':>12}")
-        for k in sorted(k for k in stats if k.startswith("t_")):
-            print(f"  {k:<20} {stats[k]:>12.1f}")
+        # Wall, and beside it the thread CPU of the stages that keep it
+        # (t_<stage>_cpu_ms): a stage far over its CPU stood waiting.
+        print(f"  {'stage':<20} {'total ms':>12} {'cpu ms':>12}")
+        for k in sorted(k for k in stats if k.startswith("t_")
+                        and not k.endswith("_cpu_ms")):
+            cpu = stats.get(k[:-len("_ms")] + "_cpu_ms")
+            print(f"  {k:<20} {stats[k]:>12.1f}"
+                  + (f" {cpu:>12.1f}" if cpu is not None else ""))
     return 0
 
 

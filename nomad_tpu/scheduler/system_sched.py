@@ -290,7 +290,8 @@ class SystemScheduler:
         kept for network-ask groups, deregisters, and as the equivalence
         oracle."""
         if use_sweep:
-            with metrics.measure(("nomad", "sched", "system", "sweep")):
+            with metrics.measure(("nomad", "sched", "system", "sweep"),
+                                 cpu=True):
                 system_sweep.compute_job_allocs(self)
             metrics.incr_counter(("nomad", "sched", "system", "fast"))
             return

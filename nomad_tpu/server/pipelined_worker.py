@@ -160,6 +160,12 @@ STATS_COUNTERS = (
     "net_refused",    # assignments the index refused (bandwidth or ports
     #                   exhausted on the chosen node): the eval falls back
 )
+# The stages that partition their thread's time, and no stage nested in
+# them: beside t_<stage>_ms, which is how long the stage stood open,
+# t_<stage>_cpu_ms is the CPU its thread spent in it (time.thread_time).
+CPU_STAGES = ("lease", "fill", "dispatch",              # the run loop
+              "drain",                                  # the drain thread
+              "build", "planwait", "evalupd", "slow")   # the build thread
 STATS_TIMERS_MS = (
     "t_lease_ms",        # waiting for the shared chain-lease (ChainArbiter)
     "t_fill_ms",         # window fill, raft-sync barrier, snapshot
@@ -180,9 +186,14 @@ STATS_TIMERS_MS = (
     "t_planwait_ms",     # waiting on the plan applier
     "t_evalupd_ms",      # consensus EvalUpdate batch
     "t_slow_ms",         # slow-path evals of the window
-    "t_stagewait_ms",    # windows waiting at a stage seam, blocked put incl.
+    "t_stagewait_ms",    # windows waiting at a stage seam, blocked put incl.:
+    "t_wait_drain_ms",   # ... at _drain_q and
+    "t_wait_build_ms",   # ... at _build_q (the two sum to t_stagewait_ms)
+    "t_handoff_drain_ms",  # of t_wait_drain_ms: the run loop blocked in put
+    "t_handoff_build_ms",  # of t_wait_build_ms: the drain thread blocked
+    "t_turnwait_ms",     # of t_planwait_ms: the chain-order barrier
     "t_mesh_exchange_ms",  # mesh pipeline: cold rebuild + winner exchange
-)
+) + tuple(f"t_{stage}_cpu_ms" for stage in CPU_STAGES)
 
 
 def new_stats() -> dict:
@@ -341,23 +352,44 @@ class PipelinedWorker(Worker):
         """One stage of one window, timed once for all three readers: the
         registry's nomad.worker.<stage> sample and the profiler's span
         (metrics.measure, which carries `attrs`), and
-        stats["t_<stage>_ms"]. One span a stage a window: per-eval work
-        inside a stage adds to `stats` alone."""
+        stats["t_<stage>_ms"]; for a stage of CPU_STAGES also the
+        thread's CPU inside it, stats["t_<stage>_cpu_ms"]. One span a
+        stage a window: per-eval work inside a stage adds to `stats`
+        alone."""
         timed = metrics.measure(("nomad", "worker", stage),
                                 worker=self.name, window=window, **attrs)
+        # The thread's CPU clock is a system call a read: taken for the
+        # eight outer stages, not for the dozen nested in them.
+        cpu0 = time.thread_time() if stage in CPU_STAGES else None
         try:
             with timed:
                 yield
         finally:
             self.stats[f"t_{stage}_ms"] += timed.ms
+            if cpu0 is not None:
+                self.stats[f"t_{stage}_cpu_ms"] += \
+                    (time.thread_time() - cpu0) * 1e3
 
-    def _hand_off(self, q: "queue.Queue", work: _WindowWork) -> None:
+    def _hand_off(self, stage: str, q: "queue.Queue",
+                  work: _WindowWork) -> None:
+        """Offer a window to the next thread. The offer is a stage of the
+        GIVER (`handoff_drain`: the run loop, `handoff_build`: the drain
+        thread): with the seam's one slot taken the put blocks, the giver
+        does nothing else, and the span says so on its own thread. The
+        taker counts the same time again from the stamp (_enter_stage)."""
         work.staged = time.monotonic()
-        q.put(work)  # the taker counts the wait, a blocked put included
+        with self._stage(stage, work.number):
+            q.put(work)
 
-    def _enter_stage(self, work: _WindowWork) -> None:
-        self.stats["t_stagewait_ms"] += \
-            (time.monotonic() - work.staged) * 1e3
+    def _enter_stage(self, seam: str, work: _WindowWork) -> None:
+        """The taker's side of a seam: from the stamp before the put to
+        the take, a blocked put included, added to the seam's key (each
+        has one writer: its taker's thread); t_stagewait_ms is their
+        sum."""
+        stats = self.stats
+        stats[seam] += (time.monotonic() - work.staged) * 1e3
+        stats["t_stagewait_ms"] = \
+            stats["t_wait_drain_ms"] + stats["t_wait_build_ms"]
         self._reset_window_deadlines(work)
 
     # -------------------------------------------------------------- run loop
@@ -422,7 +454,7 @@ class PipelinedWorker(Worker):
                     # failure path.
                     self._arbiter.abort(lease)
                 if work is not None:
-                    self._hand_off(self._drain_q, work)
+                    self._hand_off("handoff_drain", self._drain_q, work)
         finally:
             self._drain_q.put(None)
             drainer.join(timeout=60.0)
@@ -460,7 +492,7 @@ class PipelinedWorker(Worker):
             if work is None:
                 self._build_q.put(None)
                 return
-            self._enter_stage(work)
+            self._enter_stage("t_wait_drain_ms", work)
             try:
                 if work.fast and not work.failed:
                     with self._stage("drain", work.number):
@@ -473,7 +505,7 @@ class PipelinedWorker(Worker):
                 if not (self._stop.is_set()
                         or not self.eval_broker.enabled()):
                     logger.exception("pipelined worker: window drain failed")
-            self._hand_off(self._build_q, work)
+            self._hand_off("handoff_build", self._build_q, work)
 
     def _build_loop(self) -> None:
         """Stage 3: plan build/submit -> status batch -> acks, plus the
@@ -482,7 +514,7 @@ class PipelinedWorker(Worker):
             work = self._build_q.get()
             if work is None:
                 return
-            self._enter_stage(work)
+            self._enter_stage("t_wait_build_ms", work)
             try:
                 if work.failed:
                     raise RuntimeError("window drain failed")
@@ -1160,7 +1192,9 @@ class PipelinedWorker(Worker):
         # ANOTHER worker's tail could otherwise beat that worker's build
         # here and read the taint sequence before the phantom it rode on
         # is announced.
-        if not self._arbiter.wait_turn(work.chain_seq, self._stop):
+        with self._stage("turnwait", work.number):
+            in_turn = self._arbiter.wait_turn(work.chain_seq, self._stop)
+        if not in_turn:
             logger.debug("window %d: predecessors unsettled after barrier "
                          "timeout; taint check may be early", work.chain_seq)
         external_taint = (work.chained

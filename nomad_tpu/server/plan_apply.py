@@ -441,6 +441,14 @@ def _evaluate_node_plan(snap, plan: Plan, node_id: str) -> bool:
     return fit
 
 
+def _join(wait: threading.Thread) -> None:
+    """Wait for the previous group's commit. One nomad.plan.join sample a
+    group that had a commit before it: how long the applier's loop stood
+    behind its own apply thread (~0 where that had already ended)."""
+    with metrics.measure(("nomad", "plan", "join")):
+        wait.join()
+
+
 class PlanApplier:
     """The leader's plan-apply loop with verify/apply overlap
     (reference: planApply, plan_apply.go:41-119).
@@ -448,7 +456,7 @@ class PlanApplier:
     Concurrency note (why no guarded_by registry here): the applier's
     mutable state is confined by protocol, not by a lock. The run loop
     owns verify-side stats keys; the single in-flight apply thread owns
-    apply-side keys (`applied`/`apply_failed`/`t_apply_ms`); the run
+    apply-side keys (`applied`/`apply_failed`); the run
     loop only reads apply-side keys after `wait.join()`, which is the
     happens-before edge. At most one apply thread exists at a time."""
 
@@ -474,11 +482,11 @@ class PlanApplier:
         self._retired: List[threading.Thread] = []
         self._pool_size = pool_size or max(1, (os.cpu_count() or 2) // 2)
         self._pool: Optional[ThreadPoolExecutor] = None
-        # Counters for telemetry/tests (t_* wall-clock; under GIL
-        # contention these overcount serialized python, like the worker's).
+        # Counters for telemetry/tests. The verify and the apply are timed
+        # by the registry: nomad.plan.evaluate (a plan), nomad.plan.apply
+        # (a group), each with its thread CPU as `.cpu`.
         self.stats = {"applied": 0, "rejected": 0, "overlapped": 0,
-                      "apply_failed": 0, "t_verify_ms": 0.0,
-                      "t_apply_ms": 0.0}
+                      "apply_failed": 0}
 
     def _nt(self):
         return self.tindex.nt if self.tindex is not None else None
@@ -593,7 +601,7 @@ class PlanApplier:
 
                 # Last apply already done? Fall back to a fresh snapshot.
                 if wait is not None and not wait.is_alive():
-                    wait.join()
+                    _join(wait)
                     wait = None
                     opt = None
                 # The optimistic view is only valid WHILE an apply is in
@@ -621,7 +629,7 @@ class PlanApplier:
                     nonlocal wait
                     failed_before = self.stats["apply_failed"]
                     if wait is not None:
-                        wait.join()
+                        _join(wait)
                         wait = None
                     return (OptimisticSnapshot(
                                 self.raft.fsm.state.snapshot(),
@@ -638,7 +646,7 @@ class PlanApplier:
                 # than one group from the log (plan_apply.go:96-103).
                 if wait is not None:
                     prev_failed_before = self.stats["apply_failed"]
-                    wait.join()
+                    _join(wait)
                     opt = OptimisticSnapshot(self.raft.fsm.state.snapshot(),
                          nt=self._nt())
                     if self.stats["apply_failed"] != prev_failed_before:
@@ -685,7 +693,6 @@ class PlanApplier:
         snapshot, and the plan gets exactly one clean re-verify. Returns
         (group, opt) — opt is replaced when a resync happened."""
         group: List[Tuple[PendingPlan, PlanResult]] = []
-        tv0 = time.perf_counter()
         queue = list(batch)
         i = 0
         while i < len(queue):
@@ -726,7 +733,6 @@ class PlanApplier:
                 continue
             opt.apply_result(result)
             group.append((pending, result))
-        self.stats["t_verify_ms"] += (time.perf_counter() - tv0) * 1e3
         return group, opt
 
     def _verify(self, pending: PendingPlan, opt: OptimisticSnapshot,
@@ -765,7 +771,8 @@ class PlanApplier:
             with trace.resume(trace.linked("eval", plan.EvalID),
                               "plan.evaluate", eval=plan.EvalID,
                               overlapped=overlapped):
-                with metrics.measure(("nomad", "plan", "evaluate")):
+                with metrics.measure(("nomad", "plan", "evaluate"),
+                                     cpu=True):
                     result = evaluate_plan(opt, plan, self._pool,
                                            nt=self._nt())
         # lint: allow(swallow, error is delivered to the plan's waiter)
@@ -791,9 +798,8 @@ class PlanApplier:
                  for pending, _ in group]
         primary = next((s for s in spans if s is not None), None)
         try:
-            ta0 = time.perf_counter()
             with (primary if primary is not None else trace.attach(None)):
-                with metrics.measure(("nomad", "plan", "apply")):
+                with metrics.measure(("nomad", "plan", "apply"), cpu=True):
                     if len(group) == 1:
                         pending, result = group[0]
                         index = self._apply(pending.plan, result)
@@ -817,7 +823,6 @@ class PlanApplier:
                         index = self.raft.apply(msg, {
                             "Batch": [e for e, _ in encoded],
                         })
-            self.stats["t_apply_ms"] += (time.perf_counter() - ta0) * 1e3
             for span in spans:
                 if span is not None:
                     span.finish()

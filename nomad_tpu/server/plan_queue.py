@@ -16,6 +16,7 @@ from typing import List, Optional, Tuple
 
 from nomad_tpu.analysis import guarded_by
 from nomad_tpu.structs import Plan, PlanResult
+from nomad_tpu.telemetry import metrics
 
 
 class PendingPlan:
@@ -26,14 +27,23 @@ class PendingPlan:
         # Made inside enqueue/enqueue_all: the applier samples
         # nomad.plan.queue_wait from this when it takes the plan up.
         self.enqueued = time.monotonic()
+        # Made by respond, just before it sets the event: a waiter that
+        # was blocked samples nomad.plan.wake from it when it runs again.
+        self.responded = 0.0
         self._event = threading.Event()
         self._result: Optional[PlanResult] = None
         self._error: Optional[Exception] = None
         self.cancelled = False
 
     def wait(self, timeout: Optional[float] = None) -> PlanResult:
-        if not self._event.wait(timeout):
-            raise TimeoutError("plan response timeout")
+        if not self._event.is_set():
+            # Blocked: from the applier's set() to this thread holding the
+            # interpreter again is the hand-over the waiter pays on top of
+            # the apply. A waiter that comes late finds the event set and
+            # measures nothing.
+            if not self._event.wait(timeout):
+                raise TimeoutError("plan response timeout")
+            metrics.measure_since(("nomad", "plan", "wake"), self.responded)
         if self._error is not None:
             raise self._error
         return self._result
@@ -42,6 +52,7 @@ class PendingPlan:
                 error: Optional[Exception]) -> None:
         self._result = result
         self._error = error
+        self.responded = time.monotonic()
         self._event.set()
 
     def cancel(self) -> None:

@@ -13,6 +13,8 @@ rendered dotted ("nomad.fsm.apply") for sinks and the HTTP endpoint.
 
 from __future__ import annotations
 
+import collections
+import gc
 import logging
 import socket
 import sys
@@ -53,31 +55,45 @@ class Measure:
     `attrs`: inert while no profiler session runs, and on the same
     nanosecond clock as the device's program events while one does, so a
     trace shows host stages and device programs together. After the block
-    `ms` holds the sample."""
+    `ms` holds the sample. With `cpu` the block's thread CPU
+    (time.thread_time: what the block cost, where `ms` is how long it
+    stood open; under one interpreter lock the two differ by what the
+    thread waited for) is taken too, kept as `cpu_ms` and sampled as
+    `<key>.cpu`. Only then: the thread's CPU clock is a system call each
+    read, where the wall clock is not."""
 
-    __slots__ = ("_registry", "_key", "_attrs", "_span", "_start", "ms")
+    __slots__ = ("_registry", "_key", "_attrs", "_cpu", "_span", "_start",
+                 "_cpu_start", "ms", "cpu_ms")
 
-    def __init__(self, registry: "MetricsRegistry", key: Key,
-                 attrs: Dict[str, Any]) -> None:
+    def __init__(self, registry: Any, key: Key, attrs: Dict[str, Any],
+                 cpu: bool = False) -> None:
         self._registry = registry
         self._key = tuple(key)
         self._attrs = attrs
+        self._cpu = cpu
         self._span = None
         self.ms = 0.0
+        self.cpu_ms = 0.0
 
     def __enter__(self) -> "Measure":
         annotation = _trace_annotation()
         if annotation is not None:
             self._span = annotation(_name(self._key), **self._attrs)
             self._span.__enter__()
+        if self._cpu:
+            self._cpu_start = time.thread_time()
         self._start = time.monotonic()
         return self
 
     def __exit__(self, *exc) -> bool:
         self.ms = (time.monotonic() - self._start) * 1000.0
+        if self._cpu:
+            self.cpu_ms = (time.thread_time() - self._cpu_start) * 1000.0
         if self._span is not None:
             self._span.__exit__(*exc)
         self._registry.add_sample(self._key, self.ms)
+        if self._cpu:
+            self._registry.add_sample(self._key + ("cpu",), self.cpu_ms)
         return False
 
 
@@ -299,9 +315,15 @@ class MetricsRegistry:
         """`start` is a time.monotonic() stamp; records milliseconds."""
         self.add_sample(tuple(key), (time.monotonic() - start) * 1000.0)
 
-    def measure(self, key: Key, **attrs) -> Measure:
-        """Context manager: sample + profiler span (see Measure)."""
-        return Measure(self, key, attrs)
+    def measure(self, key: Key, cpu: bool = False, **attrs) -> Measure:
+        """Context manager: sample + profiler span (see Measure). `cpu`
+        is an argument of this call and no span attribute: with it the
+        block's thread CPU is sampled as `<key>.cpu` beside the wall
+        sample. It costs two system calls and a second sample (a lock and
+        a fan-out; a datagram, for a statsd sink), so only the sites that
+        are read through the sinks alone pass it; PipelinedWorker._stage
+        keeps the CPU of its eight outer stages in `stats` itself."""
+        return Measure(self, key, attrs, cpu)
 
     def snapshot(self) -> Dict[str, Any]:
         return self.inmem.snapshot()
@@ -319,3 +341,128 @@ measure_since = registry.measure_since
 measure = registry.measure
 snapshot = registry.snapshot
 configure = registry.configure
+
+
+# ------------------------------------------------------------ the runtime
+# What the process itself does to every stage at once (reference: go-metrics
+# emits runtime.* gauges and runtime.gc_pause_ns samples from inside the
+# process, metrics.go:24-61): stalls of the whole interpreter, the CPU the
+# process gets, and the collector's pauses.
+TICK_S = 0.05    # the ticker's sleep: a stall longer than this shows
+FLUSH_S = 1.0    # one tick_late and one cpu_share sample a second, not twenty
+_RUNTIME = ("nomad", "runtime")
+
+
+class RuntimeCollector:
+    """One for the process, whatever the number of agents in it: a daemon
+    ticker and one gc.callbacks entry, started by the first acquire() and
+    stopped by the last release().
+
+    The ticker sleeps TICK_S and notes how long after its due time it ran:
+    a thread that needs the interpreter to wake runs late by as long as
+    the interpreter was kept from it (a full collection, a compile or a C
+    call that holds it, the host descheduled). Once every FLUSH_S it
+    samples the largest lateness as nomad.runtime.tick_late (ms), the
+    process's CPU over the wall as nomad.runtime.cpu_share (%, native
+    threads included: it may pass 100), sets the gauge
+    nomad.runtime.threads and adds the young collections since to the
+    counters nomad.runtime.gc_runs.gen0 / .gen1.
+
+    The callback adds one to a count for a collection of generation 0 or
+    1, and makes no registry call: those run hundreds of times a second. A
+    generation-2 collection is a Measure opened at `start` and closed at
+    `stop`: a span on the profiler's timeline, on the thread that
+    triggered it, and a nomad.runtime.gc sample in ms. The sample reaches
+    the registry through the ticker (this object stands where the
+    registry does for that Measure and keeps the sample until the next
+    tick): a collection starts wherever an object is allocated, also
+    inside a sink's add_sample with the sink's plain lock held, and a
+    registry call from the callback would then wait for its own thread."""
+
+    def __init__(self, registry: MetricsRegistry) -> None:
+        self._registry = registry
+        self._lock = threading.Lock()  # acquire/release only
+        self._users = 0
+        self._stop: Optional[threading.Event] = None
+        self._thread: Optional[threading.Thread] = None
+        # Collections run one at a time in a process, so the callback is
+        # the only writer of these two; the ticker reads them.
+        self._young = [0, 0]
+        self._full: Optional[Measure] = None
+        self._closed: "collections.deque[Tuple[Key, float]]" = \
+            collections.deque()
+
+    def acquire(self) -> None:
+        with self._lock:
+            self._users += 1
+            if self._users > 1:
+                return
+            self._stop = threading.Event()
+            self._thread = threading.Thread(
+                target=self._tick, args=(self._stop,), daemon=True,
+                name="runtime-metrics")
+            gc.callbacks.append(self._on_gc)
+            self._thread.start()
+
+    def release(self) -> None:
+        with self._lock:
+            if self._users == 0:
+                return
+            self._users -= 1
+            if self._users:
+                return
+            gc.callbacks.remove(self._on_gc)
+            self._stop.set()
+            thread, self._thread = self._thread, None
+        thread.join(timeout=5.0)
+
+    def add_sample(self, key: Key, value: float) -> None:
+        """Where the Measure of a full collection samples: kept, and handed
+        to the registry by the ticker."""
+        self._closed.append((key, value))
+
+    def _on_gc(self, phase: str, info: Dict[str, int]) -> None:
+        generation = info["generation"]
+        if generation < 2:
+            if phase == "stop":
+                self._young[generation] += 1
+        elif phase == "start":
+            self._full = Measure(self, _RUNTIME + ("gc",),
+                                 {"generation": generation})
+            self._full.__enter__()
+        elif self._full is not None:
+            full, self._full = self._full, None
+            full.__exit__(None, None, None)
+
+    def _tick(self, stop: threading.Event) -> None:
+        registry = self._registry
+        flushed = list(self._young)  # an earlier ticker's are out already
+        late = 0.0
+        wall0, cpu0 = time.monotonic(), time.process_time()
+        while True:
+            due = time.monotonic() + TICK_S
+            stopping = stop.wait(TICK_S)
+            now = time.monotonic()
+            late = max(late, now - due)
+            while self._closed:
+                registry.add_sample(*self._closed.popleft())
+            if stopping:
+                return
+            if now - wall0 < FLUSH_S:
+                continue
+            cpu = time.process_time()
+            registry.add_sample(_RUNTIME + ("tick_late",), late * 1e3)
+            registry.add_sample(_RUNTIME + ("cpu_share",),
+                                100.0 * (cpu - cpu0) / (now - wall0))
+            registry.set_gauge(_RUNTIME + ("threads",),
+                               threading.active_count())
+            for generation, total in enumerate(self._young):
+                if total > flushed[generation]:
+                    registry.incr_counter(
+                        _RUNTIME + ("gc_runs", f"gen{generation}"),
+                        total - flushed[generation])
+                    flushed[generation] = total
+            late, wall0, cpu0 = 0.0, now, cpu
+
+
+runtime = RuntimeCollector(registry)

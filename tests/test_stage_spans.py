@@ -10,6 +10,7 @@ import os
 import re
 import subprocess
 import sys
+import threading
 import time
 
 import jax
@@ -19,7 +20,7 @@ import pytest
 from nomad_tpu import mock
 from nomad_tpu.scheduler import kernels
 from nomad_tpu.server import Server, ServerConfig, pipelined_worker
-from nomad_tpu.server.pipelined_worker import (STATS_COUNTERS,
+from nomad_tpu.server.pipelined_worker import (CPU_STAGES, STATS_COUNTERS,
                                                STATS_TIMERS_MS,
                                                PipelinedWorker, new_stats)
 from nomad_tpu.structs.structs import EvalStatusComplete
@@ -153,10 +154,94 @@ def test_without_jax_measure_is_a_plain_timer_and_imports_nothing():
     assert proc.stdout.strip() == "plain"
 
 
+def _spin(seconds):
+    end = time.perf_counter() + seconds
+    while time.perf_counter() < end:
+        pass
+
+
+@pytest.mark.parametrize("block,busy", [(lambda: _spin(0.02), True),
+                                        (lambda: time.sleep(0.02), False)],
+                         ids=["a-busy-loop", "a-sleep"])
+def test_measure_keeps_the_threads_cpu_beside_the_wall(samples, block, busy):
+    """With cpu=True, cpu_ms is this thread's CPU inside the block: all of
+    a busy loop's wall (never more than it, but for the two clocks' reads)
+    and next to nothing of a sleep's."""
+    with metrics.measure(("nomad", "x", "cpu"), cpu=True) as timed:
+        block()
+    assert timed.ms >= 20.0
+    assert 0.0 <= timed.cpu_ms <= timed.ms + 1.0
+    if busy:
+        # A loaded machine may take the core away for part of it.
+        assert timed.cpu_ms >= 2.0
+    else:
+        assert timed.cpu_ms < 5.0
+
+
+def test_by_default_measure_reads_no_cpu_clock_and_samples_the_wall_alone(
+        samples, monkeypatch):
+    """The thread's CPU clock is a system call a read (on the chip's host
+    two a block cost svc-10k.storm 8 %: PERF.md section 6, PR 39): only a
+    caller that keeps the CPU pays for it."""
+    def no_cpu_clock():
+        raise AssertionError("thread_time read by a default measure")
+
+    monkeypatch.setattr(time, "thread_time", no_cpu_clock)
+    with metrics.measure(("nomad", "x", "wall")) as timed:
+        pass
+    assert timed.cpu_ms == 0.0
+    assert [n for n, _ in samples.rows] == ["nomad.x.wall"]
+
+
+def test_cpu_true_samples_the_cpu_beside_the_wall_sample(spans, samples):
+    with metrics.measure(("nomad", "plan", "apply"), cpu=True,
+                         batch=3) as timed:
+        _spin(0.002)
+    assert samples.rows == [("nomad.plan.apply", timed.ms),
+                            ("nomad.plan.apply.cpu", timed.cpu_ms)]
+    # An argument of the call, never an attribute of the span.
+    assert spans.opened == [("nomad.plan.apply", {"batch": 3})]
+
+
+def _calls(source, opener):
+    """The argument text of every `opener(` call in `source`, by matching
+    parentheses."""
+    out = []
+    for m in re.finditer(re.escape(opener) + r"\(", source):
+        depth, i = 1, m.end()
+        while depth:
+            depth += {"(": 1, ")": -1}.get(source[i], 0)
+            i += 1
+        out.append(source[m.end():i - 1])
+    return out
+
+
+def test_cpu_is_passed_at_three_sites_and_is_no_span_attribute():
+    """`cpu=` is taken out of measure's **attrs by name, so no span may
+    carry an attribute called cpu: none does, and the three sites that
+    pass it are those read through the sinks alone (ISSUE 39)."""
+    passed = {}
+    for path in glob.glob(os.path.join(ROOT, "nomad_tpu", "**", "*.py"),
+                          recursive=True):
+        with open(path) as f:
+            source = f.read()
+        for opener in ("metrics.measure", "self._stage"):
+            for args in _calls(source, opener):
+                if re.search(r"\bcpu\s*=", args):
+                    key = ".".join(re.findall(r'"([a-z_]+)"', args))
+                    passed[key] = (opener, re.search(
+                        r"\bcpu\s*=\s*(\w+)", args).group(1))
+    assert passed == {
+        "nomad.plan.evaluate": ("metrics.measure", "True"),
+        "nomad.plan.apply": ("metrics.measure", "True"),
+        "nomad.sched.system.sweep": ("metrics.measure", "True")}
+
+
 # ------------------------------------------------------------ worker stages
 with open(pipelined_worker.__file__) as _f:
     WORKER_SOURCE = _f.read()
-STAGES = sorted(set(re.findall(r'_stage\(\s*"([a-z_]+)"', WORKER_SOURCE)))
+STAGES = sorted(set(re.findall(r'self\.(?:_stage|_hand_off)\(\s*"([a-z_]+)"',
+                               WORKER_SOURCE)))
 
 
 def _served():
@@ -183,7 +268,10 @@ def test_the_worker_times_every_stage_the_issue_lists():
     assert set(STAGES) == {"lease", "fill", "dispatch", "refresh", "nodectx",
                            "launch", "drain_stack", "drain", "drain_fetch",
                            "build", "collect", "netassign", "planwait",
-                           "evalupd", "slow"}
+                           "evalupd", "slow",
+                           # ISSUE 39: the offers at the two seams and the
+                           # chain-order barrier inside the plan wait.
+                           "handoff_drain", "handoff_build", "turnwait"}
     # The per-eval timers of _try_dispatch_fast are all that is left of
     # the hand-written pairs: they add to `stats` alone, by design.
     assert WORKER_SOURCE.count("perf_counter()") == 5
@@ -196,11 +284,20 @@ def test_a_stage_is_a_stats_key_a_sample_and_a_span(served, spans, samples,
     key = f"t_{stage}_ms"
     assert key in STATS_TIMERS_MS
     before = worker.stats[key]
+    cpu_before = worker.stats.get(f"t_{stage}_cpu_ms")
     with worker._stage(stage, 12):
         time.sleep(0.001)
     [sample] = samples.of(f"nomad.worker.{stage}")
     assert sample >= 1.0
     assert worker.stats[key] - before == pytest.approx(sample)
+    # The thread's CPU beside it, for the eight stages that partition
+    # their thread's time and for no stage nested in them; never a second
+    # registry sample.
+    cpu_key = f"t_{stage}_cpu_ms"
+    assert (cpu_key in worker.stats) is (stage in CPU_STAGES)
+    if stage in CPU_STAGES:
+        assert 0.0 <= worker.stats[cpu_key] - cpu_before <= sample + 1.0
+    assert samples.of(f"nomad.worker.{stage}.cpu") == []
     assert spans.opened == [(f"nomad.worker.{stage}",
                              {"worker": "w-test", "window": 12})]
     assert spans.closed == [f"nomad.worker.{stage}"]
@@ -224,10 +321,10 @@ def test_the_spans_of_one_window_share_its_number(served, spans, samples):
     batch = [first]
     work = worker._dispatch_window(batch, fill=True)
     assert len(batch) == 3 and len(work.fast) == 3  # filled in place
-    worker._hand_off(worker._drain_q, work)
+    worker._hand_off("handoff_drain", worker._drain_q, work)
     assert worker._drain_q.get() is work
     time.sleep(0.002)
-    worker._enter_stage(work)
+    worker._enter_stage("t_wait_drain_ms", work)
     with worker._stage("drain", work.number):
         work.packed = worker._drain_window(work)
     worker._finish_fast(work)
@@ -307,6 +404,164 @@ def _settle(worker, work):
     worker._finish_fast(work)
     worker._arbiter.mark_settled(work.chain_seq)
     worker._arbiter.finish_window()
+
+
+# --------------------------------------------------- the seams and the waits
+@pytest.mark.parametrize("seam", ["drain", "build"])
+def test_a_blocked_hand_off_is_a_stage_of_the_givers_thread(
+        served_alone, spans, samples, seam):
+    """A seam holds one window. With its slot taken the giver's put
+    blocks: that is the span nomad.worker.handoff_<seam> on the giver's
+    thread and stats["t_handoff_<seam>_ms"]; the taker counts the same
+    time again, from the stamp to its take, under its seam's key."""
+    _, worker = served_alone
+    q = worker._drain_q if seam == "drain" else worker._build_q
+    other = "build" if seam == "drain" else "drain"
+    q.put(pipelined_worker._WindowWork(fast=[], slow=[], number=6))
+    work = pipelined_worker._WindowWork(fast=[], slow=[], number=7)
+    opened_on = []
+    real = spans.__call__
+
+    def on_thread(name, **attrs):
+        opened_on.append((name, threading.current_thread().name))
+        return real(name, **attrs)
+
+    metrics._annotation = on_thread  # the fixture restores it
+    giver = threading.Thread(
+        target=worker._hand_off, args=(f"handoff_{seam}", q, work),
+        name="the-giver")
+    giver.start()
+    time.sleep(0.05)
+    assert giver.is_alive()  # blocked in put
+    assert spans.closed == []
+    assert q.get(timeout=5).number == 6  # the test is the taker
+    giver.join(timeout=5)
+    assert not giver.is_alive()
+    assert q.get(timeout=5) is work
+    worker._enter_stage(f"t_wait_{seam}_ms", work)
+    assert opened_on == [(f"nomad.worker.handoff_{seam}", "the-giver")]
+    assert spans.opened == [(f"nomad.worker.handoff_{seam}",
+                             {"worker": "w-test", "window": 7})]
+    [blocked] = samples.of(f"nomad.worker.handoff_{seam}")
+    stats = worker.stats
+    assert blocked >= 45.0
+    assert stats[f"t_handoff_{seam}_ms"] == pytest.approx(blocked)
+    # The taker's wait holds the blocked put and the time in the slot.
+    assert stats[f"t_wait_{seam}_ms"] >= blocked
+    assert stats[f"t_handoff_{other}_ms"] == 0.0
+    assert stats[f"t_wait_{other}_ms"] == 0.0
+    assert stats["t_stagewait_ms"] == stats[f"t_wait_{seam}_ms"]
+
+
+def test_the_two_seams_sum_to_the_stage_wait_after_a_served_storm():
+    """The workers as a server runs them, three threads each: every window
+    crossed both seams, and t_stagewait_ms (what stage_wait_ms.* reads) is
+    the two seams' sum, exactly."""
+    srv = Server(ServerConfig(num_schedulers=2, pipelined_scheduling=True,
+                              scheduler_window=4))
+    srv.establish_leadership()
+    try:
+        for _ in range(4):
+            srv.node_register(mock.node())
+        evals = [srv.job_register(_plain_job())[0] for _ in range(24)]
+        assert wait_for(lambda: all(
+            (e := srv.state.eval_by_id(i)) is not None
+            and e.Status == EvalStatusComplete for i in evals), timeout=90)
+        workers = list(srv.workers)
+    finally:
+        srv.shutdown()
+    assert sum(w.stats["windows"] for w in workers) >= 2
+    for w in workers:
+        stats = w.stats
+        assert stats["t_stagewait_ms"] \
+            == stats["t_wait_drain_ms"] + stats["t_wait_build_ms"]
+        if stats["windows"]:
+            assert stats["t_wait_drain_ms"] > 0.0
+            assert stats["t_wait_build_ms"] > 0.0
+        # A blocked put lies inside its taker's wait.
+        assert stats["t_handoff_drain_ms"] <= stats["t_wait_drain_ms"]
+        assert stats["t_handoff_build_ms"] <= stats["t_wait_build_ms"]
+        # What a stage cost is no more than how long it stood open.
+        for stage in CPU_STAGES:
+            assert 0.0 <= stats[f"t_{stage}_cpu_ms"] \
+                <= stats[f"t_{stage}_ms"] + 1.0 * max(1, stats["windows"])
+
+
+def test_the_turn_wait_nests_in_the_plan_wait_with_its_window(served, spans):
+    srv, worker = served
+    before = dict(worker.stats)
+    work = _window_of(srv, worker, [_plain_job()])
+    _settle(worker, work)
+    mine = [(n, a) for n, a in spans.opened if a.get("worker") == "w-test"]
+    names = [n for n, _ in mine]
+    closed = [n for n, who in spans.closed_by if who == "w-test"]
+    assert [a for n, a in mine if n == "nomad.worker.turnwait"] \
+        == [{"worker": "w-test", "window": work.number}]
+    assert names.index("nomad.worker.planwait") \
+        < names.index("nomad.worker.turnwait")
+    assert closed.index("nomad.worker.turnwait") \
+        < closed.index("nomad.worker.planwait")
+    turn = worker.stats["t_turnwait_ms"] - before["t_turnwait_ms"]
+    assert 0.0 <= turn <= worker.stats["t_planwait_ms"] \
+        - before["t_planwait_ms"]
+
+
+@pytest.mark.parametrize("waiter", ["blocked", "late"])
+def test_the_wake_is_sampled_by_a_waiter_that_blocked_and_by_no_other(
+        samples, waiter):
+    from nomad_tpu.server.plan_queue import PendingPlan
+
+    pending = PendingPlan(mock.plan())
+    if waiter == "late":
+        pending.respond(None, None)
+        time.sleep(0.01)
+        pending.wait(timeout=5)
+        assert samples.of("nomad.plan.wake") == []
+        return
+    responder = threading.Timer(0.05, pending.respond, (None, None))
+    responder.start()
+    t0 = time.monotonic()
+    pending.wait(timeout=5)
+    waited = (time.monotonic() - t0) * 1e3
+    responder.join(timeout=5)
+    [wake] = samples.of("nomad.plan.wake")
+    # From respond's stamp, not from the wait's start.
+    assert 0.0 <= wake < waited - 30.0
+    assert pending.responded >= t0 + 0.04
+
+
+def test_a_plan_that_times_out_samples_no_wake(samples):
+    from nomad_tpu.server.plan_queue import PendingPlan
+
+    with pytest.raises(TimeoutError):
+        PendingPlan(mock.plan()).wait(timeout=0.01)
+    assert samples.of("nomad.plan.wake") == []
+
+
+def test_the_applier_samples_one_join_a_group_behind_a_commit(dev_server,
+                                                              samples):
+    """Three plans one after the other are three groups of one: the first
+    has no commit before it, each of the other two joins its
+    predecessor's apply thread once (already ended here: ~0)."""
+    for _ in range(3):
+        _run_job(dev_server, _plain_job())
+    groups = len(samples.of("nomad.plan.apply"))
+    assert groups >= 3
+    joins = samples.of("nomad.plan.join")
+    assert len(joins) == groups - 1
+    assert all(0.0 <= j < 5000.0 for j in joins)
+    # The verify and the apply carry their thread CPU (cpu=True).
+    assert len(samples.of("nomad.plan.apply.cpu")) == groups
+    assert len(samples.of("nomad.plan.evaluate.cpu")) \
+        == len(samples.of("nomad.plan.evaluate"))
+
+
+def test_the_applier_keeps_no_timer_of_its_own(dev_server):
+    """PlanApplier.stats["t_verify_ms"] / ["t_apply_ms"] went with ISSUE
+    39: nothing read them, and the blocks they timed are the registry's
+    nomad.plan.evaluate and nomad.plan.apply."""
+    assert set(dev_server.plan_applier.stats) == {
+        "applied", "rejected", "overlapped", "apply_failed"}
 
 
 def test_a_columns_only_window_still_opens_one_collect_span(served, spans):

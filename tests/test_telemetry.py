@@ -235,3 +235,229 @@ class TestSchedulingCycleMetrics:
             assert "nomad.heartbeat.active" in gauges
         finally:
             srv.shutdown()
+
+
+# ------------------------------------------------- the runtime (ISSUE 39)
+import gc  # noqa: E402
+import glob  # noqa: E402
+import os  # noqa: E402
+import subprocess  # noqa: E402
+import sys  # noqa: E402
+import threading  # noqa: E402
+
+from nomad_tpu.telemetry import metrics  # noqa: E402
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+TICKER = "runtime-metrics"
+
+
+class _Rows:
+    """A registry sink that keeps every call with the thread it came on."""
+
+    def __init__(self):
+        self.rows = []
+
+    def _keep(self, kind, key, value):
+        self.rows.append((kind, ".".join(key), value,
+                          threading.current_thread().name))
+
+    def add_sample(self, key, value):
+        self._keep("sample", key, value)
+
+    def set_gauge(self, key, value):
+        self._keep("gauge", key, value)
+
+    def incr_counter(self, key, value):
+        self._keep("counter", key, value)
+
+    def of(self, name):
+        return [(v, t) for _, n, v, t in self.rows if n == name]
+
+
+def _tickers():
+    return [t for t in threading.enumerate() if t.name == TICKER]
+
+
+def _callbacks():
+    return [c for c in gc.callbacks
+            if getattr(c, "__self__", None) is metrics.runtime]
+
+
+@pytest.fixture()
+def runtime_rows():
+    """The process's one collector, held for the test whatever agents
+    other tests left running, with the automatic collector off so that
+    the test's own gc.collect calls are all there is."""
+    sink = _Rows()
+    metrics.registry.add_sink(sink)
+    was_enabled = gc.isenabled()
+    gc.disable()
+    metrics.runtime.acquire()
+    try:
+        yield sink
+    finally:
+        metrics.runtime.release()
+        if was_enabled:
+            gc.enable()
+        with metrics.registry._lock:
+            metrics.registry._sinks = [s for s in metrics.registry._sinks
+                                       if s is not sink]
+
+
+class TestRuntimeCollector:
+    def test_many_agents_share_one_and_the_last_stops_it(self):
+        from nomad_tpu.agent import Agent
+        from nomad_tpu.agent.agent import AgentConfig
+
+        held = len(_tickers())  # 1 if an earlier test left an agent up
+        assert held == len(_callbacks()) <= 1
+        agents = [Agent(AgentConfig(server_enabled=False,
+                                    client_enabled=False, http_port=0,
+                                    bind_addr="127.0.0.1"))
+                  for _ in range(5)]
+        try:
+            for agent in agents:
+                agent.start()
+                assert len(_tickers()) == len(_callbacks()) == 1
+            for agent in agents[:-1]:
+                agent.shutdown()
+                agent.shutdown()  # a second shutdown gives nothing back
+            assert len(_tickers()) == len(_callbacks()) == 1
+        finally:
+            for agent in agents:
+                agent.shutdown()
+        assert len(_tickers()) == len(_callbacks()) == held
+
+    def test_fifty_holders_are_one_thread_and_one_callback(self):
+        mine = metrics.RuntimeCollector(MetricsRegistry())
+        for _ in range(50):
+            mine.acquire()
+        try:
+            assert [c for c in gc.callbacks
+                    if getattr(c, "__self__", None) is mine] == [mine._on_gc]
+            assert mine._thread.is_alive()
+            thread = mine._thread
+            for _ in range(49):
+                mine.release()
+            assert thread.is_alive() and mine._on_gc in gc.callbacks
+        finally:
+            mine.release()
+        assert not thread.is_alive() and mine._on_gc not in gc.callbacks
+        mine.release()  # one too many: nothing to give back, no error
+
+    def test_a_restarted_collector_counts_from_where_it_stands(self):
+        """Stopped with the last agent and started again with the next:
+        the young collections the first ticker handed over are not handed
+        over again."""
+        sink = _Rows()
+        registry = MetricsRegistry()
+        registry.add_sink(sink)
+        mine = metrics.RuntimeCollector(registry)
+        mine._young[:] = [40, 4]  # what an earlier run of it had counted
+        mine.acquire()
+        try:
+            gc.collect(0)
+            assert wait_for(
+                lambda: sink.of("nomad.runtime.gc_runs.gen0"), timeout=5,
+                interval=0.02)
+        finally:
+            mine.release()
+        assert 1 <= sum(v for v, _ in sink.of(
+            "nomad.runtime.gc_runs.gen0")) < 40
+
+    def test_a_full_collection_is_one_sample_and_one_span(self, runtime_rows,
+                                                          tmp_path):
+        import jax
+
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        options.host_tracer_level = 1
+        jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+        try:
+            gc.collect()
+        finally:
+            jax.profiler.stop_trace()
+        assert wait_for(lambda: runtime_rows.of("nomad.runtime.gc"),
+                        timeout=5, interval=0.01)
+        [(ms, thread)] = runtime_rows.of("nomad.runtime.gc")
+        assert 0.0 < ms < 5000.0
+        # Kept by the callback, handed to the registry by the ticker: a
+        # collection can start under a sink's own lock.
+        assert thread == TICKER
+        [path] = glob.glob(str(tmp_path / "plugins" / "profile" / "*"
+                               / "*.xplane.pb"))
+        found = [(e.duration_ns, dict(e.stats))
+                 for plane in jax.profiler.ProfileData.from_file(path).planes
+                 if plane.name.startswith("/host:")
+                 for line in plane.lines for e in line.events
+                 if e.name == "nomad.runtime.gc"]
+        assert len(found) == 1
+        duration_ns, stats = found[0]
+        assert duration_ns == pytest.approx(ms * 1e6, rel=0.5, abs=2e5)
+        assert int(stats["generation"]) == 2
+
+    def test_young_collections_are_counted_and_never_a_call_of_their_own(
+            self, runtime_rows):
+        me = threading.current_thread().name
+        for _ in range(7):
+            gc.collect(0)
+        for _ in range(3):
+            gc.collect(1)
+        # Nothing reached the registry from the callback (this thread).
+        assert [r for r in runtime_rows.rows
+                if r[3] == me and r[1].startswith("nomad.runtime.")] == []
+        assert metrics.runtime._full is None
+        assert wait_for(lambda: runtime_rows.of("nomad.runtime.gc_runs.gen1"),
+                        timeout=5, interval=0.02)
+        assert sum(v for v, _ in runtime_rows.of(
+            "nomad.runtime.gc_runs.gen0")) == 7
+        assert runtime_rows.of("nomad.runtime.gc_runs.gen1") \
+            == [(3.0, TICKER)]
+        assert runtime_rows.of("nomad.runtime.gc") == []
+        # With them, once a second, what the process got and how many
+        # threads it runs.
+        [(share, _)] = runtime_rows.of("nomad.runtime.cpu_share")[:1]
+        assert 0.0 <= share < 100.0 * (os.cpu_count() or 1) + 100.0
+        assert runtime_rows.of("nomad.runtime.threads")[0][0] \
+            >= len(_tickers()) + 1
+
+    def test_a_thread_that_keeps_the_interpreter_shows_as_a_late_tick(
+            self, runtime_rows):
+        """200 ms of bytecode with the switch interval raised: the ticker,
+        like every other thread, cannot run until the loop ends."""
+        interval = sys.getswitchinterval()
+        sys.setswitchinterval(2.0)
+        try:
+            end = time.perf_counter() + 0.2
+            while time.perf_counter() < end:
+                pass
+        finally:
+            sys.setswitchinterval(interval)
+        assert wait_for(
+            lambda: [v for v, _ in runtime_rows.of("nomad.runtime.tick_late")
+                     if v >= 100.0], timeout=4, interval=0.02)
+        late = max(v for v, _ in runtime_rows.of("nomad.runtime.tick_late"))
+        assert 100.0 <= late < 2000.0
+
+    def test_without_jax_the_collector_imports_nothing(self):
+        code = (
+            "import gc, sys, time\n"
+            "from nomad_tpu.telemetry import metrics\n"
+            "metrics.runtime.acquire()\n"
+            "gc.collect()\n"
+            "deadline = time.monotonic() + 5\n"
+            "seen = []\n"
+            "while time.monotonic() < deadline and not seen:\n"
+            "    time.sleep(0.02)\n"
+            "    seen = [s for s in metrics.snapshot()['Samples']\n"
+            "            if s['Name'] == 'nomad.runtime.gc']\n"
+            "metrics.runtime.release()\n"
+            "assert seen and seen[0]['Count'] >= 1, seen\n"
+            "assert 'jax' not in sys.modules, 'the collector imported jax'\n"
+            "assert not [t for t in __import__('threading').enumerate()\n"
+            "            if t.name == 'runtime-metrics']\n"
+            "print('plain')\n")
+        proc = subprocess.run([sys.executable, "-c", code], cwd=ROOT,
+                              capture_output=True, text=True, timeout=120)
+        assert proc.returncode == 0, proc.stderr[-2000:]
+        assert proc.stdout.strip() == "plain"

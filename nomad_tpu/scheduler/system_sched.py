@@ -16,10 +16,13 @@ import logging
 import random
 from typing import Dict, List, Optional
 
+import numpy as np
+
 from nomad_tpu.telemetry import metrics
 from nomad_tpu.structs import (
     Allocation,
     AllocMetric,
+    ColumnarPlacements,
     Evaluation,
     Job,
     Plan,
@@ -35,6 +38,8 @@ from nomad_tpu.structs.structs import (
     EvalTriggerJobDeregister,
     EvalTriggerJobRegister,
     EvalTriggerNodeUpdate,
+    columns_only,
+    placed_count,
 )
 from nomad_tpu.tensor import TensorIndex, alloc_vec
 
@@ -146,6 +151,13 @@ class SystemScheduler:
 
         result, new_state = self._submit_chunked(self.plan)
         self.plan_result = result
+        if use_sweep:
+            # Did any reader on the way need the placements as objects?
+            columnar = columns_only(self.plan.NodeAllocation) and (
+                result is None or columns_only(result.NodeAllocation))
+            metrics.incr_counter(("nomad", "sched", "system",
+                                  "plans_columnar" if columnar
+                                  else "plans_objects"))
         if new_state is not None:
             self.state = new_state
             if self.tindex is not None and not self.tindex.attached:
@@ -174,39 +186,33 @@ class SystemScheduler:
         sweeps take the ordinary path — as do AllAtOnce plans, whose
         all-or-nothing contract the applier enforces per plan and which
         chunking would silently weaken to per-chunk."""
-        n_allocs = sum(len(v) for v in plan.NodeAllocation.values())
+        n_allocs = placed_count(plan.NodeAllocation)
         depth_fn = getattr(self.planner, "plan_queue_depth", None)
         contended = depth_fn is not None and depth_fn() > 0
         if n_allocs <= SYSTEM_PLAN_CHUNK or not contended \
                 or plan.AllAtOnce:
             return self.planner.submit_plan(plan)
 
-        sweep = getattr(plan, "_sweep", None)
-        if (sweep is not None and not plan.NodeUpdate
-                and len(sweep.node_ids) == len(plan.NodeAllocation)):
-            # Columnar chunking: the sweep descriptor already lists every
-            # placed node in row order, so chunks slice it instead of
-            # re-walking the NodeAllocation dict — and each chunk carries
-            # its slice so the applier's one-vector-op verify survives
-            # chunking.
+        if columns_only(plan.NodeAllocation):
+            # Columnar chunking: the sweep descriptor lists every placed
+            # node in row order with its count, so chunks are slices of
+            # it, each with its own columns-only placements and the
+            # slice the applier's one-vector-op verify reads.
+            sweep = plan._sweep
+            starts = sweep.starts
             chunks = []
-            node_alloc = plan.NodeAllocation
-            ids = sweep.node_ids
-            i, total = 0, len(ids)
+            i, total = 0, len(sweep.node_ids)
             while i < total:
-                j, count = i, 0
-                while j < total and count < SYSTEM_PLAN_CHUNK:
-                    count += len(node_alloc[ids[j]])
-                    j += 1
+                j = min(int(np.searchsorted(
+                    starts, starts[i] + SYSTEM_PLAN_CHUNK)), total)
                 chunk = Plan(EvalID=plan.EvalID, Priority=plan.Priority,
                              Job=plan.Job, AllAtOnce=plan.AllAtOnce)
-                chunk.NodeAllocation = {nid: node_alloc[nid]
-                                        for nid in ids[i:j]}
                 chunk._sweep = sweep.slice(i, j)
+                chunk.NodeAllocation = ColumnarPlacements.over(chunk._sweep)
                 chunks.append(chunk)
                 i = j
             chunks[0].Annotations = plan.Annotations
-            return self._submit_chunks(chunks)
+            return self._submit_chunks(chunks, plan)
 
         chunks: List[Plan] = []
         current = None
@@ -236,11 +242,13 @@ class SystemScheduler:
                 current.NodeUpdate[node_id] = updates
                 count += len(updates)
         chunks[0].Annotations = plan.Annotations
-        return self._submit_chunks(chunks)
+        return self._submit_chunks(chunks, plan)
 
-    def _submit_chunks(self, chunks: List[Plan]):
+    def _submit_chunks(self, chunks: List[Plan], plan: Plan):
         """Submit a chunk sequence through the pipelined planner seam and
-        merge the per-chunk results."""
+        merge the per-chunk results. Chunks of a columns-only plan that
+        were all admitted as columns merge into the plan's own columns:
+        no placement is read as an object to be copied."""
         submit = getattr(self.planner, "submit_plans", None)
         if submit is not None:
             results, new_state = submit(chunks)
@@ -252,14 +260,19 @@ class SystemScheduler:
                 results.append(r)
                 new_state = ns or new_state
 
+        if None in results:
+            return None, new_state  # _process treats None as a retry
         merged = PlanResult()
         for r in results:
-            if r is None:
-                return None, new_state  # _process treats None as a retry
             merged.NodeUpdate.update(r.NodeUpdate)
-            merged.NodeAllocation.update(r.NodeAllocation)
             merged.RefreshIndex = max(merged.RefreshIndex, r.RefreshIndex)
             merged.AllocIndex = max(merged.AllocIndex, r.AllocIndex)
+        if columns_only(plan.NodeAllocation) and all(
+                columns_only(r.NodeAllocation) for r in results):
+            merged.NodeAllocation = plan.NodeAllocation.copy()
+        else:
+            for r in results:
+                merged.NodeAllocation.update(r.NodeAllocation)
         return merged, new_state
 
     def _ready_nodes(self, dcs) -> tuple:

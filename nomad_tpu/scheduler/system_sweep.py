@@ -50,8 +50,10 @@ from nomad_tpu.structs.structs import (
     AllocClientStatusPending,
     AllocDesiredStatusRun,
     AllocDesiredStatusStop,
+    ColumnarPlacements,
     JobTypeBatch,
-    generate_uuid,
+    generate_uuids,
+    stamp_alloc,
 )
 from nomad_tpu.telemetry import metrics
 from nomad_tpu.tensor import alloc_vec, resources_vec
@@ -321,16 +323,10 @@ def compute_job_allocs(sched) -> None:
 
     node_id_arr = nt.node_id_array()
     nodes_by_row = elig.nodes_by_row
-    sweep_rows: List[np.ndarray] = []
-    sweep_vecs: List[np.ndarray] = []
-    # Per-alloc descriptor columns, appended in lockstep with sweep_rows:
-    # the columnar commit path replicates (id, name, template-index) per
-    # alloc instead of the alloc objects.
-    alloc_ids_l: List[str] = []
-    alloc_names_l: List[str] = []
-    alloc_tg_l: List[int] = []
+    # One run per (instance name, rows it lands on), in emission order:
+    # job.TaskGroups order, then instance order within a group.
+    runs: List[tuple] = []  # (name, rows int64 ndarray, template index)
     sweep_templates: List[Allocation] = []
-    n_emitted = 0
 
     for tg_name, names in by_tg.items():
         tg = tg_obj[tg_name]
@@ -403,10 +399,9 @@ def compute_job_allocs(sched) -> None:
         if not placed_per_name:
             continue
 
-        # Bulk emit: one frozen task-resources template + one metric
-        # snapshot + one resource vector shared by every alloc of the TG
-        # (the shared_vec/shared_metric trick extended to the whole
-        # sweep; the value-frozen contract is alloc._resvec_cache's).
+        # One frozen task-resources template + one metric snapshot + one
+        # resource vector shared by every alloc of the TG (the
+        # value-frozen contract is alloc._resvec_cache's).
         tr_template: Dict[str, Resources] = {}
         shared_vec = np.zeros(RES_DIMS, dtype=np.float32)
         for task in tg.Tasks:
@@ -414,73 +409,63 @@ def compute_job_allocs(sched) -> None:
                  else Resources())
             tr_template[task.Name] = r
             shared_vec += resources_vec(r)
-        shared_metric = m.copy()
-        node_alloc = plan.NodeAllocation
-        # Template stamping: the dataclass constructor runs ~20 field
-        # assignments + default factories per call, which at 10k
-        # placements is a visible slice of the sweep. One fully-formed
-        # template per TG is cloned by __dict__ copy; only the per-alloc
-        # identity fields (ID, Name, NodeID) and the mutable per-alloc
-        # containers (Services/TaskStates — the client writes into
-        # those) are re-set per clone.
         template = Allocation(
             EvalID=sched.eval.ID,
             JobID=job.ID,
             TaskGroup=tg.Name,
-            Metrics=shared_metric,
+            Metrics=m.copy(),
             TaskResources=tr_template,
             DesiredStatus=AllocDesiredStatusRun,
             ClientStatus=AllocClientStatusPending,
         )
         template._resvec_cache = shared_vec
-        tmpl_dict = template.__dict__
         tpl_idx = len(sweep_templates)
         sweep_templates.append(template)
-        new = object.__new__
-        cls = Allocation
         for name, ok_rows in placed_per_name:
-            ids = node_id_arr[ok_rows]
-            kept: List[int] = []
-            for k, nid in enumerate(ids.tolist()):
-                if nid is None:
-                    continue  # row freed mid-sweep: exact path skips too
-                alloc = new(cls)
-                alloc.__dict__ = dict(tmpl_dict)
-                alloc.ID = generate_uuid()
-                alloc.Name = name
-                alloc.NodeID = nid
-                alloc.Services = {}
-                alloc.TaskStates = {}
-                bucket = node_alloc.get(nid)
-                if bucket is None:
-                    node_alloc[nid] = [alloc]
-                else:
-                    bucket.append(alloc)
-                kept.append(k)
-                alloc_ids_l.append(alloc.ID)
-                alloc_names_l.append(name)
-                alloc_tg_l.append(tpl_idx)
-            rows_kept = (ok_rows if len(kept) == len(ids)
-                         else ok_rows[kept])
-            if len(rows_kept):
-                n_emitted += len(rows_kept)
-                sweep_rows.append(rows_kept.astype(np.int64, copy=False))
-                sweep_vecs.append(
-                    np.broadcast_to(shared_vec,
-                                    (len(rows_kept), RES_DIMS)))
+            # A row freed mid-sweep has no node: the exact path skips it.
+            ok_rows = ok_rows[node_id_arr[ok_rows] != None]  # noqa: E711
+            if len(ok_rows):
+                runs.append((name, ok_rows.astype(np.int64, copy=False),
+                             tpl_idx))
                 # The next TG's fit sees this one's placements.
-                np.add.at(eff_delta, rows_kept,
-                          shared_vec.astype(np.float64))
+                np.add.at(eff_delta, ok_rows, shared_vec.astype(np.float64))
 
-    if n_emitted:
-        rows_all = np.concatenate(sweep_rows)
-        vecs_all = np.concatenate(sweep_vecs)
-        ur, inv = np.unique(rows_all, return_inverse=True)
-        delta = np.zeros((len(ur), RES_DIMS), dtype=np.float32)
-        np.add.at(delta, inv, vecs_all)
-        ids = node_id_arr[ur]
-        ids_list = ids.tolist()
-        emitted_per_row = np.bincount(inv, minlength=len(ur))
+    if runs:
+        _emit(plan, nt, node_id_arr, runs, sweep_templates)
+    metrics.measure_since(("nomad", "sched", "system", "emit"), t1)
+
+
+def _emit(plan, nt, node_id_arr, runs, templates) -> None:
+    """The sweep's placements as the plan's SweepBatch: unique placed rows
+    with their summed demand, and the per-alloc columns sorted into row
+    order so a node-range chunk slice maps to a contiguous alloc range
+    (starts). Where the plan held nothing before the emit (a fresh
+    register: no stops, no in-place updates) the batch covers every
+    placed node and the placements exist as those columns only; any other
+    plan also gets them as objects, and the batch keeps only the rows it
+    fully describes."""
+    lens = [len(rows) for _, rows, _ in runs]
+    rows_all = np.concatenate([rows for _, rows, _ in runs])
+    ur, inv = np.unique(rows_all, return_inverse=True)
+    delta = np.zeros((len(ur), RES_DIMS), dtype=np.float32)
+    np.add.at(delta, inv, np.repeat(
+        np.stack([templates[t]._resvec_cache for _, _, t in runs]),
+        lens, axis=0))
+    counts = np.bincount(inv, minlength=len(ur))
+    order = np.argsort(rows_all, kind="stable")
+    names = np.repeat(np.array([n for n, _, _ in runs], dtype=object), lens)
+    tg = np.repeat(np.array([t for _, _, t in runs], dtype=np.int64), lens)
+    node_ids = node_id_arr[ur].tolist()
+    n = len(rows_all)
+
+    alloc_ids = generate_uuids(n)  # random: any order is row order
+    as_columns = not plan.NodeUpdate and not plan.NodeAllocation
+    if not as_columns:
+        tpl = [t.__dict__ for t in templates]
+        for alloc_id, name, t, nid in zip(
+                alloc_ids, names.tolist(), tg.tolist(),
+                node_id_arr[rows_all].tolist()):
+            plan.append_alloc(stamp_alloc(tpl[t], alloc_id, name, nid))
         # Descriptor coverage: only rows whose plan state the delta FULLY
         # describes. Rows with stops stay on the per-node verify path
         # (eviction credit is verify-time snapshot state), as do rows
@@ -489,34 +474,22 @@ def compute_job_allocs(sched) -> None:
         # need the exact remove-then-add accounting.
         keep = np.asarray(
             [nid not in plan.NodeUpdate
-             and len(plan.NodeAllocation[nid]) == emitted_per_row[k]
-             for k, nid in enumerate(ids_list)], dtype=bool)
-        # Per-alloc columns, sorted into unique-row order so a node-range
-        # chunk slice maps to a contiguous alloc range (starts).
-        order = np.argsort(rows_all, kind="stable")
+             and len(plan.NodeAllocation[nid]) == counts[k]
+             for k, nid in enumerate(node_ids)], dtype=bool)
         keep_alloc = keep[inv][order]
-        aid_sorted = np.asarray(alloc_ids_l, dtype=object)[order]
-        name_sorted = np.asarray(alloc_names_l, dtype=object)[order]
-        tg_sorted = np.asarray(alloc_tg_l, dtype=np.int64)[order]
-        counts = emitted_per_row
-        if not keep.all():
-            ur, delta = ur[keep], delta[keep]
-            ids_list = [nid for nid, k in zip(ids_list, keep.tolist()) if k]
-            counts = emitted_per_row[keep]
-            aid_sorted = aid_sorted[keep_alloc]
-            name_sorted = name_sorted[keep_alloc]
-            tg_sorted = tg_sorted[keep_alloc]
-        starts = np.concatenate(
-            [np.zeros(1, dtype=np.int64),
-             np.cumsum(counts, dtype=np.int64)])
-        plan._sweep = SweepBatch(rows=ur, node_ids=ids_list,
-                                 delta=delta, epoch=nt.row_epoch,
-                                 n_rows=nt.n_rows,
-                                 counts=counts, starts=starts,
-                                 alloc_ids=aid_sorted.tolist(),
-                                 alloc_names=name_sorted.tolist(),
-                                 alloc_tg=tg_sorted.tolist(),
-                                 templates=sweep_templates)
-        metrics.incr_counter(("nomad", "sched", "system", "placed"),
-                             n_emitted)
-    metrics.measure_since(("nomad", "sched", "system", "emit"), t1)
+        order = order[keep_alloc]
+        ur, delta, counts = ur[keep], delta[keep], counts[keep]
+        node_ids = [nid for nid, k in zip(node_ids, keep.tolist()) if k]
+        alloc_ids = np.asarray(alloc_ids, dtype=object)[order].tolist()
+    names, tg = names[order], tg[order]
+    starts = np.concatenate(
+        [np.zeros(1, dtype=np.int64), np.cumsum(counts, dtype=np.int64)])
+    plan._sweep = SweepBatch(rows=ur, node_ids=node_ids, delta=delta,
+                             epoch=nt.row_epoch, n_rows=nt.n_rows,
+                             counts=counts, starts=starts,
+                             alloc_ids=alloc_ids,
+                             alloc_names=names.tolist(),
+                             alloc_tg=tg.tolist(), templates=templates)
+    if as_columns:
+        plan.NodeAllocation = ColumnarPlacements.over(plan._sweep)
+    metrics.incr_counter(("nomad", "sched", "system", "placed"), n)

@@ -91,6 +91,7 @@ from nomad_tpu.tensor.node_table import ChainArbiter
 from nomad_tpu.structs.structs import (
     EvalStatusBlocked,
     EvalStatusComplete,
+    EvalTriggerJobDeregister,
     JobTypeBatch,
     JobTypeService,
 )
@@ -113,6 +114,7 @@ FILL_TIMEOUT = 0.002
 STATS_COUNTERS = (
     "fast",       # evals committed via the device-chained fast path
     "slow",       # evals routed to the per-eval GenericScheduler
+    "stop_evals",  # of those, the evals a job deregistration triggered
     "fallback",   # fast dispatches re-run slow (partial commit/ports)
     "stale",      # evals redelivered mid-window and abandoned
     "host",       # fast evals placed host-side (shallow windows)
@@ -861,6 +863,8 @@ class PipelinedWorker(Worker):
             self._arbiter.publish(lease, usage_chain)
         self.stats["windows"] += 1
         self.stats["slow"] += len(slow)
+        self.stats["stop_evals"] += sum(
+            1 for ev, _ in slow if ev.TriggeredBy == EvalTriggerJobDeregister)
         work = _WindowWork(fast=fast, slow=slow, number=number,
                            published=bool(fast), chain_seq=lease.seq,
                            mesh_flags=mesh_flags or None,

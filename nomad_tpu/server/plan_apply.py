@@ -519,6 +519,14 @@ class PlanApplier:
         metrics.incr_counter(("nomad", "qos", "preempt", "evictions"),
                              evicted)
 
+    @staticmethod
+    def _count_stops(result: PlanResult) -> None:
+        """Count the allocations a COMMITTED plan stops (its NodeUpdate:
+        a deregistered job's, a preemption's victims)."""
+        stopped = sum(len(ups) for ups in result.NodeUpdate.values())
+        if stopped:
+            metrics.incr_counter(("nomad", "plan", "stop_rows"), stopped)
+
     def start(self) -> None:
         """Each run gets its OWN stop event, handed to the thread — a
         leadership flap that calls stop();start() must not revive the old
@@ -830,6 +838,7 @@ class PlanApplier:
                 result.AllocIndex = index
                 self.stats["applied"] += 1
                 self._count_preempt(pending.plan, result)
+                self._count_stops(result)
                 pending.respond(result, None)
         # lint: allow(swallow, error is delivered to every plan's waiter)
         except Exception as e:
@@ -853,6 +862,7 @@ class PlanApplier:
                               batch=1):
                 result.AllocIndex = self._apply(pending.plan, result)
             self._count_preempt(pending.plan, result)
+            self._count_stops(result)
         pending.respond(result, None)
 
     def _apply(self, plan: Plan, result: PlanResult) -> int:

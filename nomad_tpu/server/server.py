@@ -949,22 +949,25 @@ class Server:
         )
 
     def job_deregister(self, job_id: str) -> Tuple[str, int]:
-        """(reference: job_endpoint.go:155-207)"""
-        job = self.state.job_by_id(job_id)
-        index = self.raft.apply(MessageType.JobDeregister, {"JobID": job_id})
-        priority = job.Priority if job is not None else 50
-        jtype = job.Type if job is not None else JobTypeService
-        ev = Evaluation(
-            ID=generate_uuid(),
-            Priority=priority,
-            Type=jtype,
-            TriggeredBy=EvalTriggerJobDeregister,
-            JobID=job_id,
-            Region=self._ev_region(job),
-            JobModifyIndex=index,
-            Status=EvalStatusPending,
-        )
-        self.raft.apply(MessageType.EvalUpdate, {"Evals": [ev]})
+        """(reference: job_endpoint.go:155-207) The two consensus writes are
+        one `nomad.server.job_deregister` sample and span."""
+        with metrics.measure(("nomad", "server", "job_deregister")):
+            job = self.state.job_by_id(job_id)
+            index = self.raft.apply(MessageType.JobDeregister,
+                                    {"JobID": job_id})
+            priority = job.Priority if job is not None else 50
+            jtype = job.Type if job is not None else JobTypeService
+            ev = Evaluation(
+                ID=generate_uuid(),
+                Priority=priority,
+                Type=jtype,
+                TriggeredBy=EvalTriggerJobDeregister,
+                JobID=job_id,
+                Region=self._ev_region(job),
+                JobModifyIndex=index,
+                Status=EvalStatusPending,
+            )
+            self.raft.apply(MessageType.EvalUpdate, {"Evals": [ev]})
         return ev.ID, index
 
     def job_evaluate(self, job_id: str) -> Tuple[str, int]:

@@ -35,7 +35,11 @@ GC, deregisters, annotate requests — falls back to the exact per-eval
 GenericScheduler path (scheduler/generic_sched.py), as does any eval whose
 plan partially commits (stale chain) or whose winner fails host-side port
 assignment. Fallbacks preserve reference semantics bit-for-bit; the fast path
-only accelerates evals whose outcome is provably the same.
+only accelerates evals whose outcome is provably the same. One such case
+is batched off that path too: the evals of a window whose job is gone, which
+the exact scheduler would turn into a pure stop, are diffed on one snapshot
+and committed as one batch (_stop_batch); anything that batch cannot finish
+whole re-runs on the exact path.
 
 N workers share ONE logical usage chain through the ChainArbiter
 (tensor/node_table.py): a window lease serializes the dispatch handoff so
@@ -50,6 +54,7 @@ contention seams that made a second worker SLOWER than one.
 
 from __future__ import annotations
 
+import copy
 import logging
 import queue
 import random
@@ -57,7 +62,8 @@ import threading
 import time
 from contextlib import contextmanager
 from dataclasses import dataclass, field
-from typing import Dict, List, Optional, Tuple
+from types import SimpleNamespace
+from typing import Dict, List, Optional, Set, Tuple
 
 import numpy as np
 
@@ -79,16 +85,19 @@ from nomad_tpu.scheduler.stack import (
     eval_pad,
 )
 from nomad_tpu.scheduler.util import (
+    ALLOC_NOT_NEEDED,
     BLOCKED_EVAL_FAILED_PLACEMENTS,
     diff_allocs,
     materialize_task_groups,
+    set_status,
     tainted_nodes,
 )
-from nomad_tpu.structs import (AllocMetric, Evaluation, Plan, columns_only,
-                               placed_count)
+from nomad_tpu.structs import (Allocation, AllocMetric, Evaluation, Plan,
+                               columns_only, placed_count)
 from nomad_tpu.telemetry import metrics, trace
 from nomad_tpu.tensor.node_table import ChainArbiter
 from nomad_tpu.structs.structs import (
+    AllocDesiredStatusStop,
     EvalStatusBlocked,
     EvalStatusComplete,
     EvalTriggerJobDeregister,
@@ -113,8 +122,11 @@ FILL_TIMEOUT = 0.002
 # README's "Serving pipeline observability" section documents each key.
 STATS_COUNTERS = (
     "fast",       # evals committed via the device-chained fast path
-    "slow",       # evals routed to the per-eval GenericScheduler
+    "slow",       # evals routed to the build thread's slow stage: the
+    #               per-eval GenericScheduler, or the window's stop batch
     "stop_evals",  # of those, the evals a job deregistration triggered
+    "stop_batched",  # of those, the evals the stop batch finished (not
+    #                  those it handed to the per-eval scheduler)
     "fallback",   # fast dispatches re-run slow (partial commit/ports)
     "stale",      # evals redelivered mid-window and abandoned
     "host",       # fast evals placed host-side (shallow windows)
@@ -270,6 +282,7 @@ class _WindowWork:
     failed: bool = False                       # drain blew up: nack window
     chained: bool = False       # dispatched on a previous window's tail
     taint_seq: int = 0          # arbiter taint seq observed at chain read
+    chain_frees: int = 0        # nt.frees its chain's usage already holds
     published: bool = False     # tail published: arbiter counts us in flight
     chain_seq: int = 0          # chain position (arbiter finish barrier)
     mesh_flags: Optional[list] = None  # warm-window exactness certificates
@@ -313,6 +326,35 @@ def _prep_sig(job, place, batch: bool) -> Optional[tuple]:
         tg_sigs[tg.Name] = (tuple(tasks), constraint_sig(tg.Constraints))
     return (batch, constraint_sig(job.Constraints), tuple(names),
             tuple(sorted(tg_sigs.items())))
+
+
+def _stop_row(alloc: Allocation) -> Allocation:
+    """The row Plan.append_update(alloc, stop, ALLOC_NOT_NEEDED) adds,
+    without its deep copy: a new top-level object, so the stored allocation
+    is never written, sharing its nested values (resources, metrics, task
+    states, services). Sound because no writer changes a nested field of a
+    stored allocation in place: the store and the client replace them
+    (upsert_allocs, update_alloc_from_client, Client._run_allocs)."""
+    row = copy.copy(alloc)
+    row.Job = None
+    row.DesiredStatus = AllocDesiredStatusStop
+    row.DesiredDescription = ALLOC_NOT_NEEDED
+    return row
+
+
+def stop_plan(ev: Evaluation, snap) -> Plan:
+    """The plan GenericScheduler makes for `ev` when its job is gone from
+    `snap`, by the exact path's own diff (generic_sched
+    _compute_job_allocs with no job): every live allocation of the job is
+    a stop row."""
+    allocs = filter_complete_allocs(list(snap.allocs_by_job(ev.JobID)),
+                                    ev.Type == JobTypeBatch)
+    diff = diff_allocs(None, tainted_nodes(snap, allocs), {}, allocs)
+    plan = ev.make_plan(None)
+    for tup in diff.stop:
+        plan.NodeUpdate.setdefault(tup.Alloc.NodeID, []).append(
+            _stop_row(tup.Alloc))
+    return plan
 
 
 class PipelinedWorker(Worker):
@@ -511,7 +553,8 @@ class PipelinedWorker(Worker):
 
     def _build_loop(self) -> None:
         """Stage 3: plan build/submit -> status batch -> acks, plus the
-        slow-path evals of the window."""
+        slow-path evals of the window: its stops as one batch, then the
+        rest one by one."""
         while True:
             work = self._build_q.get()
             if work is None:
@@ -524,7 +567,7 @@ class PipelinedWorker(Worker):
                     self._finish_fast(work)
                 if work.slow:
                     with self._stage("slow", work.number):
-                        for ev, token in work.slow:
+                        for ev, token in self._stop_batch(work):
                             self._process_slow(ev, token)
             except Exception:
                 if work.published:
@@ -912,6 +955,7 @@ class PipelinedWorker(Worker):
         # detect a taint raised while this window was in flight.
         work.chained = chained_at_dispatch
         work.taint_seq = lease.taint_seq
+        work.chain_frees = lease.frees
         return work
 
     def quiesce(self, timeout: float = 30.0) -> bool:
@@ -1203,7 +1247,15 @@ class PipelinedWorker(Worker):
                          "timeout; taint check may be early", work.chain_seq)
         external_taint = (work.chained
                           and self._arbiter.taint_changed(work.taint_seq))
-        if tainted_from is not None:
+        # The other phantom: usage a device tail still holds for
+        # allocations a committed plan or client has since freed (a
+        # stop's; a host tail gave it back at acquire). A failed placement
+        # may fit the committed table: re-run it on the exact path, and
+        # rebase the chain for the windows after.
+        behind_frees = (self.tindex.nt.frees != work.chain_frees and any(
+            not rec.stale and not rec.fallback and rec.failed_tg_allocs
+            for rec in fast))
+        if tainted_from is not None or behind_frees:
             # Windows in flight on OUR tail — any worker's — inherit the
             # phantom too.
             self._arbiter.taint()
@@ -1211,8 +1263,9 @@ class PipelinedWorker(Worker):
         # (they need our taint, not our acks — settle BEFORE the status
         # batch and ack round below).
         self._arbiter.mark_settled(work.chain_seq)
-        if tainted_from is not None or external_taint:
-            start = 0 if external_taint else tainted_from + 1
+        if tainted_from is not None or external_taint or behind_frees:
+            start = 0 if external_taint or behind_frees \
+                else tainted_from + 1
             for rec in fast[start:]:
                 if (not rec.stale and not rec.fallback
                         and rec.failed_tg_allocs):
@@ -1422,6 +1475,92 @@ class PipelinedWorker(Worker):
                     chosen=chosen[idx], scores=scores[idx],
                     nf_last=int(nf_last[idx]), ok=bool(ok[idx]))
         return out
+
+    # ------------------------------------------------------------ stop batch
+    def _stop_batch(self, work: _WindowWork) -> List[Tuple[Evaluation, str]]:
+        """The window's stops as one batch, inside its slow stage. An eval
+        the exact scheduler would turn into a pure stop (service or batch,
+        a trigger it handles, no annotate request, its job gone from one
+        fresh snapshot, the first eval of its job in the window) is
+        finished by _finish_stops on that snapshot. Returns what is left
+        for _process_slow, in window order: every other eval, and each
+        one the batch did not finish."""
+        kinds = [ev.Type in (JobTypeService, JobTypeBatch)
+                 and ev.TriggeredBy in _HANDLED_TRIGGERS
+                 and not ev.AnnotatePlan for ev, _ in work.slow]
+        if not any(kinds):
+            return work.slow  # no snapshot for a window of system evals
+        snap = self.raft.fsm.state.snapshot()
+        batch: List[Tuple[Evaluation, str]] = []
+        seen: Set[str] = set()
+        for (ev, token), kind in zip(work.slow, kinds):
+            if (kind and ev.JobID not in seen
+                    and snap.job_by_id(ev.JobID) is None):
+                batch.append((ev, token))
+            seen.add(ev.JobID)
+        if not batch:
+            return work.slow
+        done: Set[str] = set()
+        with metrics.measure(("nomad", "worker", "stop_batch"),
+                             worker=self.name, window=work.number,
+                             evals=len(batch)):
+            try:
+                done = self._finish_stops(batch, snap)
+            except Exception:
+                if not (self._stop.is_set()
+                        or not self.eval_broker.enabled()):
+                    logger.exception("stop batch failed; re-running its "
+                                     "%d evals per-eval", len(batch))
+        self.stats["stop_batched"] += len(done)
+        return [(ev, token) for ev, token in work.slow if ev.ID not in done]
+
+    def _finish_stops(self, batch: List[Tuple[Evaluation, str]],
+                      snap) -> Set[str]:
+        """stop_plan for each eval of the batch, the plans enqueued in one
+        round, one wait each; the evals whose plan committed every row
+        with no RefreshIndex, and those with nothing to stop, get the
+        exact path's status update (set_status: complete, no next or
+        blocked eval) in ONE EvalUpdate entry and ONE ack round. Returns
+        their ids; an eval redelivered, or whose plan was refused, raised
+        or committed in part, is left to the exact path."""
+        stale = self.eval_broker.outstanding_reset_batch(
+            [(ev.ID, token) for ev, token in batch])
+        planned = [(ev, token, stop_plan(ev, snap))
+                   for ev, token in batch if ev.ID not in stale]
+        submit = [plan for _, _, plan in planned if not plan.is_no_op()]
+        for _, token, plan in planned:
+            plan.EvalToken = token
+        waits = dict(zip((plan.EvalID for plan in submit),
+                         self.plan_queue.enqueue_all(submit))) \
+            if submit else {}
+        finished: List[Tuple[Evaluation, str]] = []
+        for ev, token, plan in planned:
+            pending = waits.get(ev.ID)
+            if pending is not None:
+                try:
+                    result = pending.wait(timeout=30.0)
+                except Exception:
+                    logger.debug("stop plan for eval %s not committed; "
+                                 "re-running per-eval", ev.ID)
+                    continue
+                if (result.RefreshIndex or placed_count(result.NodeUpdate)
+                        != placed_count(plan.NodeUpdate)):
+                    continue
+            finished.append((ev, token))
+        if not finished:
+            return set()
+        updates: List[Evaluation] = []
+        planner = SimpleNamespace(update_eval=updates.append)
+        for ev, _ in finished:
+            set_status(planner, ev, None, None, {}, EvalStatusComplete, "")
+        self.raft.apply(MessageType.EvalUpdate, {"Evals": updates})
+        try:
+            for eval_id, e in self.eval_broker.ack_batch(
+                    [(ev.ID, token) for ev, token in finished]):
+                logger.debug("worker: ack skipped for %s: %s", eval_id, e)
+        except Exception:
+            logger.exception("worker: stop batch ack failed")
+        return {ev.ID for ev, _ in finished}
 
     # ------------------------------------------------------------- slow path
     def _process_slow(self, ev: Evaluation, token: str) -> None:

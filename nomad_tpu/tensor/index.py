@@ -308,6 +308,8 @@ class TensorIndex:
         lock; node events keep their per-event path (rare)."""
         node_ids = []
         vecs = []
+        freed_ids = []
+        freed = []
         for kind, old, new in events:
             if kind == "node":
                 self._on_node(old, new)
@@ -316,6 +318,10 @@ class TensorIndex:
                 continue
             was = old is not None and not old.terminal_status()
             now = new is not None and not new.terminal_status()
+            if was and not now:
+                freed_ids.append(old.NodeID)
+                freed.append(alloc_vec(old))
+                continue
             if was:
                 node_ids.append(old.NodeID)
                 vecs.append(-alloc_vec(old))
@@ -325,6 +331,9 @@ class TensorIndex:
         if node_ids:
             self.nt.apply_usage_deltas(
                 node_ids, np.stack(vecs).astype(np.float32))
+        if freed_ids:
+            self.nt.free_usage(freed_ids,
+                               np.stack(freed).astype(np.float32))
 
     def _on_node(self, old: Optional[Node], new: Optional[Node]) -> None:
         if new is None:
@@ -337,7 +346,7 @@ class TensorIndex:
         was_counted = old is not None and not old.terminal_status()
         now_counted = new is not None and not new.terminal_status()
         if was_counted and not now_counted:
-            self.nt.remove_alloc_usage(old)
+            self.nt.free_usage([old.NodeID], alloc_vec(old)[None, :])
         elif not was_counted and now_counted:
             self.nt.add_alloc_usage(new)
         elif was_counted and now_counted:

@@ -117,6 +117,11 @@ class NodeTensor:
         # window) into zero transfers.
         self._dirty_rows: Set[int] = set()
         self._usage_dirty: Set[int] = set()
+        # Allocations that stopped counting (free_usage), and their usage
+        # summed by row: a usage chain that skipped the usage tier since
+        # an earlier count never saw them (ChainArbiter.acquire).
+        self.frees = 0
+        self.freed_usage = np.zeros_like(self.usage)
         self._resized = True
         self._device: Optional[dict] = None
         # Multi-chip: when set, device arrays shard their node axis over the
@@ -258,6 +263,7 @@ class NodeTensor:
             self._reserved_cache.clear()
             self._dirty_rows.clear()
             self._usage_dirty.clear()
+            self.freed_usage[:] = 0.0  # epochs bump: every chain resets
             self._resized = True  # full re-upload on next device_arrays
             self.row_epoch += 1
             self.node_version += 1
@@ -301,18 +307,43 @@ class NodeTensor:
         allocs become one scatter-add instead of 50 lock/indexing rounds
         (the plan applier is on the scheduling critical path)."""
         with self._lock:
-            rows = []
-            keep = []
-            for k, nid in enumerate(node_ids):
-                row = self.row_of.get(nid)
-                if row is not None:
-                    rows.append(row)
-                    keep.append(k)
+            rows, keep = self._rows_of(node_ids)
             if not rows:
                 return
             rows_arr = np.asarray(rows, dtype=np.int64)
             np.add.at(self.usage, rows_arr, vecs[keep])
             self._usage_dirty.update(rows)
+
+    def free_usage(self, node_ids: Sequence[str], vecs: np.ndarray) -> None:
+        """Take the usage of allocations that stopped counting off the
+        table (`vecs`: their usage, positive), and keep it: counted
+        (`frees`) and summed by row (`freed_usage`) for the usage chains
+        that skipped the usage tier since."""
+        with self._lock:
+            rows, keep = self._rows_of(node_ids)
+            if rows:
+                rows_arr = np.asarray(rows, dtype=np.int64)
+                np.subtract.at(self.usage, rows_arr, vecs[keep])
+                np.add.at(self.freed_usage, rows_arr, vecs[keep])
+                self._usage_dirty.update(rows)
+            self.frees += len(node_ids)
+
+    def freed(self) -> Tuple[int, np.ndarray]:
+        """(`frees`, a copy of `freed_usage`), consistent with each other."""
+        with self._lock:
+            return self.frees, self.freed_usage.copy()
+
+    def _rows_of(self, node_ids: Sequence[str]) -> Tuple[List[int],
+                                                           List[int]]:
+        """Rows of the nodes that have one, and their positions in
+        `node_ids`. Caller holds _lock."""
+        rows, keep = [], []
+        for k, nid in enumerate(node_ids):
+            row = self.row_of.get(nid)
+            if row is not None:
+                rows.append(row)
+                keep.append(k)
+        return rows, keep
 
     # ------------------------------------------------------------ row mgmt
     def _alloc_row(self) -> int:
@@ -326,6 +357,7 @@ class NodeTensor:
         self.capacity = _grow2(self.capacity, new)
         self.score_cap = _grow2(self.score_cap, new, fill=1.0)
         self.usage = _grow2(self.usage, new)
+        self.freed_usage = _grow2(self.freed_usage, new)
         self.ready = _grow1(self.ready, new, fill=False)
         self.class_ids = _grow1(self.class_ids, new, fill=0)
         self.dc_ids = _grow1(self.dc_ids, new, fill=-1)
@@ -481,13 +513,16 @@ class ChainLease:
     now in flight) or :meth:`ChainArbiter.abort` (nothing dispatched)."""
 
     __slots__ = ("chain", "taint_seq", "epoch", "rebased", "released",
-                 "seq")
+                 "seq", "frees")
 
-    def __init__(self, chain, taint_seq: int, epoch: int, rebased: bool):
+    def __init__(self, chain, taint_seq: int, epoch: int, rebased: bool,
+                 frees: int):
         self.chain = chain
         self.taint_seq = taint_seq
         self.epoch = epoch
         self.rebased = rebased
+        self.frees = frees     # nt.frees the chain has seen: the usage of
+        #                        later frees is still in it
         self.released = False  # publish/abort happened (one-shot)
         self.seq = 0           # chain position, assigned at publish
 
@@ -537,7 +572,7 @@ class ChainArbiter:
     _concurrency = guarded_by(
         "_cond", "_tail", "_tail_epoch", "_holder", "_pending",
         "_windows_since_rebase", "_dirty", "_taint_seq", "_published_seq",
-        "_settled_seq")
+        "_settled_seq", "_chain_frees", "_chain_freed")
 
     def __init__(self, nt: NodeTensor, rebase_windows: int = REBASE_WINDOWS):
         self.nt = nt
@@ -549,6 +584,8 @@ class ChainArbiter:
         self._holder: Optional[str] = None  # window mid-dispatch (lease out)
         self._pending = 0            # published windows not yet finished
         self._windows_since_rebase = 0
+        self._chain_frees = 0        # nt.frees the chain has seen, and
+        self._chain_freed = None     # nt.freed_usage then (host tails)
         self._dirty = False          # tail carries phantom usage: rebase next
         self._taint_seq = 0
         # Chain-order finish barrier: windows SETTLE (make their phantom-
@@ -608,8 +645,22 @@ class ChainArbiter:
             if chain is None:
                 self._tail = None
                 self._windows_since_rebase = 0
+                # Read before the window reads usage: a free that lands in
+                # between is in both, and the applier refuses what that
+                # understates (a host tail gives it back twice).
+                self._chain_frees, self._chain_freed = nt.freed()
+            elif (isinstance(chain, np.ndarray)
+                  and nt.frees != self._chain_frees):
+                # A host tail gives back the usage freed since it saw the
+                # last free: one numpy subtraction. A device tail keeps it
+                # (a program and an upload a window); the worker re-runs
+                # what it refuses for that (_await_window).
+                frees, freed = nt.freed()
+                chain = self._tail = chain - (freed - self._chain_freed)
+                self._chain_frees, self._chain_freed = frees, freed
             return ChainLease(chain=chain, taint_seq=self._taint_seq,
-                              epoch=nt.row_epoch, rebased=rebased)
+                              epoch=nt.row_epoch, rebased=rebased,
+                              frees=self._chain_frees)
 
     def publish(self, lease: ChainLease, usage_after) -> None:
         """Install the dispatched window's usage tail and count it in

@@ -153,6 +153,7 @@ def test_registrations_and_stops_share_a_window(churned):
     assert parked["windows"] == 1
     assert parked["fast"] == PAIRS  # the registrations, placed as ever
     assert parked["slow"] == parked["stop_evals"] == PAIRS
+    assert parked["stop_batched"] == PAIRS  # one batch, none re-run
     assert parked["fallback"] == parked["stale"] == 0
 
 

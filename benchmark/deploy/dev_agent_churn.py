@@ -13,8 +13,12 @@ window_buckets warm-up, the reads. What differs:
 - start() places the standing set after the warm-up: `count` jobs of its
   template through the served path under that bound, every one waited to
   `complete` (set-up: `phases["standing_s"]`). A fleet that is not the
-  file's stands the file's count scaled to it (80 at a rehearsal's 400
-  nodes). The warm-up's jobs stay, as in svc-10k.storm.
+  file's stands the file's count scaled to it, but never fewer than the
+  bound on evals in flight (256 at a rehearsal's 400 nodes, where the
+  scaled count would be 80): a live set shorter than what is in flight
+  would put the next stop's head among the evals still pending, and the
+  pairs would wait for it (below). The warm-up's jobs stay, as in
+  svc-10k.storm.
 - Once start() has returned, make_job(template) is the client's side of
   the pair: it stops the oldest live job, then waits for room for the new
   registration, then builds the job. register(job) stays the registration
@@ -22,9 +26,12 @@ window_buckets warm-up, the reads. What differs:
   The live set is a FIFO of the standing set, oldest first, and then every
   job registered, so it keeps its size and every job, the standing ones
   included, is stopped as many registrations after it started as the
-  standing set holds. Only a job seen `complete` is stopped: a head not
-  yet seen so is owed, and stopped by the next pair that finds it
-  complete; a head that ended otherwise is a failed operation, which stays
+  standing set holds. Only a job seen `complete` is stopped: a pair whose
+  head is still pending waits for it (up to ROOM_TIMEOUT_S, its stop owed
+  meanwhile) and sends its registration after the stop, so the live set
+  never outgrows the standing set behind an eval that a worker holds (one
+  can hold the oldest eval unacked while the other worker's windows go
+  by); a head that ended otherwise is a failed operation, which stays
   acknowledged and is left running. A generator's closed loop
   (`closed_loop`, traffic/churn.json) therefore drives both halves.
 - deregister(job_id) calls Server.job_deregister, the endpoint behind
@@ -50,9 +57,11 @@ STANDING_TIMEOUT_S = 600.0
 
 def standing_count(config, n_nodes):
     """The standing set on a fleet of n_nodes: the file's count, scaled to
-    the fleet where it is not the file's."""
-    return config["standing_jobs"]["count"] * n_nodes \
-        // config["fleet"]["nodes"]
+    the fleet where it is not the file's, and at least the bound on evals
+    in flight."""
+    spec = config["standing_jobs"]
+    return max(spec["count"] * n_nodes // config["fleet"]["nodes"],
+               spec["outstanding"])
 
 
 class Acknowledged:
@@ -88,6 +97,7 @@ class Deployment(dev_agent.Deployment):
         self.live = None    # the FIFO, once start() has returned
         self.owed = 0       # stops owed to registrations already sent
         self.unstopped = []  # heads that ended short of complete
+        self.head_waits = 0  # pairs that waited for a pending head
         self.in_flight = {}  # eval id -> "run" | "stop", not seen ended
         self.flight_reads = []  # (runs, stops) in flight at each full read
 
@@ -143,11 +153,18 @@ class Deployment(dev_agent.Deployment):
         return super().make_job(template)
 
     def _stop_owed(self):
+        deadline = None
         while self.owed and self.live:
             job_id, eval_id = self.live[0]
             status = self.eval_status(eval_id)
             if status not in TERMINAL:
-                return  # not seen complete yet: a later pair stops it
+                if deadline is None:
+                    deadline = time.monotonic() + ROOM_TIMEOUT_S
+                    self.head_waits += 1
+                if time.monotonic() > deadline:
+                    return  # still owed: a later pair stops it
+                time.sleep(POLL_S)
+                continue
             self.live.popleft()
             if status == "complete":
                 self._wait_for_room()
@@ -182,4 +199,5 @@ class Deployment(dev_agent.Deployment):
                       "stops_max": max(s for _, s in reads)}
         return {**super().facts(), "standing_jobs": len(self.standing),
                 "stopped_jobs": len(self.stopped), "stops_owed": self.owed,
+                "head_waits": self.head_waits,
                 "unstopped": self.unstopped[:5], "in_flight": flight}

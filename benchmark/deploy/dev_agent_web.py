@@ -16,9 +16,11 @@ window_buckets warm-up, the reads. What differs:
   `fast`. tests/benchmark_suite/test_benchmark_rehearsal.py asserts
   host == fast for every cell, so a fleet that is not the file's gets the
   template with the task's CPU ask raised to rehearsal.cpu (one allocation
-  a node: no two share a port space), and only such a fleet does. The
-  file's `rehearsal.why` has the numbers; tests/test_web_shape.py holds
-  the published shape, collisions included, on the CPU.
+  a node: no two share a port space) and its count cut to rehearsal.count
+  (so that the guard ends the window after over a second, not a fifth of
+  one), and only such a fleet does. The file's `rehearsal.why` has the
+  numbers; tests/test_web_shape.py holds the published shape, collisions
+  included, on the CPU.
 """
 
 from __future__ import annotations
@@ -57,6 +59,7 @@ class Deployment(dev_agent.Deployment):
         job = super().make_job(template)
         if not self.full_size:
             for group in job.TaskGroups:
+                group.Count = self.config["rehearsal"]["count"]
                 for task in group.Tasks:
                     task.Resources.CPU = self.config["rehearsal"]["cpu"]
         return job

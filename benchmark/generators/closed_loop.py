@@ -17,20 +17,14 @@ from __future__ import annotations
 
 import time
 
-from benchmark.ops import pick_template, poll, submit
-from benchmark.reference.guarantees import capacity_allocs
+from benchmark.ops import fill_limit, pick_template, poll, submit
 
 
 def run(dep, traffic, rng, seconds, clock=time.perf_counter, progress=None):
     poll_s = traffic["poll_ms"] / 1e3
     target = int(traffic["outstanding"])
     notes = []
-    limit = None
-    if traffic.get("fill_guard"):
-        nodes = dep.server.state.nodes()
-        room = min(capacity_allocs(nodes, dep.make_job(t))
-                   for t in traffic["templates"])
-        limit = traffic["fill_guard"] * room - dep.asked
+    limit = fill_limit(dep, traffic)
     ops, pending = [], []
     asked = 0
     t0 = clock()

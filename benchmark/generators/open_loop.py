@@ -4,7 +4,12 @@ whether or not earlier ones have finished. Each is timed from its due time
 the first read that shows its eval in a terminal status.
 
 Traffic parameters: arrival ("poisson", "fixed" or "bursts"), rate_per_s,
-burst (jobs per burst, for "bursts"), poll_ms, templates (weights).
+burst (jobs per burst, for "bursts"), poll_ms, templates (weights),
+fill_guard (share of eligible capacity, optional). With a fill guard, as
+in closed_loop, no op is sent once the allocations asked for reach it: the
+window ends there, the ops in flight are still waited for, and
+`progress(asked, limit)`, where the harness gives one, is told once a
+registration. Without one the hook is not called.
 
 Every seed gets the same multiset of gaps in another order: a Poisson
 process is drawn as blocks of BLOCK gaps at the exponential distribution's
@@ -17,7 +22,7 @@ from __future__ import annotations
 import math
 import time
 
-from benchmark.ops import pick_template, poll, submit
+from benchmark.ops import fill_limit, pick_template, poll, submit
 
 BLOCK = 100
 GRACE_S = 60.0  # how long after the window an unfinished op is waited for
@@ -45,12 +50,11 @@ def gaps(traffic, rng):
 
 
 def run(dep, traffic, rng, seconds, clock=time.perf_counter, progress=None):
-    # `progress` is the harness's hook for a window that a fill guard ends
-    # (generators/closed_loop.py); an open loop has no guard and no use
-    # for it.
     poll_s = traffic["poll_ms"] / 1e3
+    limit = fill_limit(dep, traffic)
     gap = gaps(traffic, rng)
-    ops, pending = [], []
+    ops, pending, notes = [], [], []
+    asked = 0
     t0 = clock()
     t_end = t0 + seconds
     due = t0 + next(gap)
@@ -62,6 +66,17 @@ def run(dep, traffic, rng, seconds, clock=time.perf_counter, progress=None):
             ops.append(op)
             pending.append(op)
             due += next(gap)
+            if limit is None:
+                continue
+            asked += op.asks
+            if progress is not None:
+                progress(asked, limit)
+            if asked >= limit:  # the guard ends the window: send no more
+                t_end, due = clock(), math.inf
+                notes.append(f"fill guard: {asked} allocations asked for "
+                             f"reach {traffic['fill_guard']:.0%} of eligible "
+                             f"capacity; window ended after "
+                             f"{t_end - t0:.3f} s")
         for op in poll(dep, pending, clock):
             pending.remove(op)
         now = clock()
@@ -72,4 +87,4 @@ def run(dep, traffic, rng, seconds, clock=time.perf_counter, progress=None):
             wake = min(wake, due)
         time.sleep(max(0.0, min(wake, t_end + GRACE_S) - clock()))
     return {"t0": t0, "t1": t_end, "gave_up": clock(), "ops": ops,
-            "notes": []}
+            "notes": notes}

@@ -1,10 +1,13 @@
-"""One operation of a traffic mix as the generators record it, and the poll
-that every generator shares. Times are time.perf_counter() seconds."""
+"""One operation of a traffic mix as the generators record it, the poll
+that every generator shares, and the fill guard's limit that a mix may
+state for either loop. Times are time.perf_counter() seconds."""
 
 from __future__ import annotations
 
 from dataclasses import dataclass
 from typing import Optional
+
+from benchmark.reference.guarantees import capacity_allocs
 
 TERMINAL = ("complete", "failed", "canceled")
 
@@ -51,3 +54,16 @@ def pick_template(weights, rng):
     if len(names) == 1:
         return names[0]
     return rng.choices(names, [weights[n] for n in names])[0]
+
+
+def fill_limit(dep, traffic):
+    """The allocations a window may ask for before its fill guard ends it:
+    the mix's fill_guard (a share of what the eligible nodes hold when
+    empty, by the smallest template's room) less what set-up already asked
+    for. None where the mix states no guard."""
+    if not traffic.get("fill_guard"):
+        return None
+    nodes = dep.server.state.nodes()
+    room = min(capacity_allocs(nodes, dep.make_job(t))
+               for t in traffic["templates"])
+    return traffic["fill_guard"] * room - dep.asked

@@ -180,10 +180,14 @@ def failed_operations(reads, acknowledged):
     return failed
 
 
-def check(reads, acknowledged, failed_jobs, device_usage, row_of):
+def check(reads, acknowledged, failed_jobs, device_usage, row_of,
+          replica_reads=None, replicas=1):
     """Checks 2-6. reads: {"nodes", "jobs", "evals", "allocs"} lists from
     the store; acknowledged: (job_id, eval_id, template) of every job the
-    server acknowledged; failed_jobs: job ids of failed operations."""
+    server acknowledged; failed_jobs: job ids of failed operations.
+    replica_reads: one such reads (or None for a replica that did not
+    answer) per replica the deployment runs, [reads] where it runs one;
+    replicas: how many the configuration states. Check 5 is held on each."""
     import numpy as np
 
     v = Verdict()
@@ -253,14 +257,11 @@ def check(reads, acknowledged, failed_jobs, device_usage, row_of):
               f"{len(dup_ids)} allocation ids and {len(dup_names)} "
               "(JobID, Name) pairs appear twice", dup_ids + dup_names)
 
-    # 4. counts per job, and 5. acknowledged is read back.
-    not_read_back, wrong_count = [], []
+    # 4. counts per job.
+    wrong_count = []
     for job_id, _, _ in acknowledged:
         job = jobs.get(job_id)
-        if job is None:
-            not_read_back.append(job_id)
-            continue
-        if job_id in failed_jobs:
+        if job is None or job_id in failed_jobs:  # a missing job is 5's
             continue
         on = live_by_job.get(job_id, [])
         if job.Type == "system":
@@ -283,9 +284,8 @@ def check(reads, acknowledged, failed_jobs, device_usage, row_of):
     v.require("4_counts", not wrong_count,
               f"{len(wrong_count)} acknowledged, unfailed jobs without "
               "exactly their count of live allocations", wrong_count)
-    v.require("5_read_back", not not_read_back,
-              f"{len(not_read_back)} acknowledged jobs are not in the store",
-              not_read_back)
+    read_back(v, acknowledged,
+              [reads] if replica_reads is None else replica_reads, replicas)
     v.require("6_device_usage", usage_err <= 1e-2,
               f"largest |device - recomputed| usage = {usage_err}",
               value=usage_err, limit=1e-2)
@@ -298,11 +298,44 @@ def check(reads, acknowledged, failed_jobs, device_usage, row_of):
     return v
 
 
+def read_back(v, acknowledged, replica_reads, replicas):
+    """5. Every acknowledged job is read back, from every replica the
+    configuration states. With one replica the failure names the jobs, as
+    it always did. With more it names each as "replica <i>: <job id>", a
+    replica with no reads as "replica <i>: no answer", and `5_replicas`
+    compares the count of replicas that did not answer or miss a job with
+    0."""
+    acked = [job_id for job_id, _, _ in acknowledged]
+    missing, bad = [], []
+    for i in range(max(replicas, len(replica_reads))):
+        r = replica_reads[i] if i < len(replica_reads) else None
+        if r is None:
+            missing.append(f"replica {i}: no answer")
+            bad.append(f"replica {i}")
+            continue
+        held = {j.ID for j in r["jobs"]}
+        gone = [job_id for job_id in acked if job_id not in held]
+        missing += gone if replicas == 1 else [f"replica {i}: {job_id}"
+                                               for job_id in gone]
+        if gone:
+            bad.append(f"replica {i}")
+    where = "the store" if replicas == 1 else f"{len(bad)} of {replicas} " \
+        "replicas' stores"
+    v.require("5_read_back", not missing,
+              f"{len(missing)} acknowledged jobs are not in {where}",
+              missing)
+    if replicas > 1:
+        v.require("5_replicas", not bad,
+                  f"{len(bad)} of the {replicas} replicas stated did not "
+                  "answer or miss an acknowledged job", bad)
+
+
 def judge(reads, acknowledged, device_usage, row_of, undrained, platform,
-          rehearsal):
+          rehearsal, replica_reads=None, replicas=1):
     """Everything `correct` is made of but the cell's extra checks: the
     recomputation (2-6) after the drain (1) and the platform (8). It is
-    handed no counter, stage timer, path or latency, so none can enter.
+    handed no counter, stage timer, path or latency, so none can enter;
+    check 5 reads every replica's reads (`check`).
     Evals still pending when the drain gave up are failed operations, not
     incorrect outputs; but the store and the device's table were then read
     at different moments of a running system, so check 6 is left out and
@@ -312,7 +345,8 @@ def judge(reads, acknowledged, device_usage, row_of, undrained, platform,
     if undrained:
         device_usage = device_usage * 0
         row_of = {}
-    verdict = check(reads, acknowledged, failed, device_usage, row_of)
+    verdict = check(reads, acknowledged, failed, device_usage, row_of,
+                    replica_reads, replicas)
     verdict.facts["undrained_evals"] = list(undrained)[:MAX_IDS]
     verdict.facts["device_usage_checked"] = not undrained
     verdict.require("8_platform", rehearsal or platform == "tpu",

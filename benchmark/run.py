@@ -42,6 +42,15 @@ def note(name, **fields):
     print(json.dumps({"note": name, **fields}, default=str), flush=True)
 
 
+def replica_reads(dep, reads):
+    """One reads() a replica for check 5: the deployment's replica_reads()
+    where it runs more than one server (each taken once that replica has
+    applied the leader's last committed index), else the one it has."""
+    if hasattr(dep, "replica_reads"):
+        return dep.replica_reads()
+    return [reads]
+
+
 def main(argv=None):
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--workload", required=True)
@@ -117,7 +126,9 @@ def main(argv=None):
 
         verdict, failed = guarantees.judge(
             reads, dep.acknowledged, device_usage, row_of, undrained,
-            device["platform"], rehearsal=args.allow_cpu)
+            device["platform"], rehearsal=args.allow_cpu,
+            replica_reads=replica_reads(dep, reads),
+            replicas=cell.config["guarantees"]["replicas"])
         facts = dict(verdict.facts)
         for name in cell.traffic.get("extra_checks", ()):
             extra = importlib.import_module("benchmark.reference." + name)

@@ -260,10 +260,48 @@ def test_the_closed_loop_reports_every_registration_against_its_limit(
 
 
 def test_an_open_loop_takes_the_hook_and_has_no_use_for_it(monkeypatch):
+    # Without a fill guard (trickle.json, rollout.json) the hook is never
+    # called, no job is built to size a room, and the window is the clock's.
     monkeypatch.setattr(open_loop.time, "sleep", lambda s: None)
     told = []
     traffic = {"poll_ms": 2, "arrival": "fixed", "rate_per_s": 100,
                "templates": {"web": 1}}
-    window = open_loop.run(_Loop(), traffic, random.Random(1), 0.1,
-                           clock=_ticking(), progress=told.append)
+    dep = _Loop()
+    clock = _ticking()
+    window = open_loop.run(dep, traffic, random.Random(1), 0.1,
+                           clock=clock, progress=told.append)
     assert window["ops"] and told == []
+    assert dep.jobs == len(window["ops"]) and window["notes"] == []
+    assert window["t1"] - window["t0"] == pytest.approx(0.1)
+    assert [op.due - window["t0"] for op in window["ops"]] == pytest.approx(
+        [0.01 * (i + 1) for i in range(len(window["ops"]))])
+    for mix in ("trickle", "rollout"):
+        with open(os.path.join(ROOT, "benchmark", "traffic",
+                               mix + ".json")) as f:
+            assert "fill_guard" not in json.load(f)
+
+
+def test_an_open_loop_with_a_guard_ends_the_window_at_the_limit(
+        monkeypatch):
+    monkeypatch.setattr(open_loop.time, "sleep", lambda s: None)
+    dep = _Loop(nodes=40, already=20)
+    traffic = {"poll_ms": 2, "arrival": "bursts", "burst": 32,
+               "rate_per_s": 320, "templates": {"web": 1}, "fill_guard": 0.5}
+    told = []
+    window = open_loop.run(dep, traffic, random.Random(1), 30.0,
+                           clock=_ticking(),
+                           progress=lambda a, lim: told.append((a, lim)))
+    limit = 0.5 * 40 * 7 - 20  # as closed_loop's: the warm-up's counts
+    # One report a registration; the one that reaches the limit is the
+    # last op sent, in the middle of the first burst of 32, and the window
+    # ends there, long before the clock, with every op waited for.
+    assert told == [(10 * (i + 1), limit) for i in range(12)]
+    ops = window["ops"]
+    assert len(ops) == 12 and all(op.done is not None for op in ops)
+    assert len({op.due for op in ops}) == 1  # all of one burst
+    assert window["t1"] - window["t0"] < 1.0
+    assert "fill guard" in window["notes"][0]
+    # The same window without a listener.
+    quiet = open_loop.run(_Loop(nodes=40, already=20), traffic,
+                          random.Random(1), 30.0, clock=_ticking())
+    assert len(quiet["ops"]) == 12

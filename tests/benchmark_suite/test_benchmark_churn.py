@@ -11,6 +11,7 @@ import json
 import os
 import random
 import types
+from collections import deque
 
 import pytest
 
@@ -71,10 +72,13 @@ def test_the_standing_set_is_2000_services_scaled_by_the_fleet():
                     "outstanding": 256}
     nodes = CONFIG["fleet"]["nodes"]
     assert dev_agent_churn.standing_count(CONFIG, nodes) == 2000
-    # A rehearsal's fleet stands its share of the set: 80 of 400 nodes.
+    # A rehearsal's fleet stands its share of the set, but never fewer
+    # jobs than evals may be in flight: 256 of 400 nodes, not 80, so no
+    # stop is owed behind a pending head.
     assert CONFIG["rehearsal"] == {"nodes": 400}
-    assert dev_agent_churn.standing_count(CONFIG, 400) == 80
-    assert dev_agent_churn.standing_count(CONFIG, 200) == 40
+    assert dev_agent_churn.standing_count(CONFIG, 400) == 256
+    assert dev_agent_churn.standing_count(CONFIG, 200) == 256
+    assert dev_agent_churn.standing_count(CONFIG, 6000) == 1200
     # The deployment's bound on evals in flight is the mix's outstanding.
     traffic = _json("benchmark", "traffic", "churn.json")
     assert spec["outstanding"] == traffic["outstanding"] == 256
@@ -91,10 +95,53 @@ def test_the_standing_set_holds_five_to_six_percent_of_eligible_capacity():
     assert 9.5 <= standing / ready <= 10.5
 
 
+class _Pairs:
+    """What Deployment._stop_owed reads of a running deployment: the live
+    FIFO, the stops owed, each register eval's statuses in the order they
+    are read (the last one then stays)."""
+
+    def __init__(self, statuses, owed):
+        self.reads = {e: list(seq) for e, seq in statuses.items()}
+        self.live = deque((f"job-{e}", e) for e in statuses)
+        self.owed, self.unstopped, self.stops = owed, [], []
+        self.head_waits = 0
+
+    def eval_status(self, eval_id):
+        seq = self.reads[eval_id]
+        return seq.pop(0) if len(seq) > 1 else seq[0]
+
+    def _wait_for_room(self):
+        pass
+
+    def deregister(self, job_id):
+        self.stops.append(job_id)
+
+
+def test_a_pending_head_holds_the_pair_until_it_is_complete(monkeypatch):
+    """The pair stops the oldest live job once it is seen complete: a head
+    that a worker still holds is waited for, so no stop is owed behind it
+    and the registration goes after the stop, FIFO all the same."""
+    monkeypatch.setattr(dev_agent_churn, "POLL_S", 0.0)
+    dep = _Pairs({"e0": ["pending", "pending", "complete"],
+                  "e1": ["failed"], "e2": ["complete"], "e3": ["pending"]},
+                 owed=2)
+    dev_agent_churn.Deployment._stop_owed(dep)
+    assert dep.stops == ["job-e0", "job-e2"] and dep.owed == 0
+    assert dep.unstopped == ["job-e1"] and dep.head_waits == 1
+    assert list(dep.live) == [("job-e3", "e3")]
+    # A head that stays pending past ROOM_TIMEOUT_S leaves its stop owed.
+    monkeypatch.setattr(dev_agent_churn, "ROOM_TIMEOUT_S", 0.0)
+    dep.owed = 1
+    dev_agent_churn.Deployment._stop_owed(dep)
+    assert dep.owed == 1 and dep.head_waits == 2
+    assert dep.stops == ["job-e0", "job-e2"]
+
+
 # ----------------------------------------------------- BENCHMARK.json
 def declared(bench):
-    """The configuration and the cell where this PR appended them, and
-    nothing about what a later PR appends behind them."""
+    """The configuration and the cell where they were appended, the four
+    metrics at 96-99, and nothing about what a later PR appends behind them
+    (a cell, a configuration, a metric that lists this cell)."""
     names = [c["name"] for c in bench["configs"]]
     conf = bench["configs"][names.index(CONF)]
     assert names.index(CONF) == 5
@@ -112,16 +159,17 @@ def declared(bench):
            if CELL in m.get("workloads", [CELL])}
     assert e2e == {"placed_per_s", "setup_s"}
     mine = [m for m in bench["per_layer"] if CELL in m["workloads"]]
+    at = {m["name"]: i for i, m in enumerate(bench["per_layer"])}
     for m in mine:
         # Behind the cells that stood before it; a metric of the storm
-        # family or one of the four it brings.
+        # family, one of the four it brings, or one appended behind those.
         assert m["workloads"].index(CELL) == len(before & set(m["workloads"]))
-        assert m["name"].endswith(".storm") or m["name"] in NEW
+        assert (m["name"].endswith(".storm") or m["name"] in NEW
+                or at[m["name"]] > max(at[n] for n in NEW)), m["name"]
         assert m["moves"] == "placed_per_s"
     # register() is the registration alone (the stop is sent before the
     # span opens), so the benchmark's span round it reports here too.
     assert "register_ms.storm" in {m["name"] for m in mine}
-    at = {m["name"]: i for i, m in enumerate(bench["per_layer"])}
     assert [at[n] for n in NEW] == list(range(96, 100))
     for name, (unit, better, source, layer) in NEW.items():
         entry = bench["per_layer"][at[name]]
@@ -145,6 +193,24 @@ def test_the_cell_and_its_metrics_are_declared():
     assert "fill_guard" not in traffic
 
 
+def test_the_stop_batch_share_is_appended_behind_the_four():
+    """The metric the stop batch's counter waited for: the entry behind the
+    last, on this cell alone, reading stop_batched over stop_evals."""
+    entry = BENCH["per_layer"][100]
+    assert entry == {"name": "stop_batch_share.churn", "unit": "%",
+                     "better": "higher", "source": "program_counter",
+                     "layer": "Window worker: server/pipelined_worker.py",
+                     "moves": "placed_per_s", "workloads": [CELL]}
+    spec = _json("benchmark", "layer_metrics", "stop_batch_share.churn.json")
+    assert (spec["reader"], spec["args"]) == ("worker_stats_opt", {
+        "num": "stop_batched", "per": "stop_evals", "scale": 100.0})
+    # Every stop batched reads 100; a window with no stop reads nothing.
+    assert _read("stop_batch_share.churn", {
+        "stats": {**STATS, "stop_batched": 28}}) == pytest.approx(100.0)
+    assert _read("stop_batch_share.churn", {
+        "stats": {**STATS, "stop_evals": 0, "stop_batched": 0}}) is None
+
+
 def _read(name, run):
     spec = _json("benchmark", "layer_metrics", name + ".json")
     reader = importlib.import_module("benchmark.readers." + spec["reader"])
@@ -154,14 +220,15 @@ def _read(name, run):
 
 
 STATS = {"t_slow_ms": 300.0, "slow": 30, "stop_evals": 28, "windows": 2,
-         "fast": 30}
+         "fast": 30, "stop_batched": 21}
 CHANGE = {"stats": STATS, "ops": [],
           "counters": {"nomad.state.promote": 1400.0,
                        "nomad.plan.stop_rows": 1400.0},
           "samples": {"nomad.server.job_deregister": [0.25, 0.75]}}
-# The parent's program lacks the stats key, the counter and the sample
-# this PR adds; it counts promotions all the same.
-PARENT = {"stats": {k: v for k, v in STATS.items() if k != "stop_evals"},
+# A program from before the cell lacks the stats keys, the counter and the
+# sample the cell's metrics read; it counts promotions all the same.
+PARENT = {"stats": {k: v for k, v in STATS.items()
+                    if k not in ("stop_evals", "stop_batched")},
           "ops": [], "counters": {"nomad.state.promote": 1400.0},
           "samples": {}}
 
@@ -171,6 +238,7 @@ PARENT = {"stats": {k: v for k, v in STATS.items() if k != "stop_evals"},
     ("stop_evals_per_window.churn", 14.0, None),
     ("stop_promote_share.churn", 100.0, 0.0),
     ("dereg_ms.churn", 0.5, None),
+    ("stop_batch_share.churn", 75.0, None),
 ])
 def test_a_new_metric_reads_its_number_and_nothing_breaks_at_the_parent(
         name, change, parent):

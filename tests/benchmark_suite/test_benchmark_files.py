@@ -50,23 +50,48 @@ def test_a_cell_finds_its_files_by_name(cell):
     assert len(reported["end_to_end"]) >= 2 and reported["per_layer"]
 
 
+def stated(conf, body):
+    """A configuration's file against its entry, and what its guarantees
+    say runs: one dev-mode server (in-memory log) with `servers` in
+    `reduced`, or three or five servers, not in dev mode, `servers` not
+    cut, and a durability that is not "none"."""
+    assert body["name"] == conf["name"] and body["source"] == conf["source"]
+    assert sorted(body["reduced"]) == sorted(conf["reduced"])
+    guarantees = body["guarantees"]
+    assert {"capacity", "constraints", "identity_and_counts", "read_back",
+            "device_usage_table", "consistency", "replicas",
+            "durability"} <= set(guarantees)
+    replicas, dev_mode = guarantees["replicas"], body["server"]["dev_mode"]
+    if replicas == 1:
+        assert dev_mode is True and "servers" in conf["reduced"]
+    else:
+        assert replicas in (3, 5)
+        assert dev_mode is False and "servers" not in conf["reduced"]
+        assert not guarantees["durability"].startswith("none")
+    # As shipped: two workers, 32-eval windows, host placement on.
+    assert body["server"]["num_schedulers"] == 2
+    assert body["server"]["scheduler_window"] == 32
+    assert body["server"]["host_placement"] is True
+
+
+def declared(bench, bodies=None):
+    """Every configuration of a BENCHMARK.json against its file; `bodies`
+    gives, by `file`, those of a made-up copy that are not on disk
+    (test_benchmark_list_grows.py)."""
+    for conf in bench["configs"]:
+        body = (bodies or {}).get(conf["file"])
+        if body is None:
+            with open(os.path.join(ROOT, conf["file"])) as f:
+                body = json.load(f)
+        stated(conf, body)
+
+
 @pytest.mark.parametrize("conf", BENCH["configs"], ids=lambda c: c["name"])
 def test_a_configuration_states_what_runs(conf):
     assert set(conf) == {"name", "source", "file", "reduced", "why"}
     assert conf["file"].startswith(BENCH["paths"][0] + "/")
     assert len(conf["source"]) <= 200 and len(conf["why"]) <= 200
-    with open(os.path.join(ROOT, conf["file"])) as f:
-        body = json.load(f)
-    assert body["name"] == conf["name"] and body["source"] == conf["source"]
-    assert sorted(body["reduced"]) == sorted(conf["reduced"])
-    assert body["guarantees"]["replicas"] == 1
-    assert {"capacity", "constraints", "identity_and_counts", "read_back",
-            "device_usage_table", "consistency",
-            "durability"} <= set(body["guarantees"])
-    # As shipped: two workers, 32-eval windows, host placement on.
-    assert body["server"]["num_schedulers"] == 2
-    assert body["server"]["scheduler_window"] == 32
-    assert body["server"]["host_placement"] is True
+    declared({"configs": [conf]})
     assert any(w["config"] == conf["name"] for w in BENCH["workloads"])
 
 

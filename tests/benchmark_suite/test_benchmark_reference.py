@@ -6,10 +6,12 @@ import inspect
 import json
 import os
 import random
+import types
 
 import numpy as np
 import pytest
 
+from benchmark import run as bench_run
 from benchmark.deploy.dev_agent import build_fleet, seeded_uuid
 from benchmark.reference import guarantees
 from nomad_tpu.structs import Allocation, Evaluation, Job, Resources, from_dict
@@ -219,9 +221,100 @@ def test_a_partial_commit_finished_by_a_follow_up_eval_is_correct():
 
 
 def test_no_counter_path_or_timing_can_enter_the_verdict():
-    """`correct` is judged from the store's reads, the device's table, the
-    drain and the platform; worker stats (fallback, host, fast, stale,
-    rebases), row choices and latencies are not among its inputs."""
+    """`correct` is judged from the store's reads (every replica's, and how
+    many replicas the configuration states), the device's table, the drain
+    and the platform; worker stats (fallback, host, fast, stale, rebases),
+    row choices and latencies are not among its inputs."""
     params = set(inspect.signature(guarantees.judge).parameters)
     assert params == {"reads", "acknowledged", "device_usage", "row_of",
-                      "undrained", "platform", "rehearsal"}
+                      "undrained", "platform", "rehearsal", "replica_reads",
+                      "replicas"}
+
+
+# ------------------------------------------------ check 5 on every replica
+ONE_REPLICA = ["2_capacity", "3_constraints", "4_identity", "4_counts",
+               "5_read_back", "6_device_usage", "8_platform"]
+
+
+class Replicated:
+    """A deployment of three servers as the harness sees it: reads() is
+    the leader's store, replica_reads() one copy a replica (None where a
+    replica does not answer)."""
+
+    def __init__(self, state, n=3):
+        self.state = state
+        self.copies = [{k: list(v) for k, v in state.reads().items()}
+                       for _ in range(n)]
+
+    def reads(self):
+        return self.state.reads()
+
+    def replica_reads(self):
+        return self.copies
+
+
+def _judge_replicated(dep, replicas=3):
+    s = dep.state
+    reads = dep.reads()
+    return guarantees.judge(reads, s.acknowledged, s.device_usage(),
+                            s.row_of, [], "tpu", rehearsal=False,
+                            replica_reads=bench_run.replica_reads(dep, reads),
+                            replicas=replicas)[0]
+
+
+def test_three_replicas_that_agree_are_correct():
+    verdict = _judge_replicated(Replicated(State()))
+    assert verdict.correct, verdict.failures
+    assert list(verdict.compared) == ONE_REPLICA[:5] + ["5_replicas"] + \
+        ONE_REPLICA[5:]
+    assert verdict.compared["5_replicas"] == {"value": 0.0, "limit": 0.0}
+
+
+def test_a_replica_that_misses_an_acknowledged_job_is_named():
+    dep = Replicated(State())
+    job_id = dep.state.jobs[1].ID
+    dep.copies[2]["jobs"] = [j for j in dep.copies[2]["jobs"]
+                             if j.ID != job_id]
+    verdict = _judge_replicated(dep)
+    assert _names(verdict) == ["5_read_back", "5_replicas"]
+    by_check = {f["check"]: f for f in verdict.failures}
+    assert by_check["5_read_back"]["ids"] == [f"replica 2: {job_id}"]
+    assert by_check["5_replicas"]["ids"] == ["replica 2"]
+    assert verdict.compared["5_read_back"]["value"] == 1.0
+    assert verdict.compared["5_replicas"] == {"value": 1.0, "limit": 0.0}
+
+
+@pytest.mark.parametrize("silent", ["none", "short"])
+def test_a_replica_that_does_not_answer_reads_one(silent):
+    dep = Replicated(State())
+    if silent == "none":
+        dep.copies[1] = None
+    else:
+        dep.copies.pop()  # two answers where three replicas are stated
+    verdict = _judge_replicated(dep)
+    assert not verdict.correct
+    assert verdict.compared["5_replicas"] == {"value": 1.0, "limit": 0.0}
+    where = 1 if silent == "none" else 2
+    assert {f["check"]: f["ids"] for f in verdict.failures}[
+        "5_replicas"] == [f"replica {where}"]
+
+
+def test_one_replica_compares_what_it_always_did():
+    """A deployment without replica_reads() is judged on its one reads(),
+    and its `compared` has the keys and values it had before replicas were
+    read: no 5_replicas."""
+    s = State()
+    plain = types.SimpleNamespace(reads=s.reads)
+    assert bench_run.replica_reads(plain, s.reads()) == [s.reads()]
+    for sound in (True, False):
+        s = State()
+        gone = None if sound else s.jobs.pop(0)
+        before, _ = s.judge()
+        after = _judge_replicated(
+            types.SimpleNamespace(state=s, reads=s.reads), replicas=1)
+        assert list(after.compared) == ONE_REPLICA
+        assert after.compared == before.compared
+        assert after.failures == before.failures
+    # The job is named as it always was, with no replica in front.
+    assert {f["check"]: f["ids"] for f in after.failures}[
+        "5_read_back"] == [gone.ID]

@@ -352,11 +352,41 @@ TICK_S = 0.05    # the ticker's sleep: a stall longer than this shows
 FLUSH_S = 1.0    # one tick_late and one cpu_share sample a second, not twenty
 _RUNTIME = ("nomad", "runtime")
 
+# The collector's cadence while an agent runs, paced by what it finds
+# (reference: the Go runtime collects when the live heap has doubled,
+# GOGC=100, and not every so many allocations). CPython 3.12 collects the
+# young generation once threshold0 net container allocations have piled up
+# since the last, the middle one every threshold1 young collections, and
+# the whole heap once threshold2 middle ones have run and a quarter of the
+# long-lived heap is new since the last full one.
+#
+# GC_YOUNG: a storm window holds its evals, prepared batches, plans and
+# results for tens to hundreds of ms; at CPython's 700 they outlive two
+# young collections and are promoted, and the promotions alone brought a
+# full collection every 2-5 s that walked the whole heap (up to 0.9 s on a
+# TPU v5e host, every thread stopped) and reclaimed nothing. At 100,000 a
+# window's objects die young. The price is the size of what a young and a
+# middle collection walk: on that host the largest took 37-48 ms and
+# 170-242 ms in a storm of stops or of jobs of 1,000 (at 200,000 the middle
+# one reached 405 ms; at 50,000 the middle collections came often enough
+# to bring a full one back).
+GC_YOUNG = 100_000
+GC_MIDDLE = 10  # CPython's threshold1
+# threshold2: CPython's 10 to start with and after a full collection that
+# reclaimed anything; doubled after each one that reclaimed nothing, up to
+# GC_FULL_MAX. At the cap a full collection still comes once 640 x 10 x
+# 100,000 = 6.4e8 net container allocations have piled up since the last.
+GC_FULL = 10
+GC_FULL_MAX = 640
+
 
 class RuntimeCollector:
     """One for the process, whatever the number of agents in it: a daemon
     ticker and one gc.callbacks entry, started by the first acquire() and
-    stopped by the last release().
+    stopped by the last release(). The first acquire also sets the
+    collector's thresholds to (GC_YOUNG, GC_MIDDLE, GC_FULL) and the last
+    release puts back those it found, so a program that embeds agents, or
+    a test process, gets its own cadence back.
 
     The ticker sleeps TICK_S and notes how long after its due time it ran:
     a thread that needs the interpreter to wake runs late by as long as
@@ -364,20 +394,24 @@ class RuntimeCollector:
     call that holds it, the host descheduled). Once every FLUSH_S it
     samples the largest lateness as nomad.runtime.tick_late (ms), the
     process's CPU over the wall as nomad.runtime.cpu_share (%, native
-    threads included: it may pass 100), sets the gauge
-    nomad.runtime.threads and adds the young collections since to the
-    counters nomad.runtime.gc_runs.gen0 / .gen1.
+    threads included: it may pass 100), sets the gauges
+    nomad.runtime.threads and nomad.runtime.gc_threshold.gen0 / .gen2 (the
+    cadence in force), and adds the collections since to the counters
+    nomad.runtime.gc_runs.gen0 / .gen1 / .gen2 and the objects the full
+    ones reclaimed to nomad.runtime.gc_reclaimed.gen2.
 
-    The callback adds one to a count for a collection of generation 0 or
-    1, and makes no registry call: those run hundreds of times a second. A
-    generation-2 collection is a Measure opened at `start` and closed at
-    `stop`: a span on the profiler's timeline, on the thread that
-    triggered it, and a nomad.runtime.gc sample in ms. The sample reaches
-    the registry through the ticker (this object stands where the
-    registry does for that Measure and keeps the sample until the next
-    tick): a collection starts wherever an object is allocated, also
-    inside a sink's add_sample with the sink's plain lock held, and a
-    registry call from the callback would then wait for its own thread."""
+    The callback adds one to a count for a collection of any generation,
+    and makes no registry call: young ones run many times a second. A
+    generation-2 collection is also a Measure opened at `start` and closed
+    at `stop`: a span on the profiler's timeline, on the thread that
+    triggered it, with the objects it reclaimed as its `collected`, and a
+    nomad.runtime.gc sample in ms. The sample reaches the registry through
+    the ticker (this object stands where the registry does for that
+    Measure and keeps the sample until the next tick): a collection starts
+    wherever an object is allocated, also inside a sink's add_sample with
+    the sink's plain lock held, and a registry call from the callback
+    would then wait for its own thread. At its `stop` the callback paces
+    the next full collection by what this one reclaimed (GC_FULL)."""
 
     def __init__(self, registry: MetricsRegistry) -> None:
         self._registry = registry
@@ -386,11 +420,14 @@ class RuntimeCollector:
         self._stop: Optional[threading.Event] = None
         self._thread: Optional[threading.Thread] = None
         # Collections run one at a time in a process, so the callback is
-        # the only writer of these two; the ticker reads them.
-        self._young = [0, 0]
+        # the only writer of these; the ticker reads the counts.
+        self._runs = [0, 0, 0]
+        self._reclaimed = 0
         self._full: Optional[Measure] = None
         self._closed: "collections.deque[Tuple[Key, float]]" = \
             collections.deque()
+        # The thresholds the first acquire found; None while released.
+        self._found: Optional[Tuple[int, int, int]] = None
 
     def acquire(self) -> None:
         with self._lock:
@@ -401,6 +438,8 @@ class RuntimeCollector:
             self._thread = threading.Thread(
                 target=self._tick, args=(self._stop,), daemon=True,
                 name="runtime-metrics")
+            self._found = gc.get_threshold()
+            gc.set_threshold(GC_YOUNG, GC_MIDDLE, GC_FULL)
             gc.callbacks.append(self._on_gc)
             self._thread.start()
 
@@ -412,6 +451,8 @@ class RuntimeCollector:
             if self._users:
                 return
             gc.callbacks.remove(self._on_gc)
+            found, self._found = self._found, None
+            gc.set_threshold(*found)
             self._stop.set()
             thread, self._thread = self._thread, None
         thread.join(timeout=5.0)
@@ -423,20 +464,31 @@ class RuntimeCollector:
 
     def _on_gc(self, phase: str, info: Dict[str, int]) -> None:
         generation = info["generation"]
+        if phase == "start":
+            if generation == 2:
+                self._full = Measure(self, _RUNTIME + ("gc",),
+                                     {"generation": generation})
+                self._full.__enter__()
+            return
+        self._runs[generation] += 1
         if generation < 2:
-            if phase == "stop":
-                self._young[generation] += 1
-        elif phase == "start":
-            self._full = Measure(self, _RUNTIME + ("gc",),
-                                 {"generation": generation})
-            self._full.__enter__()
-        elif self._full is not None:
-            full, self._full = self._full, None
+            return
+        collected = info["collected"]
+        self._reclaimed += collected
+        if self._found is not None:  # not after the last release
+            full_after = GC_FULL if collected else \
+                min(2 * gc.get_threshold()[2], GC_FULL_MAX)
+            gc.set_threshold(GC_YOUNG, GC_MIDDLE, full_after)
+        full, self._full = self._full, None
+        if full is not None:
+            if full._span is not None:
+                full._span.set_metadata(collected=collected)
             full.__exit__(None, None, None)
 
     def _tick(self, stop: threading.Event) -> None:
         registry = self._registry
-        flushed = list(self._young)  # an earlier ticker's are out already
+        # An earlier ticker's counts are out already.
+        flushed, reclaimed = list(self._runs), self._reclaimed
         late = 0.0
         wall0, cpu0 = time.monotonic(), time.process_time()
         while True:
@@ -456,12 +508,20 @@ class RuntimeCollector:
                                 100.0 * (cpu - cpu0) / (now - wall0))
             registry.set_gauge(_RUNTIME + ("threads",),
                                threading.active_count())
-            for generation, total in enumerate(self._young):
+            young, _, full_after = gc.get_threshold()
+            registry.set_gauge(_RUNTIME + ("gc_threshold", "gen0"), young)
+            registry.set_gauge(_RUNTIME + ("gc_threshold", "gen2"),
+                               full_after)
+            for generation, total in enumerate(self._runs):
                 if total > flushed[generation]:
                     registry.incr_counter(
                         _RUNTIME + ("gc_runs", f"gen{generation}"),
                         total - flushed[generation])
                     flushed[generation] = total
+            if self._reclaimed > reclaimed:
+                registry.incr_counter(_RUNTIME + ("gc_reclaimed", "gen2"),
+                                      self._reclaimed - reclaimed)
+                reclaimed = self._reclaimed
             late, wall0, cpu0 = 0.0, now, cpu
 
 

@@ -328,6 +328,115 @@ class TestRuntimeCollector:
                 agent.shutdown()
         assert len(_tickers()) == len(_callbacks()) == held
 
+    def test_the_first_agent_sets_the_cadence_and_the_last_gives_it_back(
+            self):
+        from nomad_tpu.agent import Agent
+        from nomad_tpu.agent.agent import AgentConfig
+
+        held = len(_tickers())  # 1 if an earlier test left an agent up
+        was_enabled, found = gc.isenabled(), gc.get_threshold()
+        gc.disable()  # no automatic collection moves the pace meanwhile
+        if not held:
+            gc.set_threshold(900, 11, 12)  # what an embedding program set
+        before = gc.get_threshold()
+        agents = [Agent(AgentConfig(server_enabled=False,
+                                    client_enabled=False, http_port=0,
+                                    bind_addr="127.0.0.1"))
+                  for _ in range(5)]
+        try:
+            for agent in agents:
+                agent.start()
+                assert gc.get_threshold()[:2] == (metrics.GC_YOUNG,
+                                                  metrics.GC_MIDDLE)
+            if not held:
+                assert gc.get_threshold()[2] == metrics.GC_FULL
+            for agent in agents[:-1]:
+                agent.shutdown()
+            assert gc.get_threshold()[:2] == (metrics.GC_YOUNG,
+                                              metrics.GC_MIDDLE)
+        finally:
+            for agent in agents:
+                agent.shutdown()
+            after = gc.get_threshold()
+            gc.set_threshold(*found)
+            if was_enabled:
+                gc.enable()
+        assert after == before
+
+    def test_a_full_collection_paces_the_next_by_what_it_reclaimed(self):
+        """Doubled after each that reclaimed nothing, up to the cap; back
+        to the start after one that reclaimed anything; young collections
+        never move it."""
+        was_enabled, found = gc.isenabled(), gc.get_threshold()
+        gc.disable()  # only the collections this test hands the callback
+        mine = metrics.RuntimeCollector(MetricsRegistry())
+        mine.acquire()
+        try:
+            def full(collected):
+                info = {"generation": 2, "collected": collected,
+                        "uncollectable": 0}
+                mine._on_gc("start", info)
+                mine._on_gc("stop", info)
+                return gc.get_threshold()
+
+            assert gc.get_threshold() == (metrics.GC_YOUNG,
+                                          metrics.GC_MIDDLE, metrics.GC_FULL)
+            paces = [full(0)[2] for _ in range(8)]
+            assert paces == [20, 40, 80, 160, 320, 640, 640, 640]
+            mine._on_gc("stop", {"generation": 0, "collected": 0,
+                                 "uncollectable": 0})
+            mine._on_gc("stop", {"generation": 1, "collected": 0,
+                                 "uncollectable": 0})
+            assert gc.get_threshold()[2] == 640
+            assert full(3) == (metrics.GC_YOUNG, metrics.GC_MIDDLE,
+                               metrics.GC_FULL)
+            assert full(0)[2] == 2 * metrics.GC_FULL
+            assert mine._runs == [1, 1, 10] and mine._reclaimed == 3
+        finally:
+            mine.release()
+            after = gc.get_threshold()
+            gc.set_threshold(*found)
+            if was_enabled:
+                gc.enable()
+        assert after == found
+        # Released, the callback paces nothing: the thresholds are the
+        # program's again.
+        mine._on_gc("stop", {"generation": 2, "collected": 0,
+                             "uncollectable": 0})
+        assert gc.get_threshold() == found
+
+    def test_cyclic_garbage_is_reclaimed_under_the_cadence(self):
+        """150,000 two-object cycles, each unreachable once made, with
+        no gc.collect: the young collections the cadence brings reclaim
+        them (a finalizer on every thousandth pair says which did)."""
+        import weakref
+
+        class Pair:
+            __slots__ = ("other", "__weakref__")
+
+        mine = metrics.RuntimeCollector(MetricsRegistry())
+        was_enabled = gc.isenabled()
+        gc.enable()
+        mine.acquire()
+        dead = []
+        try:
+            assert gc.get_threshold()[0] == metrics.GC_YOUNG
+            for i in range(150_000):
+                a, b = Pair(), Pair()
+                a.other, b.other = b, a
+                if i % 1000 == 0:
+                    weakref.finalize(a, dead.append, i)
+            del a, b
+            first = sorted(dead)
+        finally:
+            mine.release()
+            if not was_enabled:
+                gc.disable()
+        # The first young collection comes after 100,000 net allocations:
+        # 50,000 pairs. At least those are gone.
+        assert len(first) >= 50
+        assert first[:50] == list(range(0, 50_000, 1000))
+
     def test_fifty_holders_are_one_thread_and_one_callback(self):
         mine = metrics.RuntimeCollector(MetricsRegistry())
         for _ in range(50):
@@ -353,7 +462,7 @@ class TestRuntimeCollector:
         registry = MetricsRegistry()
         registry.add_sink(sink)
         mine = metrics.RuntimeCollector(registry)
-        mine._young[:] = [40, 4]  # what an earlier run of it had counted
+        mine._runs[:] = [40, 4, 0]  # what an earlier run of it had counted
         mine.acquire()
         try:
             gc.collect(0)
@@ -395,6 +504,7 @@ class TestRuntimeCollector:
         duration_ns, stats = found[0]
         assert duration_ns == pytest.approx(ms * 1e6, rel=0.5, abs=2e5)
         assert int(stats["generation"]) == 2
+        assert int(stats["collected"]) >= 0  # set at the collection's stop
 
     def test_young_collections_are_counted_and_never_a_call_of_their_own(
             self, runtime_rows):
@@ -420,6 +530,33 @@ class TestRuntimeCollector:
         assert 0.0 <= share < 100.0 * (os.cpu_count() or 1) + 100.0
         assert runtime_rows.of("nomad.runtime.threads")[0][0] \
             >= len(_tickers()) + 1
+
+    def test_full_collections_what_they_reclaimed_and_the_cadence_are_flushed(
+            self, runtime_rows):
+        class Ring:
+            __slots__ = ("next",)
+
+        for _ in range(1000):
+            ring = Ring()
+            ring.next = ring
+        del ring
+        gc.collect()
+        assert wait_for(
+            lambda: runtime_rows.of("nomad.runtime.gc_reclaimed.gen2"),
+            timeout=5, interval=0.02)
+        # The ticker's, once a second, with the young counts.
+        runs = runtime_rows.of("nomad.runtime.gc_runs.gen2")
+        assert sum(v for v, _ in runs) >= 1
+        assert {t for _, t in runs} == {TICKER}
+        [(reclaimed, thread)] = runtime_rows.of(
+            "nomad.runtime.gc_reclaimed.gen2")
+        assert reclaimed >= 1000 and thread == TICKER
+        # The gauges of the tick that flushed it: a collection that
+        # reclaimed something puts the full pace back to its start.
+        young = runtime_rows.of("nomad.runtime.gc_threshold.gen0")
+        full = runtime_rows.of("nomad.runtime.gc_threshold.gen2")
+        assert young[-1] == (float(metrics.GC_YOUNG), TICKER)
+        assert full[-1] == (float(metrics.GC_FULL), TICKER)
 
     def test_a_thread_that_keeps_the_interpreter_shows_as_a_late_tick(
             self, runtime_rows):
